@@ -537,8 +537,33 @@ def _join_on_boot(
     raise SystemExit(f"join via {coordinator_uri} did not complete in {timeout}s")
 
 
+def configure_compile_cache() -> str:
+    """Place JAX's persistent compilation cache and return its directory.
+    Where JAX_COMPILATION_CACHE_DIR is set JAX reads it itself and no
+    directory is set here; otherwise the cache lives at the fixed
+    `<checkout>/.jax_cache` beside the package (the path is part of the
+    cache key, so it must not move between runs). A served path compiles
+    one plan per query shape, most in well under JAX's default one-second
+    floor for persisting an entry, so the floor is dropped."""
+    import jax
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        import pilosa_tpu
+
+        checkout = os.path.dirname(
+            os.path.dirname(os.path.abspath(pilosa_tpu.__file__))
+        )
+        jax.config.update(
+            "jax_compilation_cache_dir", os.path.join(checkout, ".jax_cache")
+        )
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return jax.config.jax_compilation_cache_dir
+
+
 def cmd_server(cfg: Config, wait: bool = True, join: Optional[str] = None):
+    from pilosa_tpu import native
     from pilosa_tpu.cluster.topology import Node
+    from pilosa_tpu.parallel.mesh import device_report
     from pilosa_tpu.server.node import NodeServer
 
     data_dir = os.path.expanduser(cfg.data_dir) if cfg.data_dir else None
@@ -618,6 +643,7 @@ def cmd_server(cfg: Config, wait: bool = True, join: Optional[str] = None):
         tls_skip_verify=cfg.tls.skip_verify,
         tls_ca_cert=os.path.expanduser(cfg.tls.ca_certificate) if cfg.tls.ca_certificate else "",
     )
+    cache_dir = configure_compile_cache()
     srv.start()
     # static --cluster-hosts flags SEED a cluster; once membership is on
     # disk (.topology, written whenever a multi-node topology installs),
@@ -664,8 +690,14 @@ def cmd_server(cfg: Config, wait: bool = True, join: Optional[str] = None):
             )
         else:
             _join_on_boot(srv, join)
+    devices = device_report()
     print(
-        f"pilosa-tpu node {srv.node.id} listening on {srv.node.uri}",
+        f"pilosa-tpu node {srv.node.id} listening on {srv.node.uri}"
+        f" platform={devices[0]['platform']}"
+        f" device_kind={devices[0]['deviceKind']!r}"
+        f" devices={len(devices)}"
+        f" native={'on' if native.available() else 'off'}"
+        f" compile_cache={cache_dir}",
         file=sys.stderr,
     )
     if wait:

@@ -59,11 +59,10 @@ from pilosa_tpu.shardwidth import SHARD_WIDTH, SHARD_WIDTH_EXPONENT
 # sort/dedup kernel; smaller deltas stay on the vectorized host path
 # (a 200-position delta must not pay a program dispatch). < 0 disables
 # the device path outright; 0 forces it (tests use both extremes).
-# None = AUTO: 65536 on a real accelerator, device-off on the CPU
-# backend — there the "device" is the same silicon reached through
-# XLA's ~5x-slower sort comparator (ops/merge.py), so the dispatch can
-# never pay for itself at any burst size (np.unique measured ~6x
-# faster than the XLA CPU sort across 2^18..2^22 keys).
+# None = AUTO: 65536 on an accelerator, device-off on the CPU backend —
+# there the "device" is the same silicon reached through XLA's two-key
+# sort comparator (ops/merge.py), which np.unique beats at every burst
+# size, so the dispatch can never pay for itself.
 _ACCEL_DEVICE_THRESHOLD = 65536
 
 
@@ -109,14 +108,12 @@ def device_threshold() -> int:
     if _device_threshold is not None:
         return _device_threshold
     if not _auto_threshold:
-        try:
-            import jax
+        import jax
 
-            backend = jax.default_backend()
-        except Exception:  # noqa: BLE001 - probe failure -> host path
-            backend = "cpu"
+        # a backend that fails to initialise raises here: substituting
+        # "cpu" would silently turn the device merge off on the chip
         _auto_threshold.append(
-            -1 if backend == "cpu" else _ACCEL_DEVICE_THRESHOLD
+            -1 if jax.default_backend() == "cpu" else _ACCEL_DEVICE_THRESHOLD
         )
     return _auto_threshold[0]
 

@@ -514,8 +514,6 @@ class View:
         # index arrays, so a burst smeared over S shards costs one
         # whole-extent copy instead of S of them — the old per-position
         # `.at[p].set` cascade paid a full-extent copy per dirty shard
-        # (~11.6 s for a 50k-position burst over 954 shards,
-        # BENCH_NOTES round-10's named caveat)
         idx_p: List[int] = []
         idx_d: List[int] = []
         blocks: List[np.ndarray] = []

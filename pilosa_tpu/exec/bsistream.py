@@ -6,8 +6,7 @@ halved the SHARD axis until a depth-wide operand fit the quarter-budget —
 a deep int field paid many sequential staged dispatches where Count pays
 one — and `sum_counts_stacked`/`min_max_signed` read `[1 + 2D, S]`
 partials back for a Python host combine, with kernels that swept the
-word rows once per plane (BENCH_NOTES round-10: 5-15x off the Count
-roofline at 1B columns).
+word rows once per plane where a Count sweeps them once.
 
 This module rebuilds the lowering as plane-streamed:
 

@@ -141,9 +141,9 @@ def group_by_device(  # dispatch-ok: caller holds dispatch_mutex
     # One-shot path for small cross-products: build the full prefix
     # accumulator on device with NO intermediate host reads, tally the
     # last level, read ONCE. The pruned descent below costs one blocking
-    # read per depth — on tunneled hardware that is ~RTT x depth of pure
-    # latency — and pruning only pays when the cross-product is too big
-    # to materialize anyway.
+    # read per depth — a synchronisation each, pure latency — and
+    # pruning only pays when the cross-product is too big to materialize
+    # anyway.
     g_pre = 1
     for p in planes_list[:-1]:
         g_pre *= int(p.shape[0])

@@ -5,8 +5,7 @@ ledger (LRU + pins); this package decides *what* the ledger holds for the
 stacked query path: operand stacks are split into shard-major EXTENTS that
 page in and out individually, so an HBM budget below one query's working
 set re-stages only the evicted slices instead of re-shipping whole ~100 MB
-stacks over PCIe per query (the 30-40x cliff BENCH_r05's
-hbm_evict_count_ms measured). exec/plan.py pins a plan's extents for the
+stacks over PCIe per query. exec/plan.py pins a plan's extents for the
 duration of its compiled dispatch; sched/ reads residency for admission
 cost discounts and feeds the optional prefetcher from its queue peek.
 

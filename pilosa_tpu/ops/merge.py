@@ -2,9 +2,9 @@
 position keys as ONE compiled program.
 
 The host sorted-array merge that `Fragment._sync_locked` pays per
-fragment at every read barrier is ~100-250 MB/s-class (BENCH_NOTES
-round-6) and became the ingest ceiling once the staged write path made
-everything else cheap. The staged architecture batches naturally: the
+fragment at every read barrier became the ingest ceiling once the
+staged write path made everything else cheap. The staged architecture
+batches naturally: the
 pending position buffers of EVERY staged fragment a read is about to
 touch are stacked into one key array (segment id packed into the high
 bits, core/merge.py) and this module sorts + dedups them in one XLA
@@ -13,14 +13,11 @@ dispatch.
 Kernel shape (mirrors the TopN gather-tally style — segmentation by
 cumsum, no scatter):
 
-- on TPU, x64 stays off (TPU-native dtypes are 32-bit), so a uint64
-  key sorts as its (hi, lo) uint32 halves via `lax.sort` with two sort
-  keys — one stable multi-operand sort, lexicographic by (hi, lo). On
-  CPU/GPU backends the same program sorts native uint64 single-key
-  under `jax.experimental.enable_x64` instead: XLA's multi-operand
-  comparator costs ~5x a single-key sort on CPU (measured 106 ms vs
-  19 ms at 262 k keys), and the crossover knob exists precisely so the
-  dispatch pays for itself on whatever backend is serving.
+- x64 stays off (TPU-native dtypes are 32-bit), so a uint64 key sorts
+  as its (hi, lo) uint32 halves via `lax.sort` with two sort keys —
+  one stable multi-operand sort, lexicographic by (hi, lo). Every
+  backend runs this one formulation, so the CPU tests certify the
+  kernel the chip runs.
 - dedup is a neighbor-compare mask over the sorted keys; padding
   (all-ones sentinel, unreachable because core/merge.py bounds the
   packed keyspace below 2^63) sorts to the tail and masks out.
@@ -56,41 +53,6 @@ def reset_stats() -> None:
 
 _SENTINEL64 = np.uint64(0xFFFFFFFFFFFFFFFF)
 _PAD_MIN = 1024
-
-# Backend probe for the kernel variant: TPU lacks native 64-bit, so it
-# takes the (hi, lo) two-key formulation; everything else sorts uint64
-# single-key under enable_x64 (see module docstring for the measured
-# comparator-cost cliff). Resolved once, at first dispatch.
-_X64_KERNEL: list = []
-
-
-def _use_x64_kernel() -> bool:
-    if not _X64_KERNEL:
-        try:
-            _X64_KERNEL.append(jax.default_backend() != "tpu")
-        except Exception:  # noqa: BLE001 - probe failure -> portable path
-            _X64_KERNEL.append(False)
-    return _X64_KERNEL[0]
-
-
-@jax.jit
-def _merge_sorted_u64(keys):
-    """Single-key uint64 variant of `_merge_sorted_u32` (CPU/GPU under
-    enable_x64): sort, first-occurrence mask, padding mask-out, bit
-    cumsum. Same output contract, minus the split halves."""
-    s = jnp.sort(keys)
-    changed = s[1:] != s[:-1]
-    first = jnp.concatenate([jnp.ones(1, bool), changed])
-    keep = first & (s != jnp.uint64(0xFFFFFFFFFFFFFFFF))
-    bit = jnp.where(
-        keep,
-        jnp.left_shift(
-            jnp.uint32(1), jnp.bitwise_and(s, jnp.uint64(31)).astype(jnp.uint32)
-        ),
-        jnp.uint32(0),
-    )
-    cum = jnp.cumsum(bit, dtype=jnp.uint32)
-    return s, keep, cum
 
 
 @jax.jit
@@ -137,20 +99,6 @@ def merge_keys_device(keys: np.ndarray):
     from pilosa_tpu.exec.plan import dispatch_mutex
 
     buf = _pad_pow2(np.ascontiguousarray(keys, dtype=np.uint64))
-    if _use_x64_kernel():
-        with jax.experimental.enable_x64():
-            # device transfer happens before the dispatch lock (LOCK003:
-            # no device round-trips under a mutex)
-            keys_d = jax.device_put(buf)
-            with dispatch_mutex():
-                out = _merge_sorted_u64(keys_d)
-            MERGE_STATS["device_launches"] += 1
-            # the blocking device->host read happens OUTSIDE the
-            # dispatch lock: this is a single-device program (no
-            # collective rendezvous), so no other dispatch can deadlock
-            # against its completion
-            s, keep, cum = (np.asarray(x) for x in out)
-        return s[keep], cum[keep]
     hi = (buf >> np.uint64(32)).astype(np.uint32)
     lo = buf.astype(np.uint32)  # truncates to the low 32 bits
     hi_d = jax.device_put(hi)

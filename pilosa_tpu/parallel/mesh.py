@@ -38,13 +38,6 @@ from pilosa_tpu.ops import bitmap as ob
 from pilosa_tpu.utils.locks import TrackedLock
 from pilosa_tpu.utils.race import race_checked
 
-# jax.shard_map graduated from jax.experimental in newer releases; support
-# both so the mesh step runs on the 0.4.x line this image ships.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - depends on installed jax version
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 _pc = jax.lax.population_count
 
 
@@ -120,6 +113,27 @@ def activate_default_mesh() -> Optional[Mesh]:
         if _ACTIVE_MESH is None or set(_ACTIVE_MESH.devices.flat) != set(devices):
             set_active_mesh(make_mesh(devices))
     return _ACTIVE_MESH
+
+
+def device_report() -> list:
+    """The devices this process holds, as JAX reports them: one dict per
+    device with its platform, kind, id and `memory_stats()` bytes in use
+    and limit (None where the backend reports no memory stats, as the
+    CPU backend does). `/info` and the server's start-up line carry
+    this, so a server that came up on the wrong backend says so."""
+    out = []
+    for d in jax.devices():
+        stats = d.memory_stats() or {}
+        out.append(
+            {
+                "id": d.id,
+                "platform": d.platform,
+                "deviceKind": d.device_kind,
+                "bytesInUse": stats.get("bytes_in_use"),
+                "bytesLimit": stats.get("bytes_limit"),
+            }
+        )
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -301,7 +315,7 @@ def make_query_step(mesh: Mesh, row_a: int = 0, row_b: int = 1):
         rows = jax.lax.psum(rows, ("shards", "cols"))
         return data, inter, uni, rows
 
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(DATA_SPEC, DATA_SPEC),

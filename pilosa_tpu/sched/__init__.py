@@ -17,8 +17,8 @@ the query is *shed* with HTTP 429 + Retry-After instead of queueing
 unboundedly; server/faults.py already classifies 429 as retryable, so
 internode load shedding composes with the fan-out's failover retries.
 The controller also feeds observed load into exec/batcher.py's
-CountBatcher so batch size grows under load (the >=4-queries/sweep
-plateau from BENCH_NOTES round 3).
+CountBatcher so batch size grows under load (the fixed per-sweep cost
+amortizes over the batch).
 """
 
 from pilosa_tpu.sched.admission import (  # noqa: F401

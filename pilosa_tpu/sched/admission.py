@@ -96,7 +96,7 @@ class ShedError(Exception):
     root span would have carried) so the 429 body/header names the exact
     flight record to look for.
 
-    `reason` is the shed taxonomy tag (rate | bytes | queue | deadline)
+    `reason` is the shed tag (rate | bytes | queue | deadline)
     and, when a tenant quota tripped, `quota_limit`/`quota_usage`/
     `quota_value` name the limit for the X-Pilosa-Quota-* response
     headers — so a client can tell "the node is overloaded" from "YOU
@@ -695,7 +695,7 @@ class AdmissionController:
         # admit/shed/wait carry class AND index labels — per-tenant QoS
         # attribution; "-" marks requests bound to no index (e.g. resize
         # transfer serving) so the family's label set stays uniform.
-        # sched.shed additionally carries the reason taxonomy
+        # sched.shed additionally carries the reason tag
         # (rate | bytes | queue | deadline): overload and abuse must be
         # distinguishable from /metrics alone.
         self._emit_gauges(gauges)

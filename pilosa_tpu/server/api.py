@@ -1090,8 +1090,13 @@ class API:
         return __version__
 
     def info(self) -> dict:
-        """Host info (reference: api.Info — shard width + CPU counts)."""
+        """Host info (reference: api.Info — shard width + CPU counts).
+        Here the host's hardware is the accelerator, so the devices this
+        process holds and the HBM budget in force are reported too."""
         import os as _os
+
+        from pilosa_tpu.core.devcache import DEVICE_CACHE
+        from pilosa_tpu.parallel.mesh import device_report
 
         logical = _os.cpu_count() or 1
         physical = logical
@@ -1115,6 +1120,8 @@ class API:
             "shardWidth": SHARD_WIDTH,
             "cpuPhysicalCores": physical,
             "cpuLogicalCores": logical,
+            "devices": device_report(),
+            "hbmBudgetBytes": DEVICE_CACHE.budget_bytes,
         }
 
     def index_info(self, name: str) -> dict:

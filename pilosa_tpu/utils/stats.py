@@ -242,7 +242,7 @@ STAT_LABELS: Dict[str, Tuple[str, ...]] = {
     "ingest.apply_ms": ("index",),
     "ingest.route_ms": ("index",),
     "sched.admit": ("class", "index"),
-    # shed additionally carries the reason taxonomy — rate (tenant qps
+    # shed additionally carries the reason tag — rate (tenant qps
     # bucket), bytes (tenant bytes/s bucket or in-flight byte quota),
     # queue (admission/leg queue full), deadline (all deadline sheds) —
     # so overload and abuse are distinguishable from /metrics alone

@@ -320,8 +320,10 @@ class TestEndToEnd:
         q = "Count(Intersect(Row(f=1), Row(f=2)))"
         (expect,) = api.query("bi", q)  # warm + truth
         # overlap is timing-dependent, so retry the round until at least
-        # one batch forms (locked STATS make the totals exact per round)
-        for _ in range(5):
+        # one batch forms (locked STATS make the totals exact per round);
+        # a round is milliseconds, and at 5 rounds none overlapped in about
+        # one run in six on an 8-core host
+        for _ in range(40):
             _reset_stats()
             results = []
             errs = []

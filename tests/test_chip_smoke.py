@@ -1,0 +1,139 @@
+"""chip_smoke.py's own parts, small, on CPU: the seeded generator, the
+HTTP loader, the query list and the numpy reference must agree with a
+real server; the device assertion must refuse a CPU server; and the
+compile-cache function must leave a cache dir placed from outside alone.
+"""
+
+import os
+
+import jax
+import pytest
+
+import chip_smoke as cs
+import pilosa_tpu
+from pilosa_tpu.cli.main import configure_compile_cache
+from pilosa_tpu.testing import ClusterHarness
+
+
+def test_smoke_parts_agree_with_a_served_index_small():
+    with ClusterHarness(1, in_memory=True) as c:
+        uri = c[0].node.uri
+        http_ = cs.Http(uri)
+        info = http_.call("GET", "/info")
+        data = cs.Data(seed=3, shards=3, shard_width=info["shardWidth"])
+        ref = cs.Reference(data)
+        queries = cs.read_queries(ref)
+        cs.create_schema(http_)
+        assert set(cs.load(uri, data)) == {"f", "g", "h", "v"}
+        cs.run_queries(http_, queries, cold=True)
+        cs.run_queries(http_, queries, cold=False)
+        readback = cs.write_then_read(http_, data, ref)
+        assert [n for _, n in readback] == [
+            len(ref.row("f", 1)), len(ref.row("f", 0)),
+        ]
+        # the reference moved with the writes: the Pallas-pass repeat
+        # (plain jnp here) still agrees
+        for queries in cs.pallas_queries(ref):
+            cs.run_queries(http_, queries, cold=True)
+        http_.close()
+
+
+def test_pallas_pass_reaches_pallas_dispatch_points(monkeypatch):
+    """With the flag on, the smoke's Pallas pass must not be vacuous: its
+    MinRow/MaxRow and Tanimoto TopN run `popcount` and `popcount_rows`
+    through the Pallas kernels (interpreted here, on request), and every
+    answer still equals the reference."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from pilosa_tpu.ops import bitmap as ob
+    from pilosa_tpu.ops import pallas_kernels as pk
+    from pilosa_tpu.parallel import mesh as pmesh
+
+    reached = []
+    for name in ("popcount", "popcount_rows"):
+        real = getattr(pk, name)
+
+        def counted(*args, _real=real, _name=name):
+            reached.append(_name)
+            with pltpu.force_tpu_interpret_mode():
+                return _real(*args)
+
+        monkeypatch.setattr(pk, name, counted)
+    with ClusterHarness(1, in_memory=True) as c:
+        uri = c[0].node.uri
+        http_ = cs.Http(uri)
+        data = cs.Data(seed=5, shards=2, shard_width=1 << 20)
+        ref = cs.Reference(data)
+        cs.create_schema(http_)
+        cs.load(uri, data)
+        monkeypatch.setattr(ob, "_USE_PALLAS", True)
+        # single device: the interpreter's host callbacks cannot be
+        # partitioned over the suite's 8-virtual-device mesh
+        old_mesh = pmesh.active_mesh()
+        pmesh.set_active_mesh(None)
+        try:
+            for queries in cs.pallas_queries(ref):
+                cs.run_queries(http_, queries, cold=True)
+        finally:
+            pmesh.set_active_mesh(old_mesh)
+        http_.close()
+    assert {"popcount", "popcount_rows"} <= set(reached)
+
+
+def test_a_wrong_answer_fails_the_smoke():
+    with ClusterHarness(1, in_memory=True) as c:
+        http_ = cs.Http(c[0].node.uri)
+        cs.create_schema(http_)
+        with pytest.raises(AssertionError, match="want 1"):
+            cs.run_queries(
+                http_, [("count", "Count(Row(f=0))", 1, 0)], cold=True
+            )
+        http_.close()
+
+
+def test_device_assertion_refuses_a_cpu_server():
+    with ClusterHarness(1, in_memory=True) as c:
+        http_ = cs.Http(c[0].node.uri)
+        info = http_.call("GET", "/info")
+        http_.close()
+    assert info["devices"] and info["devices"][0]["platform"] == "cpu"
+    assert info["hbmBudgetBytes"] > 0
+    with pytest.raises(RuntimeError, match="not on a TPU"):
+        cs.check_device(info)
+    with pytest.raises(RuntimeError, match="not on a TPU"):
+        cs.check_device({"shardWidth": 1 << 20})  # a server that says nothing
+    tpu = dict(id=0, platform="tpu", deviceKind="TPU v5 lite",
+               bytesInUse=0, bytesLimit=1)
+    assert cs.check_device({"devices": [tpu]}) == {
+        "platform": "tpu", "kind": "TPU v5 lite", "count": 1,
+    }
+    with pytest.raises(RuntimeError, match="not on a TPU"):
+        cs.check_device({"devices": [tpu, info["devices"][0]]})
+
+
+@pytest.fixture
+def restore_jax_cache_config():
+    keys = (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+    )
+    before = {k: getattr(jax.config, k) for k in keys}
+    yield
+    for k, v in before.items():
+        jax.config.update(k, v)
+
+
+def test_compile_cache_dir_is_placed_from_outside(
+    monkeypatch, restore_jax_cache_config
+):
+    # set: the code sets no directory of its own (jax reads the variable
+    # itself at import; the config value here is whatever it was)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    jax.config.update("jax_compilation_cache_dir", "/some/dir")
+    assert configure_compile_cache() == "/some/dir"
+    assert jax.config.jax_compilation_cache_dir == "/some/dir"
+    # unset: the fixed <checkout>/.jax_cache beside the package
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    checkout = os.path.dirname(os.path.dirname(pilosa_tpu.__file__))
+    assert configure_compile_cache() == os.path.join(checkout, ".jax_cache")
+    assert configure_compile_cache() == os.path.join(checkout, ".jax_cache")

@@ -3,8 +3,8 @@ paging, pinning, prefetch, gauges, and the /debug/pprof satellite.
 
 The acceptance property: with an HBM budget BELOW a query's working set,
 the second run of the same query re-uploads only the evicted extents'
-bytes — never the whole stack set (the 30-40x hbm_evict cliff from
-BENCH_r05 was exactly whole-set re-staging per query).
+bytes — never the whole stack set (whole-set re-staging per query is
+the cliff this layer exists to remove).
 """
 
 import threading

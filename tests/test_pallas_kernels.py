@@ -1,4 +1,4 @@
-"""Pallas kernels vs the jnp reference paths (differential, CPU interpret).
+"""Pallas kernels vs the jnp reference paths (differential, interpreted).
 
 Mirrors the reference's differential testing discipline (roaring vs naive
 model, roaring/fuzzer.go): every kernel must agree bit-for-bit with the
@@ -7,6 +7,7 @@ ops/bitmap.py / ops/bsi.py implementations it can replace.
 
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 import pilosa_tpu.ops.bitmap as ob
 import pilosa_tpu.ops.bsi as bsi
@@ -17,6 +18,23 @@ from pilosa_tpu.shardwidth import WORDS_PER_ROW
 @pytest.fixture(scope="module")
 def rng():
     return np.random.default_rng(42)
+
+
+@pytest.fixture(autouse=True)
+def interpret_mode():
+    """The kernels compile for the TPU only; this CPU suite asks for the
+    Pallas TPU interpreter explicitly around every test."""
+    with pltpu.force_tpu_interpret_mode():
+        yield
+
+
+def test_no_silent_interpreter(rng):
+    """Outside an explicit interpret request a backend that cannot
+    compile the kernel raises — it never quietly interprets."""
+    a = rand_words(rng, 1024)
+    with pltpu.force_tpu_interpret_mode(None):
+        with pytest.raises(ValueError, match="interpret"):
+            pk.popcount(a)
 
 
 def rand_words(rng, *shape):

@@ -213,7 +213,7 @@ class TestAdmission:
         t.release()
         snap = st.registry.snapshot()
         # admit/shed carry class AND index labels ("-" = no index bound);
-        # shed additionally carries the reason taxonomy tag
+        # shed additionally carries the reason tag
         assert snap.get("sched.admit;class:interactive,index:-") == 1
         assert snap.get("sched.shed;class:batch,index:-,reason:queue") == 1
         assert "sched.queue_depth" in snap

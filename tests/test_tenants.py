@@ -614,7 +614,7 @@ def test_quota_shed_carries_429_detail_headers():
         # the unlimited tenant is untouched by its neighbor's limit
         status, body = _post_query(uri, "good", "Count(Row(f=1))")
         assert status == 200 and body["results"] == [50]
-        # node-saturation sheds keep the taxonomy but carry NO quota
+        # node-saturation sheds keep the reason tag but carry NO quota
         # headers (nothing tenant-specific tripped)
         snap = srv.stats.registry.snapshot()
         assert any(
@@ -766,7 +766,7 @@ def test_two_tenant_overload_soak():
         by_index = RESULT_CACHE.stats_snapshot()["by_index"]
         for idx in good:
             assert by_index.get(idx, 0) > 0, by_index
-        # shed taxonomy on /metrics: the abuser's rate sheds are tagged
+        # shed reasons on /metrics: the abuser's rate sheds are tagged
         snap = srv.stats.registry.snapshot()
         assert any(
             "sched.shed" in k
