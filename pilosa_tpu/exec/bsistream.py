@@ -131,11 +131,21 @@ def _slab_guard(n_shards: int, depth: int) -> None:
         raise BudgetExceeded("BSI slab exceeds device budget")
 
 
-def _run(fn, read: bool = True):
+def _run(program: str, fn, read: bool = True):
+    """One streamed dispatch; `program` is the jitted kernel `fn` runs,
+    as the profiler names it (the exec.dispatch span's plan.program)."""
     from pilosa_tpu.exec import plan as planmod
 
     _bump("plane_dispatches")
-    return planmod.run_counted(fn, read=read)
+    return planmod.run_counted(fn, read=read, family="bsi", program=program)
+
+
+def _lower_span():
+    """exec.lower for the streamed path's staging: the residency lookups
+    (and, on a miss, uploads) that turn a field into device operands."""
+    from pilosa_tpu.exec import plan as planmod
+
+    return planmod.lower_span("bsi")
 
 
 def _stage_slab(bsiv, lo: int, d: int, shards) -> Any:
@@ -146,10 +156,11 @@ def _stage_slab(bsiv, lo: int, d: int, shards) -> Any:
     slab on every staging)."""
     from pilosa_tpu.core.fragment import BSI_OFFSET_BIT
 
-    planes = bsiv.plane_stack(
-        range(BSI_OFFSET_BIT + lo, BSI_OFFSET_BIT + lo + d), shards,
-        parts=True,
-    )
+    with _lower_span():
+        planes = bsiv.plane_stack(
+            range(BSI_OFFSET_BIT + lo, BSI_OFFSET_BIT + lo + d), shards,
+            parts=True,
+        )
     _bump("slabs")
     if planes is not None:
         _bump(
@@ -172,15 +183,16 @@ def _field_rows(bsiv, shards, signed_: bool):
     Parts align with _stage_slab's: same shard list, same extent rows."""
     from pilosa_tpu.core.fragment import BSI_EXISTS_BIT, BSI_SIGN_BIT
 
-    exists = bsiv.row_stack(BSI_EXISTS_BIT, shards, parts=True)
-    if exists is None:
-        return None, None
-    sign = (
-        bsiv.row_stack(BSI_SIGN_BIT, shards, parts=True)
-        if signed_
-        else None
-    )
-    return exists, sign
+    with _lower_span():
+        exists = bsiv.row_stack(BSI_EXISTS_BIT, shards, parts=True)
+        if exists is None:
+            return None, None
+        sign = (
+            bsiv.row_stack(BSI_SIGN_BIT, shards, parts=True)
+            if signed_
+            else None
+        )
+        return exists, sign
 
 
 _EMPTY = "empty"  # chunk sentinel: no data -> zero contribution
@@ -197,7 +209,7 @@ def _filter_stack(ex, idx, filter_call, shards):
 
     low = _StackedLowering(ex, idx, list(shards), no_sparse_guard=True)
     try:
-        with DEVICE_CACHE.deferred_eviction():
+        with _lower_span(), DEVICE_CACHE.deferred_eviction():
             root = low.lower(filter_call)
             if isinstance(root, PZero):
                 return _EMPTY
@@ -349,6 +361,7 @@ def _aggregate_chunk(ex, idx, bsiv, f, filter_call, chunk, kind: str,
                 planes = _stage_slab(bsiv, lo, d, chunk)
                 host = np.asarray(
                     _run(
+                        "jit_sum_stream_slab",
                         lambda planes=planes, lo=lo:
                         obsi.sum_stream_slab(
                             planes, consider, sign, signed_, lo == 0
@@ -368,6 +381,7 @@ def _aggregate_chunk(ex, idx, bsiv, f, filter_call, chunk, kind: str,
             planes = _stage_slab(bsiv, 0, depth, chunk)
             host = np.asarray(
                 _run(
+                    "jit_min_max_stream",
                     lambda: obsi.min_max_stream(
                         planes, exists, sign, filt, is_min, signed_
                     )
@@ -387,6 +401,7 @@ def _aggregate_chunk(ex, idx, bsiv, f, filter_call, chunk, kind: str,
                 d = min(slab, depth - lo)
                 planes = _stage_slab(bsiv, lo, d, chunk)
                 fa, va = _run(
+                    "jit__min_max_stream_step",
                     lambda planes=planes, fa=fa, va=va, n=n:
                     obsi.min_max_stream_step(
                         planes, exists, sign, filt, fa, va,
@@ -396,6 +411,7 @@ def _aggregate_chunk(ex, idx, bsiv, f, filter_call, chunk, kind: str,
                 )
             host = np.asarray(
                 _run(
+                    "jit_min_max_stream_finish",
                     lambda: obsi.min_max_stream_finish(
                         exists, sign, filt, fa, va,
                         depth + (1 if signed_ else 0),
@@ -600,6 +616,7 @@ def _count_chunk(bsiv, chunk, depth: int, signed_: bool, jobs, preds,
             # pure mask count: != null, strict < 0, saturated predicates
             host = np.asarray(
                 _run(
+                    "jit_mask_count_pair",
                     lambda: obsi.mask_count_pair(
                         exists, sign, filt, extra_sels[0]
                     )
@@ -612,6 +629,7 @@ def _count_chunk(bsiv, chunk, depth: int, signed_: bool, jobs, preds,
             planes = _stage_slab(bsiv, 0, depth, chunk)
             host = np.asarray(
                 _run(
+                    "jit_range_stream_single",
                     lambda: obsi.range_stream_single(
                         planes, exists, sign, filt, upreds, jobs, extra_sels
                     )
@@ -625,6 +643,7 @@ def _count_chunk(bsiv, chunk, depth: int, signed_: bool, jobs, preds,
                 d = min(slab, depth - lo)
                 planes = _stage_slab(bsiv, lo, d, chunk)
                 state = _run(
+                    "jit__range_stream_step",
                     lambda planes=planes, state=state, lo=lo, n=n:
                     obsi.range_stream_step(
                         planes, exists, sign, filt, state, upreds,
@@ -634,6 +653,7 @@ def _count_chunk(bsiv, chunk, depth: int, signed_: bool, jobs, preds,
                 )
             host = np.asarray(
                 _run(
+                    "jit_range_stream_finish",
                     lambda: obsi.range_stream_finish(
                         exists, sign, filt, state, jobs, extra_sels
                     )

@@ -48,11 +48,13 @@ from pilosa_tpu.exec.plan import (
     SparseView,
     StackedPlan,
     Unsupported,
+    lower_span,
 )
 from pilosa_tpu.ops import bitmap as ob
 from pilosa_tpu.pql import Call, Query, parse
 from pilosa_tpu.pql.ast import BETWEEN, EQ, GT, GTE, LT, LTE, NEQ, Condition
 from pilosa_tpu.shardwidth import SHARD_WIDTH
+from pilosa_tpu.utils import tracing
 
 DEFAULT_MIN_THRESHOLD = 1  # reference: defaultMinThreshold, executor.go
 
@@ -557,6 +559,16 @@ class _StackedLowering:
 # ---------------------------------------------------------------------------
 
 _CACHE_KINDS = {"Count": "count", "TopN": "topn", "GroupBy": "groupby"}
+
+
+def _cache_kind(c: Call) -> Optional[str]:
+    """The result cache's kind for a call; None for a call it never keeps,
+    or while it is off."""
+    if rcache.RESULT_CACHE.budget_bytes <= 0:
+        return None
+    return _CACHE_KINDS.get(c.name)
+
+
 _CACHE_BITMAP_OK = frozenset(
     {"Row", "Union", "Intersect", "Difference", "Xor", "Not", "All",
      "Shift", "Range"}
@@ -683,7 +695,7 @@ class Executor:
                     ]
                     miss_results = None
                     if len(miss) >= 2:
-                        miss_results = self._execute_count_batch(
+                        miss_results = self._traced_count_batch(
                             idx, [cc for cc, _ in miss], shards, opt
                         )
                         if miss_results is not None:
@@ -697,12 +709,12 @@ class Executor:
                         elif miss_results is not None:
                             results.append(next(it))
                         else:
-                            r = self._execute_call(idx, cc, shards, opt)
+                            r = self._traced_call(idx, cc, shards, opt)
                             self._cache_store(idx, cx, r)
                             results.append(r)
                     i = j
                     continue
-                batch = self._execute_count_batch(idx, calls[i:j], shards, opt)
+                batch = self._traced_count_batch(idx, calls[i:j], shards, opt)
                 if batch is not None:
                     for cx, r in zip(ctxs, batch):
                         self._cache_store(idx, cx, r)
@@ -712,7 +724,7 @@ class Executor:
                     # per-call (re-attempting ever-shorter batches would be
                     # O(run^2) lowering walks)
                     for cc, cx in zip(calls[i:j], ctxs):
-                        r = self._execute_call(idx, cc, shards, opt)
+                        r = self._traced_call(idx, cc, shards, opt)
                         self._cache_store(idx, cx, r)
                         results.append(r)
                 i = j
@@ -722,7 +734,7 @@ class Executor:
                 results.append(cx.hit_result)
                 cache_hits += 1
             else:
-                r = self._execute_call(idx, calls[i], shards, opt)
+                r = self._traced_call(idx, calls[i], shards, opt)
                 self._cache_store(idx, cx, r)
                 results.append(r)
             i += 1
@@ -731,8 +743,6 @@ class Executor:
             # histograms must be attributable, not mysterious): tag the
             # enclosing api.query span; profiles and the slow-query log
             # then show cache-served queries explicitly
-            from pilosa_tpu.utils import tracing
-
             sp = tracing.active_span()
             if sp is not None:
                 sp.set_tag("cache.hit", True)
@@ -762,6 +772,21 @@ class Executor:
             resp.results = translation.translate_results(idx, query, results)
         return resp
 
+    def _traced_call(self, idx: Index, c: Call, shards, opt: ExecOptions):
+        """One top-level call under its exec.call span: what is in no
+        child span (lowering, dispatch) is the call's own host work."""
+        with tracing.start_span("exec.call") as sp:
+            sp.set_tag("pql.family", c.name)
+            return self._execute_call(idx, c, shards, opt)
+
+    def _traced_count_batch(self, idx: Index, calls: List[Call], shards, opt):
+        """A run of adjacent Counts as one multi-root plan: one exec.call
+        span for the run."""
+        with tracing.start_span("exec.call") as sp:
+            sp.set_tag("pql.family", "Count")
+            sp.set_tag("exec.calls", len(calls))
+            return self._execute_count_batch(idx, calls, shards, opt)
+
     def _shards_for(self, idx: Index, shards, call: Optional[Call] = None) -> List[int]:
         if shards is not None:
             s = list(shards)
@@ -789,8 +814,8 @@ class Executor:
         resolved shard list, remote flag): remote legs return different
         shapes (untrimmed TopN candidates) than coordinator results, so
         they cache under distinct keys."""
-        kind = _CACHE_KINDS.get(c.name)
-        if kind is None or rcache.RESULT_CACHE.budget_bytes <= 0:
+        kind = _cache_kind(c)
+        if kind is None:
             return None
         scope = getattr(idx, "_cache_scope", None)
         if scope is None:
@@ -1080,6 +1105,15 @@ class Executor:
         the call is ineligible; otherwise a _CacheCtx whose `hit` is set
         when the stored result revalidated (or was repaired in place by
         the read barrier this lookup ran)."""
+        if _cache_kind(c) is None:
+            return None  # nothing to look up, and no span for it
+        with tracing.start_span("exec.cache") as sp:
+            sp.set_tag("cache.op", "lookup")
+            ctx = self._cache_resolve(idx, c, shards, opt)
+            sp.set_tag("cache.hit", ctx is not None and ctx.hit)
+            return ctx
+
+    def _cache_resolve(self, idx: Index, c: Call, shards, opt: ExecOptions):
         ctx = self._cache_spec(idx, c, shards, opt)
         if ctx is None:
             return None
@@ -1154,15 +1188,17 @@ class Executor:
         version state."""
         if ctx is None or ctx.vector is None or result is None:
             return
-        opt = ExecOptions(remote=ctx.opt_remote)
-        vec2 = self.version_vector(idx, ctx, opt, expect=ctx.vector)
-        if vec2 != ctx.vector:
-            return
-        rcache.RESULT_CACHE.put(
-            ctx.key, ctx.kind, ctx.index_name, ctx.text, result, ctx.vector,
-            repair_spec=ctx.repair_spec, dep_rows=ctx.dep_rows,
-            clocks=ctx.clocks,
-        )
+        with tracing.start_span("exec.cache") as sp:
+            sp.set_tag("cache.op", "store")
+            opt = ExecOptions(remote=ctx.opt_remote)
+            vec2 = self.version_vector(idx, ctx, opt, expect=ctx.vector)
+            if vec2 != ctx.vector:
+                return
+            rcache.RESULT_CACHE.put(
+                ctx.key, ctx.kind, ctx.index_name, ctx.text, result,
+                ctx.vector, repair_spec=ctx.repair_spec,
+                dep_rows=ctx.dep_rows, clocks=ctx.clocks,
+            )
 
     # ------------------------------------------------------------------
     # prefetch warming (pilosa_tpu/hbm/)
@@ -1366,6 +1402,10 @@ class Executor:
         shard-axis chunking."""
         if not _STACKED_ENABLED or not shard_list:
             return None
+        with lower_span("stacked"):
+            return self._lower_roots_impl(idx, calls, shard_list, empty_ok)
+
+    def _lower_roots_impl(self, idx: Index, calls: List[Call], shard_list, empty_ok: bool):
         shard_list = list(shard_list)
         # Shift reads the PREVIOUS shard's child bits for its carry
         # (serial path: _bitmap_call_shard(shard-1)); when the caller asked
@@ -1911,7 +1951,7 @@ class Executor:
 
         low = _StackedLowering(self, idx, bsi_shards, no_sparse_guard=True)
         try:
-            with DEVICE_CACHE.deferred_eviction():
+            with lower_span("bsi"), DEVICE_CACHE.deferred_eviction():
                 low._stack_guard(bsiv, mult=f.options.bit_depth + 3)
                 filt = None
                 if filter_call is not None:
@@ -3209,7 +3249,7 @@ class Executor:
         low = _StackedLowering(self, idx, gb_shards, no_sparse_guard=True)
         planes_list = []
         try:
-            with DEVICE_CACHE.deferred_eviction():
+            with lower_span("groupby"), DEVICE_CACHE.deferred_eviction():
                 filt = None
                 if filter_call is not None:
                     root = low.lower(filter_call)
@@ -3240,7 +3280,7 @@ class Executor:
         # a profile shows the answer came from the device
         return planmod.run_counted(
             lambda: qgb.group_by_device(planes_list, child_rows, filt),
-            read=False,
+            read=False, family="groupby", program="jit__counts_cross",
         )
 
     def _group_by_shard(  # dispatch-ok: per-shard path, single-device

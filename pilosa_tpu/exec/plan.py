@@ -23,6 +23,7 @@ sums them in exact Python ints — one device->host read per query.
 
 from __future__ import annotations
 
+import threading
 import time as _time
 from dataclasses import dataclass
 from functools import partial
@@ -34,6 +35,7 @@ import numpy as np
 
 from pilosa_tpu.utils import tracing
 from pilosa_tpu.utils.locks import TrackedLock
+from pilosa_tpu.utils.stats import PROCESS
 from pilosa_tpu.ops import bsi as obsi
 from pilosa_tpu.ops.bitmap import shift_bits
 
@@ -53,6 +55,39 @@ STATS = {"evals": 0, "host_reads": 0}
 # mesh is the execution model anyway; the lock makes it explicit. It is
 # held through the device->host read so no async execution escapes it.
 _DISPATCH_MU = TrackedLock("plan.dispatch_mu")
+
+
+# Compile accounting, from jax.monitoring (names as jax 0.9.0 records
+# them: jax/_src/dispatch.py BACKEND_COMPILE_EVENT, which times every
+# compile request that reaches the backend, persistent-cache hits
+# included, and jax/_src/compiler.py, which records the hit). The counts
+# live in the process registry (utils/stats.py PROCESS) and reach
+# /debug/vars as exec.compiles / exec.compile_ms / exec.compile_cache_hits.
+# jit compiles on the calling thread, so a per-thread count tells a
+# dispatch whether it compiled (the span's dispatch.compiled tag).
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_compile_tls = threading.local()
+
+
+def _on_event_duration(event: str, duration_secs: float, **_kw) -> None:
+    if event == _BACKEND_COMPILE_EVENT:
+        _compile_tls.n = getattr(_compile_tls, "n", 0) + 1
+        PROCESS.count("exec.compiles", 1, ())
+        PROCESS.count("exec.compile_ms", duration_secs * 1000.0, ())
+
+
+def _on_event(event: str, **_kw) -> None:
+    if event == _CACHE_HIT_EVENT:
+        PROCESS.count("exec.compile_cache_hits", 1, ())
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_event_duration)
+jax.monitoring.register_event_listener(_on_event)
+
+
+def _thread_compiles() -> int:
+    return getattr(_compile_tls, "n", 0)
 
 
 def reset_stats() -> None:
@@ -76,16 +111,17 @@ def dispatch_mutex() -> TrackedLock:
     return _DISPATCH_MU
 
 
-def run_counted(fn, read: bool = True):
+def run_counted(fn, read: bool = True, family: str = "", program: str = ""):
     """run_serialized plus dispatch accounting and the exec.dispatch
     attribution probe: STATS["evals"] books the compiled dispatch and —
     when `read` — STATS["host_reads"] books the blocking result read the
     caller is about to take. The plane-streamed BSI aggregates ride this
     so their "one dispatch per budget chunk / one scalar read" contracts
-    are counter-asserted exactly like StackedPlan's."""
+    are counter-asserted exactly like StackedPlan's. `family` and
+    `program` name what `fn` runs, for the span's plan.* tags."""
     t_lock = _pre_dispatch()
     with _DISPATCH_MU:
-        probe = _DispatchProbe(t_lock)
+        probe = _DispatchProbe(t_lock, family, program)
         try:
             import jax
 
@@ -356,10 +392,11 @@ def _eval_jit(plan: PNode, out_mode: str, operands: Tuple, scalars: Tuple):
 
 def _flush_stage_span() -> None:
     """Flush this thread's staging account (hbm/residency uploads, device
-    cache build waits, prefetch credit) into an exec.stage span anchored
-    just before the dispatch that consumes the staged operands. Always
-    drains the accumulator — staging by an unsampled query must not leak
-    into the next sampled one on the same thread."""
+    cache build waits, prefetch credit) into an exec.stage span: at the
+    end of the exec.lower span that staged (lower_span), and, for what
+    staged outside one, just before the dispatch that consumes the
+    operands. Always drains the accumulator — staging by an unsampled
+    query must not leak into the next sampled one on the same thread."""
     nbytes, seconds, hits = tracing.take_stage_account()
     if tracing.active_span() is None:
         return
@@ -370,6 +407,27 @@ def _flush_stage_span() -> None:
         seconds,
         tags={"stage.bytes": nbytes, "stage.prefetch_hits": hits},
     )
+
+
+class lower_span:
+    """`with lower_span(family):` — the exec.lower span around a call
+    becoming device operands (lowering, residency lookups, and on a miss
+    the uploads). The staging done inside is flushed into its exec.stage
+    child on the way out, so the child's window lies in the parent's and
+    the two self times add up."""
+
+    __slots__ = ("_span",)
+
+    def __init__(self, family: str):
+        self._span = tracing.start_span("exec.lower")
+        self._span.set_tag("plan.family", family)
+
+    def __enter__(self):
+        return self._span.__enter__()
+
+    def __exit__(self, *exc) -> None:
+        _flush_stage_span()
+        self._span.__exit__(*exc)
 
 
 def _pre_dispatch() -> float:
@@ -387,12 +445,22 @@ class _DispatchProbe:
     _pre_dispatch), call evaled() between the jitted call and the host
     read, finish() in the dispatch `finally`. Tags: lock wait vs device
     eval vs blocking device->host read; eval/read are omitted when the
-    eval raised before evaled()."""
+    eval raised before evaled(). `family` is the plan family (stacked /
+    bsi / groupby), `program` the jitted program as the profiler's "XLA
+    Modules" line names it, which joins this span to its device ops;
+    dispatch.compiled says whether this dispatch had to compile. The
+    span is entered and left by hand, as a `with` would, so that it is
+    on the profiler's clock too (utils/tracing.py)."""
 
-    __slots__ = ("_span", "_t_lock", "_t0", "_t1")
+    __slots__ = ("_span", "_t_lock", "_t0", "_t1", "_compiles")
 
-    def __init__(self, t_lock: float):
-        self._span = tracing.start_span("exec.dispatch")
+    def __init__(self, t_lock: float, family: str = "stacked",
+                 program: str = "jit__eval_jit"):
+        self._span = sp = tracing.start_span("exec.dispatch")
+        sp.__enter__()
+        sp.set_tag("plan.family", family)
+        sp.set_tag("plan.program", program)
+        self._compiles = _thread_compiles()
         self._t_lock = t_lock
         self._t0 = _time.perf_counter()
         self._t1: Optional[float] = None
@@ -417,7 +485,8 @@ class _DispatchProbe:
             sp.set_tag(
                 "dispatch.read_ms", round((end - self._t1) * 1000.0, 3)
             )
-        sp.finish()
+        sp.set_tag("dispatch.compiled", _thread_compiles() > self._compiles)
+        sp.__exit__(None, None, None)
 
 
 class StackedPlan:
@@ -585,7 +654,7 @@ class MultiCountPlan:
     def counts(self) -> List[int]:
         t_lock = _pre_dispatch()
         with _DISPATCH_MU:
-            probe = _DispatchProbe(t_lock)
+            probe = _DispatchProbe(t_lock, program="jit__eval_multi_jit")
             probe.tag("dispatch.roots", len(self.roots))
             try:
                 out = _eval_multi_jit(
@@ -613,7 +682,7 @@ class MultiCountPlan:
             return self.counts()
         t_lock = _pre_dispatch()
         with _DISPATCH_MU:
-            probe = _DispatchProbe(t_lock)
+            probe = _DispatchProbe(t_lock, program="jit__eval_multi_jit")
             probe.tag("dispatch.roots", len(self.roots))
             probe.tag("dispatch.mode", "total")
             try:

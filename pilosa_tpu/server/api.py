@@ -56,6 +56,12 @@ def _group_by_shard(shards: np.ndarray, timestamps):
 _VIEW_NAME_RE = re.compile(r"[a-z][a-z0-9_]{0,63}")
 
 
+def _pql_family(query) -> str:
+    """The top-level call names of a parsed query, each once, in order
+    ("Count", "TopN,Sum"): the `pql.family` tag of the request's spans."""
+    return ",".join(dict.fromkeys(c.name for c in query.calls))
+
+
 def _validate_view_name(view: str) -> None:
     """View names become path components (view.go naming: standard,
     standard_YYYYMMDDHH, bsig_<field>); anything else is rejected so
@@ -186,13 +192,22 @@ class API:
         from pilosa_tpu.utils import tracing
 
         self._validate("query")
+        # the span this call runs under, if any: the HTTP handler's
+        # http.request. api.parse and api.admit are its children (no
+        # span of their own without one: they come before api.query
+        # opens and would be roots beside it), and the handler, whose
+        # span is still open, assembles the profile tree
+        enclosing = tracing.current_span()
         pql_text = query if isinstance(query, str) else str(query)
         if isinstance(query, str):
             from pilosa_tpu.pql import parse
             from pilosa_tpu.pql.parser import ParseError
 
             try:
-                query = parse(query)
+                with tracing.start_span("api.parse") as parse_span:
+                    query = parse(query)
+                    family = _pql_family(query)
+                    parse_span.set_tag("pql.family", family)
             except ParseError:
                 # parsing now happens before the span/stat machinery (the
                 # admission cost estimate needs the call tree), but a
@@ -202,6 +217,8 @@ class API:
                 stats.count("query_n")
                 stats.timing("query_ms", 0.0)
                 raise
+        else:
+            family = _pql_family(query)
         opt = ExecOptions(
             remote=remote,
             column_attrs=column_attrs,
@@ -212,43 +229,70 @@ class API:
         # executes, but its 429 must still name the flight record it
         # would have flown under (satellite: diagnosable sheds)
         incoming_trace = headers.get(tracing.TRACE_HEADER) if headers else None
-        trace_id = incoming_trace or tracing.new_trace_id()
+        trace_id = (
+            getattr(enclosing, "trace_id", "")
+            or incoming_trace
+            or tracing.new_trace_id()
+        )
+        # everything from admission on runs under the ticket's
+        # try/finally — even a failure closing or building a span must
+        # release the slot, or the node would bleed concurrency capacity
+        # until restart
+        ticket = None
         try:
-            ticket = self._admit(index, query, shards, remote, headers, opt)
-        except ShedError as e:
-            if not e.trace_id:
-                e.trace_id = trace_id
-            raise
-        # everything from here on runs under the ticket's try/finally —
-        # even a failure building the span must release the slot, or the
-        # node would bleed concurrency capacity until restart
-        try:
-            span = (
-                self.server.tracer.start_span_from_headers(
+            with tracing.start_span("api.admit") as admit_span:
+                try:
+                    ticket = self._admit(
+                        index, query, shards, remote, headers, opt
+                    )
+                except ShedError as e:
+                    if not e.trace_id:
+                        e.trace_id = trace_id
+                    raise
+                if ticket is not None:
+                    admit_span.set_tag("sched.class", ticket.cls)
+                    admit_span.set_tag(
+                        "sched.wait_ms", round(ticket.waited * 1000.0, 3)
+                    )
+                    # the queue wait as a stage of its own, inside
+                    # api.admit's window. Fast-path grants (waited 0) record
+                    # nothing — a zero-length span per query would evict real
+                    # stages from the ring, and the sched.wait_ms tag carries
+                    # the value
+                    if ticket.waited > 0:
+                        tracing.record_span(
+                            "sched.admit",
+                            ticket.waited,
+                            tags={"sched.class": ticket.cls},
+                            parent=admit_span,
+                        )
+            if enclosing is not None:
+                span = self.server.tracer.start_span(
+                    "api.query", parent=enclosing, force=profile
+                )
+            elif incoming_trace:
+                span = self.server.tracer.start_span_from_headers(
                     "api.query", headers, force=profile
                 )
-                if incoming_trace
-                else self.server.tracer.start_span(
+            else:
+                span = self.server.tracer.start_span(
                     "api.query", trace_id=trace_id, force=profile
                 )
-            )
             t0 = _time.perf_counter()
             resp = None
             with span:
                 span.set_tag("index", index)
                 span.set_tag("remote", remote)
+                span.set_tag("pql.family", family)
                 if ticket is not None:
                     span.set_tag("sched.class", ticket.cls)
                     span.set_tag(
                         "sched.wait_ms", round(ticket.waited * 1000.0, 3)
                     )
-                    # admission wait as a first-class stage: it completed
-                    # before this span opened, so assembly clamps it and
-                    # keeps the raw window. Fast-path grants (waited 0)
-                    # record nothing — a zero-length span per query would
-                    # evict real stages from the ring, and the root's
-                    # sched.wait_ms tag already carries the value
-                    if ticket.waited > 0:
+                    # without an api.admit span the wait is recorded here:
+                    # it completed before this span opened, so assembly
+                    # clamps it and keeps the raw window
+                    if ticket.waited > 0 and enclosing is None:
                         tracing.record_span(
                             "sched.admit",
                             ticket.waited,
@@ -285,8 +329,9 @@ class API:
                         self._log_slow_query(index, pql_text, dt, lqt, span)
             # the root span is finished and recorded here; the remote
             # legs' spans were ingested during execution, so the ring now
-            # holds the whole trace
-            if profile and resp is not None:
+            # holds the whole trace (under an enclosing span the root is
+            # the caller's, and so is the assembly)
+            if profile and resp is not None and enclosing is None:
                 resp.profile = self._assemble_trace(span.trace_id or trace_id)
             return resp
         finally:

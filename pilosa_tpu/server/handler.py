@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import json
 import re
+import time
 import traceback
 import urllib.parse
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -27,6 +28,7 @@ from pilosa_tpu.pql.parser import ParseError
 from pilosa_tpu.sched.admission import ShedError
 from pilosa_tpu.server import wire
 from pilosa_tpu.server.api import ApiError, DisabledError
+from pilosa_tpu.utils import tracing
 
 _ROUTES: List[Tuple[str, re.Pattern, str]] = []
 
@@ -48,6 +50,10 @@ def route(method: str, pattern: str):
     return deco
 
 
+def _ms_since(t: float) -> float:
+    return round((time.perf_counter() - t) * 1000.0, 3)
+
+
 class Handler(BaseHTTPRequestHandler):
     server_version = "pilosa-tpu/0.1"
     protocol_version = "HTTP/1.1"
@@ -66,12 +72,19 @@ class Handler(BaseHTTPRequestHandler):
 
     # -- plumbing ----------------------------------------------------------
 
-    def _body(self) -> bytes:
+    def _body(self, span=None) -> bytes:
+        """The request's body; under a request span, timed into its
+        http.read_ms / http.bytes_in tags."""
+        t = time.perf_counter()
         n = int(self.headers.get("Content-Length") or 0)
-        return self.rfile.read(n) if n else b""
+        data = self.rfile.read(n) if n else b""
+        if span is not None:
+            span.set_tag("http.read_ms", _ms_since(t))
+            span.set_tag("http.bytes_in", len(data))
+        return data
 
-    def _json_body(self) -> Any:
-        data = self._body()
+    def _json_body(self, span=None) -> Any:
+        data = self._body(span)
         return json.loads(data) if data else {}
 
     def _reply(self, obj: Any, code: int = 200, raw: Optional[bytes] = None,
@@ -89,6 +102,29 @@ class Handler(BaseHTTPRequestHandler):
 
     def _error(self, msg: str, code: int = 400) -> None:
         self._reply({"error": msg}, code=code)
+
+    # -- the request's root span (query and import routes) ------------------
+    # http.request covers the route from before the body is read to after
+    # the reply's last write; _body and _write_reply time its parts into
+    # tags. An error reply is written by _dispatch, after the span closed.
+
+    def _request_span(self, route_name: str, force: bool = False):
+        span = self.node.tracer.start_span_from_headers(
+            "http.request", self.headers, force=force
+        )
+        return span.set_tag("http.route", route_name)
+
+    def _write_reply(self, span, obj: Any = None,
+                     raw: Optional[bytes] = None) -> None:
+        """Reply with `raw`, or with `obj` encoded here (http.encode_ms)."""
+        if raw is None:
+            t = time.perf_counter()
+            raw = json.dumps(obj).encode()
+            span.set_tag("http.encode_ms", _ms_since(t))
+        span.set_tag("http.bytes_out", len(raw))
+        t = time.perf_counter()
+        self._reply(None, raw=raw)
+        span.set_tag("http.write_ms", _ms_since(t))
 
     def _int_param(self, name: str, default: Any = _REQUIRED) -> Optional[int]:
         """Validated integer query parameter: absent -> `default` (or 400
@@ -267,9 +303,7 @@ class Handler(BaseHTTPRequestHandler):
                         hdrs["X-Pilosa-Quota-Value"] = f"{e.quota_value:g}"
                     body = {"error": str(e)}
                     if trace_id:
-                        from pilosa_tpu.utils import tracing as _tracing
-
-                        hdrs[_tracing.TRACE_HEADER] = trace_id
+                        hdrs[tracing.TRACE_HEADER] = trace_id
                         body["traceId"] = trace_id
                     self._reply(body, code=429, extra_headers=hdrs)
                 except DisabledError as e:
@@ -436,10 +470,8 @@ class Handler(BaseHTTPRequestHandler):
         clamped windows and per-span self-times — the flight record."""
         trace_id = self.query.get("trace")
         if trace_id:
-            from pilosa_tpu.utils import tracing as _tracing
-
             self._reply(
-                _tracing.assemble(
+                tracing.assemble(
                     self.node.tracer.spans_for(trace_id), trace_id
                 )
             )
@@ -507,7 +539,13 @@ class Handler(BaseHTTPRequestHandler):
 
     @route("POST", "/index/(?P<index>[^/]+)/query")
     def post_query(self, index: str):
-        body = self._body()
+        with self._request_span(
+            "query", force=self.query.get("profile", "") in ("1", "true")
+        ) as span:
+            self._post_query(index, span)
+
+    def _post_query(self, index: str, span) -> None:
+        body = self._body(span)
         ctype = (self.headers.get("Content-Type") or "").split(";")[0].strip()
         shards = None
         if ctype == "application/json":
@@ -525,6 +563,9 @@ class Handler(BaseHTTPRequestHandler):
             return self.query.get(name, "") in ("1", "true")
 
         opts = d if ctype == "application/json" else None
+        profile = flag("profile", opts)
+        if profile:
+            span.sampled = True  # asked for in the JSON body: known only now
         resp = self.api.query_response(
             index,
             pql,
@@ -533,33 +574,47 @@ class Handler(BaseHTTPRequestHandler):
             column_attrs=flag("columnAttrs", opts),
             exclude_row_attrs=flag("excludeRowAttrs", opts),
             exclude_columns=flag("excludeColumns", opts),
-            profile=flag("profile", opts),
+            profile=profile,
         )
+        t = time.perf_counter()
         out = {"results": [wire.result_to_public_json(r) for r in resp.results]}
         if resp.column_attr_sets is not None:
             out["columnAttrs"] = [s.to_json() for s in resp.column_attr_sets]
-        if resp.profile is not None:
-            out["profile"] = resp.profile
-        self._reply(out)
+        raw = json.dumps(out).encode()
+        span.set_tag("http.encode_ms", _ms_since(t))
+        if profile:
+            # the tree, with this still-open root at its duration so far:
+            # assembly, the tree's own encoding and the write lie after
+            # it, so the tree of a request never holds them (the ring's
+            # copy of the finished span does)
+            tree = tracing.assemble_open(
+                span, self.node.tracer.spans_for(span.trace_id)
+            )
+            raw = b'%s, "profile": %s}' % (raw[:-1], json.dumps(tree).encode())
+        self._write_reply(span, raw=raw)
 
     @route("POST", "/index/(?P<index>[^/]+)/field/(?P<field>[^/]+)/import")
     def post_import(self, index: str, field: str):
-        d = self._json_body()
-        rows = d.get("rowKeys") or d.get("rows") or []
-        cols = d.get("colKeys") or d.get("cols") or []
-        summary = self.api.import_bits(
-            index, field, rows, cols,
-            clear=d.get("clear", False),
-            timestamps=d.get("timestamps"),
-        )
-        self._reply(summary or {})
+        with self._request_span("import") as span:
+            d = self._json_body(span)
+            rows = d.get("rowKeys") or d.get("rows") or []
+            cols = d.get("colKeys") or d.get("cols") or []
+            summary = self.api.import_bits(
+                index, field, rows, cols,
+                clear=d.get("clear", False),
+                timestamps=d.get("timestamps"),
+            )
+            self._write_reply(span, summary or {})
 
     @route("POST", "/index/(?P<index>[^/]+)/field/(?P<field>[^/]+)/import-value")
     def post_import_value(self, index: str, field: str):
-        d = self._json_body()
-        cols = d.get("colKeys") or d.get("cols") or []
-        summary = self.api.import_values(index, field, cols, d.get("values", []))
-        self._reply(summary or {})
+        with self._request_span("import-value") as span:
+            d = self._json_body(span)
+            cols = d.get("colKeys") or d.get("cols") or []
+            summary = self.api.import_values(
+                index, field, cols, d.get("values", [])
+            )
+            self._write_reply(span, summary or {})
 
     @route(
         "POST",
@@ -617,10 +672,8 @@ class Handler(BaseHTTPRequestHandler):
 
     @route("POST", "/internal/index/(?P<index>[^/]+)/query")
     def post_internal_query(self, index: str):
-        from pilosa_tpu.utils import tracing as _tracing
-
         d = self._json_body()
-        trace_id = self.headers.get(_tracing.TRACE_HEADER)
+        trace_id = self.headers.get(tracing.TRACE_HEADER)
         try:
             results = self.api.query(
                 index,

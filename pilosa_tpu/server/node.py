@@ -830,6 +830,33 @@ class NodeServer:
         self.stats.gauge("mesh.group_size", group_size)
         self.stats.gauge("mesh.local_shards", gsnap["local_shards"])
         self.stats.gauge("mesh.collective_bytes", gsnap["collective_bytes"])
+        # compiled dispatches (exec/plan.py) and the batcher's rounds
+        # (exec/batcher.py): the counts those modules keep, and the
+        # compile counts jax.monitoring feeds the process registry —
+        # exec.compiles that moves in a steady state is a program
+        # compiled per request shape
+        from pilosa_tpu.exec import batcher as batcher_mod
+        from pilosa_tpu.exec import plan as plan_mod
+        from pilosa_tpu.utils.stats import PROCESS
+
+        self.stats.gauge("exec.dispatches", plan_mod.STATS["evals"])
+        self.stats.gauge("exec.host_reads", plan_mod.STATS["host_reads"])
+        self.stats.gauge("exec.compiles", PROCESS.total_counter("exec.compiles"))
+        self.stats.gauge(
+            "exec.compile_ms", PROCESS.total_counter("exec.compile_ms")
+        )
+        self.stats.gauge(
+            "exec.compile_cache_hits",
+            PROCESS.total_counter("exec.compile_cache_hits"),
+        )
+        self.stats.gauge("batcher.leader", batcher_mod.STATS["leader"])
+        self.stats.gauge("batcher.batched", batcher_mod.STATS["batched"])
+        self.stats.gauge(
+            "batcher.merged_execs", batcher_mod.STATS["merged_execs"]
+        )
+        self.stats.gauge(
+            "batcher.fallback_splits", batcher_mod.STATS["fallback_splits"]
+        )
         # per-index attribution (the telemetry-plane families): who owns
         # the resident bytes, and who has been paying the restage bill.
         # hbm.resident_bytes sums over labels to the global devcache

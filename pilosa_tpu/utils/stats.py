@@ -70,8 +70,29 @@ STAT_NAMES = frozenset(
         "sched.shed",
         "sched.wait_ms",
         # cross-request count batching (exec/batcher.py): calls merged
-        # into each executed round
+        # into each executed round; then the batcher's own counts of
+        # rounds led, requests that rode another's round, merged
+        # executions and merges split after an error (batcher.STATS,
+        # published at scrape time by publish_cache_gauges)
         "batcher.batch_size",
+        "batcher.leader",
+        "batcher.batched",
+        "batcher.merged_execs",
+        "batcher.fallback_splits",
+        # compiled dispatches (exec/plan.py, published at scrape time,
+        # process-global like the hbm.* gauges): dispatches and blocking
+        # device->host reads (plan.STATS), and what jax.monitoring tells
+        # the listeners plan.py registers — compile requests that reached
+        # the backend (compiles; compile_ms their wall time) and how many
+        # of those the persistent cache answered (compile_cache_hits;
+        # compiles - compile_cache_hits were compiled anew). A steady
+        # state compiles nothing: exec.compiles that rises with traffic
+        # is a program compiled per request shape
+        "exec.dispatches",
+        "exec.host_reads",
+        "exec.compiles",
+        "exec.compile_ms",
+        "exec.compile_cache_hits",
         # device-cache residency (core/devcache.py, refreshed at scrape
         # time by server/node.py publish_cache_gauges)
         "devcache.resident_bytes",
@@ -638,6 +659,14 @@ class Registry:
             out.append(f"# TYPE {m} {mtype}")
             out.extend(lines)
         return "\n".join(out) + "\n"
+
+
+# Counters of work that belongs to the PROCESS and not to a node: the
+# compile listeners of exec/plan.py count here, where no NodeServer is in
+# reach, and every node's publish_cache_gauges copies the totals into its
+# own registry (in-process harness nodes share one jax, as they share
+# one device).
+PROCESS = Registry()
 
 
 class StatsClient:

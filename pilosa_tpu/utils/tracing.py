@@ -25,6 +25,18 @@ This module is the flight-recorder substrate:
 
 Cross-node context rides the `X-Pilosa-Trace-Id` / `X-Pilosa-Span-Id`
 headers.
+
+The profiler's clock: while a sampled span is entered (`with span:`) it
+also holds a `jax.profiler.TraceAnnotation(name, trace_id=...)`, so a
+profiler session finds the program's spans in its `/host:CPU` plane on
+the clock the device planes use (under a microsecond per span while no
+session is open). Synthetic spans (`record_span`: `sched.admit`,
+`exec.stage`) describe work that has already happened and cannot be
+annotated after the fact: they exist in the ring and the assembled tree
+only. Exported spans carry `startMonoNs`, the span's start on
+`time.monotonic()` in nanoseconds — on Linux the clock of
+`time.perf_counter()`, so a client on the same host can place a span
+between its own send and receive without a handshake.
 """
 
 from __future__ import annotations
@@ -33,9 +45,10 @@ import contextvars
 import random
 import threading
 import time
-import uuid
 from collections import deque
 from typing import Any, Deque, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from pilosa_tpu.utils.locks import TrackedLock
 from pilosa_tpu.utils.race import race_checked
@@ -51,13 +64,41 @@ from pilosa_tpu.utils.race import race_checked
 
 SPAN_NAMES = frozenset(
     {
-        # request roots (server/api.py)
+        # one served HTTP request, from before the body is read to after
+        # the reply's last write (server/handler.py: the query route and
+        # the two import routes); tags: http.route / http.bytes_in /
+        # http.bytes_out / http.read_ms / http.encode_ms, and
+        # http.write_ms, which is known only after the request's own
+        # ?profile=1 tree has left (ring and /debug/traces have it)
+        "http.request",
+        # PQL text -> call tree (server/api.py); tags: pql.family, the
+        # top-level call names ("Count", "TopN,Sum")
+        "api.parse",
+        # cost estimate, prefetch hand-off and scheduler.admit
+        # (server/api.py _admit); tags: sched.class / sched.wait_ms
+        "api.admit",
+        # execution after admission (server/api.py); the root when the
+        # API is called without the HTTP handler
         "api.query",
         "api.import",
-        # admission wait, recorded retroactively once the ticket is
-        # granted (server/api.py; the wait precedes the root span, so
-        # assembly clamps it and keeps the raw window)
+        # admission queue wait, recorded retroactively once the ticket is
+        # granted, and only when above 0 (server/api.py): a child of
+        # api.admit, or of api.query where no api.admit span is open (the
+        # wait then precedes its parent, so assembly clamps it and keeps
+        # the raw window)
         "sched.admit",
+        # one top-level call (exec/executor.py execute_response): the
+        # host work that is in no child — rank-cache walk, per-shard
+        # loops, result assembly; tags: pql.family
+        "exec.call",
+        # result cache (exec/executor.py _cache_lookup / _cache_store):
+        # key, lookup, revalidation, store; tags: cache.op / cache.hit
+        "exec.cache",
+        # a call becoming device operands (exec/executor.py _lower_roots,
+        # _group_by_stacked; exec/bsistream.py staging helpers): lowering
+        # and residency lookups, exec.stage is its child; tags:
+        # plan.family (stacked / bsi / groupby)
+        "exec.lower",
         # cross-request count batching rounds (exec/batcher.py):
         # leader-executed merges and ride-along waits
         "exec.batch",
@@ -66,7 +107,9 @@ SPAN_NAMES = frozenset(
         # per-thread accumulator fed by hbm/residency.py + core/devcache.py)
         "exec.stage",
         # one compiled dispatch under plan._DISPATCH_MU: lock wait vs
-        # device eval vs blocking host read (exec/plan.py)
+        # device eval vs blocking host read (exec/plan.py); tags:
+        # plan.family / plan.program (the jitted program as the
+        # profiler's "XLA Modules" line names it) / dispatch.compiled
         "exec.dispatch",
         # a whole distributed fan-out incl. re-map rounds
         # (exec/distributed.py)
@@ -112,6 +155,12 @@ def current_span():
     return _current.get()
 
 
+# ids: 64 random bits as 16 hex digits, from a generator of this module's
+# own (seeded from the OS; a test that seeds the global one must not make
+# ids repeat). Telling spans apart needs no urandom call per id, and
+# uuid4 was half of a span's cost
+_getrandbits = random.Random().getrandbits
+
 TRACE_HEADER = "X-Pilosa-Trace-Id"
 SPAN_HEADER = "X-Pilosa-Span-Id"
 
@@ -121,19 +170,20 @@ _RING = 1024
 def new_trace_id() -> str:
     """Fresh trace id (also used to stamp shed queries so a 429 is
     diagnosable from the client side without any span existing)."""
-    return uuid.uuid4().hex[:16]
+    return "%016x" % _getrandbits(64)
 
 
 class Span:
     __slots__ = ("tracer", "name", "trace_id", "span_id", "parent_id", "tags",
-                 "start", "start_mono", "duration", "sampled", "node", "_token")
+                 "start", "start_mono", "duration", "sampled", "node", "_token",
+                 "_annotation")
 
     def __init__(self, tracer, name, trace_id=None, parent_id=None,
                  sampled=True, node=""):
         self.tracer = tracer
         self.name = name
         self.trace_id = trace_id or new_trace_id()
-        self.span_id = uuid.uuid4().hex[:16]
+        self.span_id = "%016x" % _getrandbits(64)
         self.parent_id = parent_id
         self.tags: Dict[str, object] = {}
         # epoch start is DISPLAY/ordering only; duration is measured on
@@ -144,6 +194,7 @@ class Span:
         self.sampled = sampled
         self.node = node
         self._token = None
+        self._annotation = None
 
     def set_tag(self, key: str, value) -> "Span":
         self.tags[key] = value
@@ -157,9 +208,18 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._token = _current.set(self)
+        if self.sampled:
+            # the same span on the profiler's clock (module docstring)
+            self._annotation = TraceAnnotation(
+                self.name, trace_id=self.trace_id
+            )
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc) -> None:
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         if self._token is not None:
             _current.reset(self._token)
             self._token = None
@@ -173,6 +233,7 @@ class Span:
             "parentId": self.parent_id,
             "node": self.node,
             "start": self.start,
+            "startMonoNs": int(self.start_mono * 1e9),
             "durationMs": None if self.duration is None else self.duration * 1000,
             "tags": dict(self.tags),
         }
@@ -189,11 +250,13 @@ class Span:
         s.tags = dict(d.get("tags") or {})
         s.start = float(d.get("start") or 0.0)
         s.start_mono = 0.0  # foreign monotonic base is meaningless here
+        # (to_json then says startMonoNs 0, which assemble leaves out)
         dur = d.get("durationMs")
         s.duration = None if dur is None else float(dur) / 1000.0
         s.sampled = True
         s.node = d.get("node") or node
         s._token = None
+        s._annotation = None
         return s
 
 
@@ -519,6 +582,8 @@ def assemble(span_dicts: List[dict], trace_id: str) -> dict:
             "tags": dict(d.get("tags") or {}),
             "children": [],
         }
+        if d.get("startMonoNs"):  # absent on a span ingested from a peer
+            node["startMonoNs"] = d["startMonoNs"]
         if (start, end) != (raw_start, raw_start + raw_dur):
             node["raw"] = {
                 "startMs": round((raw_start - t0) * 1000.0, 3),
@@ -539,6 +604,15 @@ def assemble(span_dicts: List[dict], trace_id: str) -> dict:
         for d in sorted(by_parent.get(None, ()), key=lambda d: d.get("start") or 0.0)
     ]
     return {"traceId": trace_id, "spanCount": len(spans), "roots": roots}
+
+
+def assemble_open(span: Span, span_dicts: List[dict]) -> dict:
+    """The tree of `span`'s trace while `span` itself is still open (the
+    HTTP handler attaches the tree to the reply that its root span
+    covers): the open span enters with its duration so far."""
+    d = span.to_json()
+    d["durationMs"] = (time.monotonic() - span.start_mono) * 1000.0
+    return assemble(span_dicts + [d], span.trace_id)
 
 
 def _walk(node: dict):

@@ -307,7 +307,7 @@ class TestEndToEnd:
         yield srv
         srv.stop()
 
-    def test_concurrent_clients_share_dispatches(self, server):
+    def test_concurrent_clients_share_dispatches(self, server, monkeypatch):
         api = server.api
         api.create_index("bi")
         api.create_field("bi", "f")
@@ -319,12 +319,34 @@ class TestEndToEnd:
             f.import_bits(np.full(len(cols), row, np.uint64), cols)
         q = "Count(Intersect(Row(f=1), Row(f=2)))"
         (expect,) = api.query("bi", q)  # warm + truth
+        # a result-cache hit is tens of microseconds of pure Python, far
+        # under the interpreter's 5 ms switch interval, so on their own
+        # the clients run one after the other and never meet in the
+        # batcher. Each round's first leader therefore holds its
+        # execution until a second client has queued behind it (the
+        # Event of the unit tests above, end to end)
+        real_execute = server.executor.execute_response
+        held = []
+
+        def execute(*a, **k):
+            if not held:
+                held.append(True)
+                deadline = time.monotonic() + 5
+                while (
+                    not server.count_batcher._queue.get("bi")
+                    and time.monotonic() < deadline
+                ):
+                    time.sleep(0.001)
+            return real_execute(*a, **k)
+
+        monkeypatch.setattr(server.executor, "execute_response", execute)
         # overlap is timing-dependent, so retry the round until at least
         # one batch forms (locked STATS make the totals exact per round);
         # a round is milliseconds, and at 5 rounds none overlapped in about
         # one run in six on an 8-core host
         for _ in range(40):
             _reset_stats()
+            del held[:]
             results = []
             errs = []
 

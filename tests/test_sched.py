@@ -708,14 +708,36 @@ def test_ticket_released_even_when_span_construction_fails():
         srv.api.create_field("tl", "f", {"type": "set"})
         srv.api.query("tl", "Set(1, f=1)")
 
-        class BoomTracer:
-            def start_span(self, *a, **k):
-                raise RuntimeError("boom")
-
-            def start_span_from_headers(self, *a, **k):
-                raise RuntimeError("boom")
-
         orig = srv.tracer
+
+        class BoomTracer:
+            """Fails where api.query is built, which is after admission;
+            the handler's http.request root, opened before the body is
+            read, is the real tracer's."""
+
+            def start_span(self, name, *a, **k):
+                if name == "api.query":
+                    raise RuntimeError("boom")
+                return orig.start_span(name, *a, **k)
+
+            def start_span_from_headers(self, name, *a, **k):
+                if name == "api.query":
+                    raise RuntimeError("boom")
+                return orig.start_span_from_headers(name, *a, **k)
+
+            def __getattr__(self, attr):
+                return getattr(orig, attr)
+
+        # the failure has to land on a request that holds a ticket, or
+        # this test shows nothing: keep what admission granted
+        granted = []
+        real_admit = srv.scheduler.admit
+
+        def admit(*a, **k):
+            granted.append(real_admit(*a, **k))
+            return granted[-1]
+
+        srv.scheduler.admit = admit
         srv.tracer = BoomTracer()
         try:
             with pytest.raises(urllib.error.HTTPError) as ei:
@@ -724,6 +746,8 @@ def test_ticket_released_even_when_span_construction_fails():
             ei.value.close()
         finally:
             srv.tracer = orig
+            srv.scheduler.admit = real_admit
+        assert len(granted) == 1 and granted[0]._released
         assert srv.scheduler.pending() == (0, 0)
         # the single slot was NOT leaked: the next query runs
         status, body = _post_query(uri, "tl", "Row(f=1)")
