@@ -15,15 +15,20 @@ one process holds the chip and this parent never initialises a JAX backend
    program for, with `?profile=1`, and compares every answer with a plain
    numpy set-algebra reference computed here from the same seeded arrays;
    each cold answer must carry at least one `exec.dispatch` span
-   (unfiltered TopN is served from the rank cache: the one exception);
+   (unfiltered TopN is served from the rank cache: the one exception),
+   and the GroupBy's cross tally must have run as the VMEM kernel on one
+   chip (`groupby.kernel_tallies` on `/debug/vars`), as the XLA program
+   on several;
 4. writes (a PQL `Set`, then an `/import` burst large enough to cross the
    device-merge threshold), reads the writes back, restarts the server on
    the same data dir and reads them back again;
 5. restarts once more with PILOSA_TPU_PALLAS=1, repeats Count, filtered
    TopN and Sum and asks the two served queries that reach a Pallas
-   dispatch point, then runs all five `ops/bitmap.py` dispatch points and
-   the Pallas BSI sum directly at the real stack width in a child of
-   their own (the served path reaches only two of them).
+   dispatch point, then runs all five `ops/bitmap.py` dispatch points,
+   the Pallas BSI sum and the GroupBy cross tally directly at the real
+   stack width in a child of their own (the served path reaches only two
+   of the dispatch points, and its TopN rows are too sparse to be
+   tallied as stacks).
 
 Anything a phase raises ends the run non-zero; nothing is folded into the
 output. Times printed are smoke timings on a cold process, not metrics.
@@ -431,9 +436,42 @@ def ask(http_: Http, pql: str) -> tuple:
     return _normalise(out["results"][0]), n, took
 
 
-def run_queries(http_: Http, queries: list, cold: bool) -> None:
+def tally_counts(http_: Http) -> tuple:
+    """(kernel, xla) cross tallies so far (exec/groupby.py cross_tally)."""
+    vars_ = http_.call("GET", "/debug/vars")
+    return (int(vars_.get("groupby.kernel_tallies", 0)),
+            int(vars_.get("groupby.xla_tallies", 0)))
+
+
+def check_tally_program(family: str, kernel: int, xla: int,
+                        device: dict) -> None:
+    """A cold GroupBy or filtered TopN tallies its cross on the device.
+    On one TPU chip that is the VMEM kernel and never the XLA loop; stacks
+    sharded over several devices, or another backend, are the XLA
+    program's. A JAX upgrade that breaks the kernel fails here."""
+    one_chip = device["platform"] == "tpu" and device["count"] == 1
+    ran, other = (kernel, xla) if one_chip else (xla, kernel)
+    # a filtered TopN whose candidates are all sparse rows tallies no stack
+    if other or not (ran or family != "group_by"):
+        raise AssertionError(
+            f"{family}: {kernel} kernel and {xla} XLA cross tallies on "
+            f"{device['count']} x {device['platform']}"
+        )
+
+
+TALLY_FAMILIES = ("group_by", "topn_filtered", "pallas_topn_filtered")
+
+
+def run_queries(http_: Http, queries: list, cold: bool,
+                device: dict = None) -> None:
     for family, pql, want, min_dispatches in queries:
+        tallied = cold and device is not None and family in TALLY_FAMILIES
+        before = tally_counts(http_) if tallied else None
         got, n_dispatch, took = ask(http_, pql)
+        if tallied:
+            kernel, xla = (a - b for a, b in zip(tally_counts(http_), before))
+            check_tally_program(family, kernel, xla, device)
+            print(f"  {family:22s} cross tallies: kernel={kernel} xla={xla}")
         if got != want:
             raise AssertionError(f"{family}: {pql} -> {got!r}, want {want!r}")
         if cold and n_dispatch < min_dispatches:
@@ -476,14 +514,24 @@ def write_then_read(http_: Http, data: Data, ref: Reference) -> list:
     readback = [("Count(Row(f=1))", len(ref.row("f", 1)))]
     run_queries(http_, [("set_then_count", *readback[0], 0)], cold=False)
 
-    cols = data.unused_columns(np.arange(100, 100 + BURST_PER_SHARD))
+    readback.append(import_burst(http_, data, ref, 100))
+    return readback
+
+
+def import_burst(http_: Http, data: Data, ref: Reference,
+                 first_stratum: int) -> tuple:
+    """An /import burst of one new column per shard in each of
+    BURST_PER_SHARD strata from `first_stratum`, into f row 0, read back
+    by the next Count. Returns that (pql, count)."""
+    cols = data.unused_columns(
+        np.arange(first_stratum, first_stratum + BURST_PER_SHARD))
     for lo in range(0, len(cols), MAX_WRITES_PER_REQUEST):
         part = cols[lo:lo + MAX_WRITES_PER_REQUEST].tolist()
         http_.call("POST", f"/index/{INDEX}/field/f/import",
                    {"rows": [0] * len(part), "cols": part})
     ref.add_bits("f", 0, cols)
-    readback.append(("Count(Row(f=0))", len(ref.row("f", 0))))
-    run_queries(http_, [("import_then_count", *readback[1], 0)], cold=False)
+    readback = ("Count(Row(f=0))", len(ref.row("f", 0)))
+    run_queries(http_, [("import_then_count", *readback, 0)], cold=False)
     return readback
 
 
@@ -566,8 +614,9 @@ class Server:
 
 def pallas_kernels_child(seed: int) -> None:
     """Runs in a child of its own with PILOSA_TPU_PALLAS=1 after the last
-    server has exited: the five `ops/bitmap.py` dispatch points and the
-    Pallas BSI sum at the real stack width against numpy."""
+    server has exited: the five `ops/bitmap.py` dispatch points, the
+    Pallas BSI sum and the GroupBy cross tally at the real stack width
+    against numpy."""
     import jax
 
     if jax.devices()[0].platform != "tpu":
@@ -609,6 +658,22 @@ def pallas_kernels_child(seed: int) -> None:
         (int(got[0]), np.asarray(got[1]).tolist(), np.asarray(got[2]).tolist()),
         (want_sum[0], want_sum[1].tolist(), want_sum[2].tolist()),
     ))
+    # the GroupBy cross tally at the filtered TopN's shape (one filter
+    # row against a chunk of two dense candidate rows — the smoke's own
+    # rows are sparse, so its served TopN tallies no stack) and fused
+    # over three stacks with a filter
+    pair = np.stack([b, a ^ b])
+    pair_d = jax.device_put(pair)
+    per_shard = lambda x: bc(x).sum(axis=-1).tolist()  # noqa: E731
+    checks += [
+        ("cross_counts g=1",
+         np.asarray(pk.cross_counts(ad[None], pair_d)).tolist(),
+         per_shard(a[None, None] & pair[None])),
+        ("cross_counts fused",
+         np.asarray(pk.cross_counts(ad[None], pair_d, pair_d, bd)).tolist(),
+         per_shard((a & b)[None, None, None] & pair[None, :, None]
+                   & pair[None, None])),
+    ]
     for name, have, want in checks:
         if have != want:
             raise AssertionError(f"pallas {name}: {have!r} != {want!r}")
@@ -664,7 +729,7 @@ def main() -> None:
                   f"smoke timing {took:.1f} s")
 
         print("cold queries:")
-        run_queries(http_, queries, cold=True)
+        run_queries(http_, queries, cold=True, device=device)
         info = http_.call("GET", "/info")
         for d in info["devices"]:
             print(f"  device {d['id']}: bytes_in_use={d['bytesInUse']}")
@@ -676,10 +741,22 @@ def main() -> None:
         print("write then read:")
         before = merge_device_count(http_)
         readback = write_then_read(http_, data, ref)
-        moved = merge_device_count(http_) - before
-        print(f"  ingest.merge_device moved by {moved}")
-        if moved < 1:
-            raise AssertionError("the import burst did not run the device merge")
+        bursts = 1
+        while merge_device_count(http_) == before:
+            # the server's once-a-minute rank-cache flush
+            # (holder.flush_caches) merges each fragment's staged delta on
+            # the host as it passes; a burst staged while it runs reaches
+            # the read barrier in pieces under the device threshold. A
+            # warm compile cache puts this step about a minute after the
+            # server's start, so it happens: burst again on new columns
+            if bursts == 3:
+                raise AssertionError(
+                    "three import bursts did not run the device merge")
+            print("  the burst was merged on the host in pieces; once more")
+            readback[1] = import_burst(
+                http_, data, ref, 100 + bursts * BURST_PER_SHARD)
+            bursts += 1
+        print(f"  ingest.merge_device moved after {bursts} burst(s)")
         http_.close()
         srv.stop_clean()
 
@@ -700,7 +777,7 @@ def main() -> None:
         http_ = Http(srv.uri)
         check_device(http_.call("GET", "/info"))
         repeat, reaching = pallas_queries(ref)
-        run_queries(http_, repeat, cold=True)
+        run_queries(http_, repeat, cold=True, device=device)
         if device["count"] == 1:
             run_queries(http_, reaching, cold=True)
         else:

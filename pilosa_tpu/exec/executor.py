@@ -2883,7 +2883,7 @@ class Executor:
                 parts.append(
                     planmod.run_serialized(
                         lambda src=src, planes=planes, n=len(ids):
-                        gb._counts_cross(src[None], planes)[0][:n, :n_present]
+                        gb.cross_tally(src[None], planes)[0][:n, :n_present]
                     )
                 )
                 order.extend(ids)
@@ -3280,7 +3280,8 @@ class Executor:
         # a profile shows the answer came from the device
         return planmod.run_counted(
             lambda: qgb.group_by_device(planes_list, child_rows, filt),
-            read=False, family="groupby", program="jit__counts_cross",
+            read=False, family="groupby",
+            program=qgb.tally_program(planes_list, filt),
         )
 
     def _group_by_shard(  # dispatch-ok: per-shard path, single-device

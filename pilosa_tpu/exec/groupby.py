@@ -3,12 +3,7 @@
 TPU-native replacement for the reference's groupByIterator
 (/root/reference/executor.go:3063), which walks the rows cross-product one
 group element at a time — in the round-1 rebuild that meant one device
-dispatch + host sync per (group-prefix, depth). Here the tally is
-level-wise and batched: at depth d, ONE jitted call computes
-popcount(acc[g] & planes[r]) for every live prefix g and every candidate
-row r across all shards at once, and one host read prunes zero groups
-before descending. Dispatch count is O(depth x chunks), independent of the
-number of groups.
+dispatch + host sync per (group-prefix, depth).
 
 Shapes: `planes` stacks are uint32[R, S, W] (candidate rows x shards x
 words, built by View.plane_stack and shard-axis-sharded under an active
@@ -16,10 +11,27 @@ mesh); the accumulator `acc` is uint32[G, S, W] for the G live prefixes.
 Counts are reduced over W on device in uint32 (one shard holds at most
 2^20 bits, so a per-shard count can never wrap) and over the shard axis
 on the host in exact uint64 — the same overflow discipline as
-StackedPlan.count (exec/plan.py). The [G, R, S] host transfer stays small
-because the prefix tile G shrinks as S grows (G*S*W*4 <= tile bytes).
+StackedPlan.count (exec/plan.py).
 
-Memory is bounded by processing prefixes depth-first in chunks of at most
+Two programs tally a cross (`cross_tally` picks by what its operands are):
+
+- `ops/pallas_kernels.cross_counts`, a VMEM kernel, for stacks that live
+  on ONE TPU: every operand row is read from HBM once per tally, the
+  whole G x R cross of AND + popcount is formed on tiles in VMEM, and
+  with a third stack the last prefix level (acc[g] & mid[m]) is formed
+  there too and never written to HBM. A 2- or 3-level GroupBy whose
+  [groups, S] count read fits `_ONESHOT_READ_BYTES` is therefore ONE
+  launch and ONE host read whatever the number of shards.
+- `_counts_cross`, the XLA program (a lax.map over the candidate rows
+  that re-reads `acc` per row), for every other backend and for stacks
+  sharded over a mesh; it is also the kernel's differential oracle. Its
+  [G, S, W] intermediate is why prefixes are chunked for it.
+
+Cross-products too deep or too large for one read are tallied level-wise:
+at depth d one tally counts every live prefix against every candidate
+row, and one host read prunes zero groups before descending. Dispatch
+count is O(depth x chunks), independent of the number of groups. Prefixes
+materialised in HBM are processed depth-first in chunks of at most
 `_gmax()` rows (PILOSA_TPU_GROUPBY_TILE_MB, default 256 MB per tile), so
 live device memory is <= depth * tile regardless of group fan-out. Chunk
 index vectors are padded to powers of two to bound recompilation.
@@ -34,12 +46,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# Dispatch accounting (tests assert O(depth), not O(groups), dispatches).
-STATS = {"evals": 0}
+# Dispatch accounting (tests assert O(depth), not O(groups), dispatches);
+# the two tally counts are published as groupby.* gauges (server/node.py).
+STATS = {"evals": 0, "kernel_tallies": 0, "xla_tallies": 0}
+
+KERNEL_PROGRAM = "jit__cross_counts_vmem"
+XLA_PROGRAM = "jit__counts_cross"
 
 
 def reset_stats() -> None:
-    STATS["evals"] = 0
+    for k in STATS:
+        STATS[k] = 0
 
 
 def _tile_bytes() -> int:
@@ -82,6 +99,49 @@ def _counts_cross(acc, planes):
 
     out = jax.lax.map(per_row, planes)  # [R, G, S]
     return jnp.transpose(out, (1, 0, 2))
+
+
+def _kernel_covers(*stacks) -> bool:
+    """Whether the VMEM kernel tallies these operands: all of them on one
+    TPU, rows a whole number of lanes. Read from the arrays themselves —
+    a mesh-sharded stack, another backend or a host array is the XLA
+    program's."""
+    for x in stacks:
+        if x is None:
+            continue
+        devices = getattr(x, "devices", None)
+        if devices is None or x.shape[-1] % 128:
+            return False
+        devices = devices()
+        if len(devices) != 1 or next(iter(devices)).platform != "tpu":
+            return False
+    return True
+
+
+def tally_program(planes_list: Sequence[jax.Array], filt=None) -> str:
+    """The jitted program that will tally this GroupBy, as the profiler's
+    "XLA Modules" line names it (the exec.dispatch span's plan.program)."""
+    return KERNEL_PROGRAM if _kernel_covers(*planes_list, filt) else XLA_PROGRAM
+
+
+def cross_tally(acc, planes, mid=None, filt=None):  # dispatch-ok: caller holds dispatch_mutex
+    """Per-shard counts uint32[G, R, S] of popcount(acc[g] & planes[r]) —
+    with `mid`, uint32[G, M, R, S] of acc[g] & mid[m] & planes[r]; `filt`
+    uint32[S, W] masks acc. The kernel where it covers the operands, else
+    the XLA program over prefixes materialised here (callers keep G x M
+    within `_gmax` for it)."""
+    if _kernel_covers(acc, planes, mid, filt):
+        from pilosa_tpu.ops import pallas_kernels
+
+        STATS["kernel_tallies"] += 1
+        return pallas_kernels.cross_counts(acc, planes, mid, filt)
+    STATS["xla_tallies"] += 1
+    if filt is not None:
+        acc = jnp.bitwise_and(acc, filt[None])
+    if mid is None:
+        return _counts_cross(acc, planes)
+    out = _counts_cross(_cross_expand(acc, mid), planes)
+    return out.reshape(acc.shape[0], mid.shape[0], *out.shape[1:])
 
 
 def _host_sum(counts) -> np.ndarray:
@@ -137,23 +197,28 @@ def group_by_device(  # dispatch-ok: caller holds dispatch_mutex
     depth_n = len(planes_list)
     s, w = planes_list[0].shape[-2], planes_list[0].shape[-1]
     gmax = _gmax(s, w)
+    kernel = _kernel_covers(*planes_list, filt)
 
-    # One-shot path for small cross-products: build the full prefix
-    # accumulator on device with NO intermediate host reads, tally the
-    # last level, read ONCE. The pruned descent below costs one blocking
-    # read per depth — a synchronisation each, pure latency — and
-    # pruning only pays when the cross-product is too big to materialize
-    # anyway.
-    g_pre = 1
-    for p in planes_list[:-1]:
-        g_pre *= int(p.shape[0])
-    read_cells = g_pre * int(planes_list[-1].shape[0]) * s * 4
-    if g_pre <= gmax and read_cells <= _ONESHOT_READ_BYTES:
-        return _group_by_oneshot(planes_list, row_lists, filt)
+    # One-shot path for small cross-products: NO intermediate host reads,
+    # tally the last level, read ONCE. The pruned descent below costs one
+    # blocking read per depth — a synchronisation each, pure latency — and
+    # pruning only pays when the count read is too big to take whole.
+    # What bounds it besides the read is the prefix rows it must hold in
+    # HBM at once: all of them for the XLA program (its tally holds a
+    # [G, S, W] intermediate even when G is an operand as staged); for the
+    # kernel, which forms the last prefix level in VMEM, only the cross of
+    # the levels before the last two — nothing up to three levels.
+    rows = [int(p.shape[0]) for p in planes_list]
+    g_pre = int(np.prod(rows[:-1], dtype=np.int64))
+    read_cells = g_pre * rows[-1] * s * 4
+    held = rows[:-1] if not kernel else rows[:-2] if depth_n > 3 else []
+    g_held = int(np.prod(held, dtype=np.int64))
+    if g_held <= gmax and read_cells <= _ONESHOT_READ_BYTES:
+        return _group_by_oneshot(planes_list, row_lists, filt, kernel)
 
     # Depth 0: counts for every candidate row of the first child.
     if filt is not None:
-        h = _host_sum(_counts_cross(filt[None], planes_list[0])[0])
+        h = _host_sum(cross_tally(filt[None], planes_list[0])[0])
     else:
         h = _host_sum(_counts_planes(planes_list[0]))
     STATS["evals"] += 1
@@ -180,29 +245,39 @@ def _group_by_oneshot(  # dispatch-ok: caller holds dispatch_mutex
     planes_list: Sequence[jax.Array],
     row_lists: Sequence[Sequence[int]],
     filt: Optional[jax.Array],
+    kernel: bool,
 ) -> Dict[Tuple[int, ...], int]:
     """Whole cross-product in one fused device pipeline + ONE host read.
     Zero-count groups are pruned at merge (same contract as the descent).
-    All dispatches are async; only the final np.asarray blocks."""
+    All dispatches are async; only the final np.asarray blocks. With the
+    kernel the filter and the last prefix level ride inside the tally, so
+    up to three levels are a single launch."""
     merged: Dict[Tuple[int, ...], int] = {}
+    n = len(planes_list)
     acc = planes_list[0]
-    if filt is not None:
+    if filt is not None and (n == 1 or not kernel):
         acc = _select_rows_filtered(acc, np.arange(acc.shape[0]), filt)
         STATS["evals"] += 1
+        filt = None
     keys: List[Tuple[int, ...]] = [(int(r),) for r in row_lists[0]]
-    for d in range(1, len(planes_list) - 1):
-        acc = _cross_expand(acc, planes_list[d])
-        STATS["evals"] += 1
-        keys = [k + (int(r),) for k in keys for r in row_lists[d]]
-    if len(planes_list) == 1:
+    if n == 1:
         h = _host_sum(_counts_planes(acc))
         STATS["evals"] += 1
         for i, cnt in enumerate(h):
             if cnt:
                 merged[keys[i]] = int(cnt)
         return merged
+    mid = None
+    for d in range(1, n - 1):
+        if kernel and d == n - 2:
+            mid = planes_list[d]  # the last prefix level: formed in the tally
+        else:
+            acc = _cross_expand(acc, planes_list[d])
+            STATS["evals"] += 1
+        keys = [k + (int(r),) for k in keys for r in row_lists[d]]
     last_rows = row_lists[-1]
-    h = _host_sum(_counts_cross(acc, planes_list[-1]))  # [G, R_last]
+    h = _host_sum(cross_tally(acc, planes_list[-1], mid, filt))
+    h = h.reshape(len(keys), len(last_rows))  # [G(, M), R_last] -> [G, R_last]
     STATS["evals"] += 1
     gs, rs = np.nonzero(h)
     for g, r in zip(gs, rs):
@@ -219,7 +294,7 @@ def _descend(  # dispatch-ok: caller holds dispatch_mutex
     merged: Dict[Tuple[int, ...], int],
     gmax: int,
 ) -> None:
-    h = _host_sum(_counts_cross(acc, planes_list[depth]))[: len(prefixes)]
+    h = _host_sum(cross_tally(acc, planes_list[depth]))[: len(prefixes)]
     STATS["evals"] += 1
     gs, rs = np.nonzero(h)
     if depth == len(planes_list) - 1:

@@ -5,8 +5,8 @@ roaring/roaring.go:3121, popcount :5291, the TopN tally fragment.go:1570,
 BSI sum fragment.go:1111) as explicit single-pass VMEM kernels: one HBM
 read per operand, popcount + reduce fused on the VPU, sequential-grid
 accumulation into SMEM/VMEM partials. The jnp paths in ops/bitmap.py /
-ops/bsi.py compute the same functions (XLA usually fuses them well) and
-serve as the differential oracle.
+ops/bsi.py / exec/groupby.py compute the same functions and serve as the
+differential oracle.
 
 All kernels:
 - operate on uint32 word arrays (bit b of word w = position 32w+b),
@@ -17,19 +17,21 @@ All kernels:
   (`pltpu.force_tpu_interpret_mode()`); anywhere else a backend that
   cannot compile the kernel raises.
 
-Disposition (r5, closing VERDICT r4 weak #7): these kernels are RETAINED
-AS ORACLE ONLY, default-off behind PILOSA_TPU_PALLAS=1 (ops/bitmap.py).
-The op mix is VPU/HBM-bound and XLA already fuses and tiles it. The one
-declared Pallas candidate win — the filtered-TopN gather+mask+popcount
-tally — was implemented as a plain XLA program instead (ops/bitmap.py
-gather_tally_sorted: gather + cumsum segments, no scatter); a hand
-kernel would save nothing further because the query's end-to-end cost
-is dominated by the single host read.
+Disposition. The count / per-row / BSI-sum kernels are RETAINED AS ORACLE
+ONLY, default-off behind PILOSA_TPU_PALLAS=1 (ops/bitmap.py): that op mix
+is one pass over its operands either way, and XLA already fuses and tiles
+it (88 % of the HBM roofline in the segment cell, PERF.md). `cross_counts`
+is the exception and SHIPS ON THE DEFAULT PATH (exec/groupby.py
+cross_tally, whenever the operands are on one TPU): the GroupBy cross
+tally is a G x R cross over multi-GB stacks, which XLA ran as a per-row
+slice-and-copy loop at 0.4 % of the roofline (PERF.md, PR 27); tiling the
+cross in VMEM reads every row once.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -252,3 +254,262 @@ def sum_counts(planes, exists, sign, filter_words, bit_depth: int):
     )(planes.astype(jnp.uint32), rows)
     col = out[:, 0].astype(jnp.uint32)
     return col[0], col[1 : 1 + bit_depth], col[1 + bit_depth :]
+
+
+# -- GroupBy cross tally (exec/groupby.py; reference executor.go:3063) -------
+
+# The tally never reshapes or copies a stack: it reads each [rows, S, W]
+# operand in the layout the device already keeps it in. The TPU runtime
+# picks that layout from the shape. "Shard-major" (S outermost, a tile =
+# 8 rows x 128 words of one shard) is what a stack of 1, 2, 4 or 8k rows
+# gets when S is not a multiple of 8 (954 shards: every benchmark stack);
+# otherwise the row-major default (a tile = 8 shards x 128 words of one
+# row). One kernel body per layout; an operand in the other layout is
+# still answered exactly, after a relayout copy XLA inserts.
+_CROSS_ROWS = (8, 8, 16)  # acc / mid / planes rows per grid step
+_CROSS_PARTS = 32  # partial-count vregs carried through the word loop
+_CROSS_WORDS = {True: 32768, False: 4096}  # words of a row per grid step
+_CROSS_VMEM_BYTES = 40 << 20
+_SUBLANES = 8
+
+
+def _cross_refs(mt: int, has_filt: bool, refs):
+    refs = list(refs)
+    acc_ref = refs.pop(0)
+    filt_ref = refs.pop(0) if has_filt else None
+    mid_ref = refs.pop(0) if mt else None
+    planes_ref, out_ref = refs
+
+    @pl.when(pl.program_id(4) == 0)
+    def _init():
+        out_ref[:] = jnp.zeros_like(out_ref)
+
+    return acc_ref, filt_ref, mid_ref, planes_ref, out_ref
+
+
+def _lane_slice(j):
+    return pl.ds(pl.multiple_of(j * _LANES, _LANES), _LANES)
+
+
+def _word_loop(steps: int, body, init):
+    """The loop over a block's 128-word columns, unrolled while the
+    partials of the unrolled bodies still fit the vreg budget (a body of
+    one or two pairs is otherwise mostly loop overhead)."""
+    unroll = max(1, min(8, _CROSS_PARTS // len(init)))
+    while steps % unroll:
+        unroll -= 1
+
+    def unrolled(i, parts):
+        for k in range(unroll):
+            parts = body(i * unroll + k, parts)
+        return parts
+
+    return jax.lax.fori_loop(0, steps // unroll, unrolled, init)
+
+
+def _tally_step(parts, pre, row, acc_ref, filt, mid_ref, ps):
+    """parts += popcount(acc[g] (& filt) (& mid[m]) & p) for every (g, m)
+    of `pre` and candidate tile p of `ps`, prefix-major; `row(ref, i)`
+    loads row i's tile, each operand row once."""
+    accs = {g: row(acc_ref, g) for g in {g for g, _ in pre}}
+    if filt is not None:
+        accs = {g: a & filt for g, a in accs.items()}
+    if mid_ref is None:
+        ts = [accs[g] for g, _ in pre]
+    else:
+        mids = {m: row(mid_ref, m) for m in {m for _, m in pre}}
+        ts = [accs[g] & mids[m] for g, m in pre]
+    pcs = [
+        jax.lax.population_count(jnp.bitwise_and(t, p).astype(jnp.int32))
+        for t in ts
+        for p in ps
+    ]
+    return tuple(a + b for a, b in zip(parts, pcs))
+
+
+def _cross_kernel_shard_major(gt, mt, rt, has_filt, *refs):
+    """One grid step on [rows, wt words] blocks of ONE shard: a vreg holds
+    128 words of 8 candidate rows, each prefix row is broadcast over the
+    sublanes. Partials [8 rows, 128 lanes] per (prefix, 8-row slab) are
+    carried through the word loop and reduced across lanes once, into lane
+    `prefix` of the resident out block [rt, 128]. mt == 0: no mid level."""
+    acc_ref, filt_ref, mid_ref, planes_ref, out_ref = _cross_refs(
+        mt, has_filt, refs
+    )
+    steps = planes_ref.shape[-1] // _LANES
+    m_n = max(mt, 1)
+    prefixes = [(g, m) for g in range(gt) for m in range(m_n)]
+    slabs = [
+        (r0, min(r0 + _SUBLANES, rt)) for r0 in range(0, rt, _SUBLANES)
+    ]
+    sub_p = max(1, _CROSS_PARTS // len(slabs))
+    for p0 in range(0, len(prefixes), sub_p):
+        pre = prefixes[p0 : p0 + sub_p]
+
+        def body(j, parts, pre=pre):
+            sl = _lane_slice(j)
+            return _tally_step(
+                parts, pre, lambda ref, i: ref[i : i + 1, sl], acc_ref,
+                filt_ref[:, sl] if has_filt else None, mid_ref,
+                [planes_ref[lo:hi, sl] for lo, hi in slabs],
+            )
+
+        zeros = tuple(
+            jnp.zeros((hi - lo, _LANES), jnp.int32) for lo, hi in slabs
+        )
+        parts = _word_loop(steps, body, zeros * len(pre))
+        for v, (lo, hi) in enumerate(slabs):
+            lane = jax.lax.broadcasted_iota(jnp.int32, (hi - lo, _LANES), 1)
+            tile = zeros[v]
+            for k in range(len(pre)):
+                col = jnp.sum(parts[k * len(slabs) + v], axis=1, keepdims=True)
+                tile = jnp.where(lane == p0 + k, col, tile)
+            out_ref[lo:hi, :] += tile
+
+
+def _cross_kernel_row_major(gt, mt, rt, has_filt, *refs):
+    """One grid step on [rows, 8 shards, wt words] blocks: a vreg holds the
+    same 128 words of 8 shards of one row, so a pair's partial
+    [8 shards, 128 lanes] reduces across lanes to 8 per-shard counts, put
+    in lane `pair` of the resident out block [8, pairs]."""
+    acc_ref, filt_ref, mid_ref, planes_ref, out_ref = _cross_refs(
+        mt, has_filt, refs
+    )
+    steps = planes_ref.shape[-1] // _LANES
+    m_n = max(mt, 1)
+    prefixes = [(g, m) for g in range(gt) for m in range(m_n)]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (_SUBLANES, _LANES), 1)
+    zero = jnp.zeros((_SUBLANES, _LANES), jnp.int32)
+    sub_r = min(rt, _SUBLANES)
+    sub_p = max(1, _CROSS_PARTS // sub_r)
+    for p0 in range(0, len(prefixes), sub_p):
+        pre = prefixes[p0 : p0 + sub_p]
+        for r0 in range(0, rt, sub_r):
+            rows = range(r0, min(r0 + sub_r, rt))
+
+            def body(j, parts, pre=pre, rows=rows):
+                sl = _lane_slice(j)
+                return _tally_step(
+                    parts, pre, lambda ref, i: ref[i, :, sl], acc_ref,
+                    filt_ref[:, sl] if has_filt else None, mid_ref,
+                    [planes_ref[r, :, sl] for r in rows],
+                )
+
+            parts = _word_loop(steps, body, (zero,) * (len(pre) * len(rows)))
+            upd = {}
+            for k, (g, m) in enumerate(pre):
+                for n, r in enumerate(rows):
+                    pair = (g * m_n + m) * rt + r
+                    col = jnp.sum(
+                        parts[k * len(rows) + n], axis=1, keepdims=True
+                    )
+                    c = pair // _LANES
+                    upd[c] = jnp.where(
+                        lane == pair % _LANES, col, upd.get(c, zero)
+                    )
+            for c, u in upd.items():
+                out_ref[:, c * _LANES : (c + 1) * _LANES] += u
+
+
+@functools.partial(jax.jit, static_argnames=("shard_major",))
+def _cross_counts_vmem(acc, planes, mid, filt, shard_major: bool):
+    g_n, s_n, w = acc.shape
+    r_n = planes.shape[0]
+    m_n = 1 if mid is None else mid.shape[0]
+    assert w % _LANES == 0, f"row width {w} not a lane multiple"
+    wt = math.gcd(w, _CROSS_WORDS[shard_major])
+    gt, rt = min(g_n, _CROSS_ROWS[0]), min(r_n, _CROSS_ROWS[2])
+    mt = 0 if mid is None else min(m_n, _CROSS_ROWS[1])
+    pre = gt * max(mt, 1)  # prefixes per grid step
+    n_g, n_m, n_r = pl.cdiv(g_n, gt), pl.cdiv(m_n, max(mt, 1)), pl.cdiv(r_n, rt)
+    pairs = pre * rt
+    if shard_major:
+        # [rows, S, W] seen as [S, rows, W]: no data moves for an operand
+        # the device keeps shard-major
+        def view(x):
+            return x.transpose(1, 0, 2)
+
+        def spec(t, axis):
+            return pl.BlockSpec((None, t, wt), lambda *i: (i[0], i[axis], i[4]))
+
+        filt_view = None if filt is None else filt[:, None, :]
+        filt_spec = pl.BlockSpec((None, 1, wt), lambda s, g, m, r, j: (s, 0, j))
+        kernel, n_s = _cross_kernel_shard_major, s_n
+        out_block, lanes = (None, None, rt, _LANES), _LANES
+        assert pre <= _LANES
+    else:
+        def view(x):
+            return x
+
+        def spec(t, axis):
+            return pl.BlockSpec(
+                (t, _SUBLANES, wt), lambda *i: (i[axis], i[0], i[4])
+            )
+
+        filt_view = filt
+        filt_spec = pl.BlockSpec((_SUBLANES, wt), lambda s, g, m, r, j: (s, j))
+        kernel, n_s = _cross_kernel_row_major, pl.cdiv(s_n, _SUBLANES)
+        lanes = pl.cdiv(pairs, _LANES) * _LANES
+        out_block = (None, None, _SUBLANES, lanes)
+    in_specs, args = [spec(gt, 1)], [view(acc)]
+    if filt is not None:
+        in_specs.append(filt_spec)
+        args.append(filt_view)
+    if mt:
+        in_specs.append(spec(mt, 2))
+        args.append(view(mid))
+    in_specs.append(spec(rt, 3))
+    args.append(view(planes))
+    out = pl.pallas_call(
+        functools.partial(kernel, gt, mt, rt, filt is not None),
+        out_shape=jax.ShapeDtypeStruct(
+            (n_g * n_m * n_r, n_s) + out_block[2:], jnp.int32
+        ),
+        grid=(n_s, n_g, n_m, n_r, w // wt),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec(
+            out_block, lambda s, g, m, r, j: ((g * n_m + m) * n_r + r, s, 0, 0)
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * 4 + ("arbitrary",),
+            vmem_limit_bytes=_CROSS_VMEM_BYTES,
+        ),
+        name="cross_counts",
+    )(*[a.astype(jnp.uint32) for a in args])
+    # per-tile counts -> [G, M, R, S]: a few MB at most
+    if shard_major:  # [tile, S, rt, prefix]
+        out = out[..., :pre].transpose(0, 3, 2, 1)
+    else:  # [tile, S/8, 8, pair]
+        out = out.reshape(-1, n_s * _SUBLANES, lanes)[:, :s_n, :pairs]
+        out = out.reshape(-1, s_n, pre, rt).transpose(0, 2, 3, 1)
+    out = out.reshape(n_g, n_m, n_r, gt, pre // gt, rt, s_n)
+    out = out.transpose(0, 3, 1, 4, 2, 5, 6).reshape(
+        n_g * gt, n_m * (pre // gt), n_r * rt, s_n
+    )[:g_n, :m_n, :r_n].astype(jnp.uint32)
+    return out if mt else out[:, 0]
+
+
+def _shard_major(x) -> bool:
+    """Whether the device keeps stack x [rows, S, W] with the shard axis
+    outermost (read from the array; host arrays and tracers: no)."""
+    layout = getattr(getattr(x, "format", None), "layout", None)
+    order = getattr(layout, "major_to_minor", None)
+    return order is not None and tuple(order)[0] == 1
+
+
+def cross_counts(  # dispatch-ok: wrapper; callers serialize (run_serialized)
+    acc, planes, mid=None, filt=None, shard_major: Optional[bool] = None
+) -> jnp.ndarray:
+    """GroupBy cross tally in one pass over its rows.
+
+    acc uint32[G, S, W] x planes uint32[R, S, W] -> per-shard counts
+    uint32[G, R, S] of popcount(acc[g] & planes[r]); with mid uint32[M, S, W]
+    the last prefix level is formed in VMEM, never in HBM, and the result
+    is uint32[G, M, R, S] of popcount(acc[g] & mid[m] & planes[r]). filt
+    uint32[S, W] is one more AND on the acc tiles. Each grid step brings
+    its tile of every operand row into VMEM once and forms the whole cross
+    there; only the counts are written. `shard_major` names the kernel
+    body; left None it follows the layout of the candidate rows."""
+    if shard_major is None:
+        shard_major = _shard_major(planes)
+    return _cross_counts_vmem(acc, planes, mid, filt, shard_major=shard_major)

@@ -836,6 +836,7 @@ class NodeServer:
         # exec.compiles that moves in a steady state is a program
         # compiled per request shape
         from pilosa_tpu.exec import batcher as batcher_mod
+        from pilosa_tpu.exec import groupby as groupby_mod
         from pilosa_tpu.exec import plan as plan_mod
         from pilosa_tpu.utils.stats import PROCESS
 
@@ -848,6 +849,14 @@ class NodeServer:
         self.stats.gauge(
             "exec.compile_cache_hits",
             PROCESS.total_counter("exec.compile_cache_hits"),
+        )
+        # which program tallied the GroupBy / filtered-TopN crosses
+        # (exec/groupby.py cross_tally): the VMEM kernel or the XLA loop
+        self.stats.gauge(
+            "groupby.kernel_tallies", groupby_mod.STATS["kernel_tallies"]
+        )
+        self.stats.gauge(
+            "groupby.xla_tallies", groupby_mod.STATS["xla_tallies"]
         )
         self.stats.gauge("batcher.leader", batcher_mod.STATS["leader"])
         self.stats.gauge("batcher.batched", batcher_mod.STATS["batched"])

@@ -93,6 +93,12 @@ STAT_NAMES = frozenset(
         "exec.compiles",
         "exec.compile_ms",
         "exec.compile_cache_hits",
+        # cross tallies of GroupBy and filtered TopN (exec/groupby.py
+        # cross_tally, published at scrape time): how many ran as the VMEM
+        # kernel (stacks on one TPU) and how many as the XLA program (any
+        # other backend, mesh-sharded stacks)
+        "groupby.kernel_tallies",
+        "groupby.xla_tallies",
         # device-cache residency (core/devcache.py, refreshed at scrape
         # time by server/node.py publish_cache_gauges)
         "devcache.resident_bytes",

@@ -25,7 +25,17 @@ def test_smoke_parts_agree_with_a_served_index_small():
         queries = cs.read_queries(ref)
         cs.create_schema(http_)
         assert set(cs.load(uri, data)) == {"f", "g", "h", "v"}
-        cs.run_queries(http_, queries, cold=True)
+        # a CPU server tallies its GroupBy and filtered TopN with the
+        # XLA program, and the smoke's counter check says so
+        device = {"platform": "cpu", "kind": "cpu", "count": 1}
+        before = cs.tally_counts(http_)
+        cs.run_queries(http_, queries, cold=True, device=device)
+        kernel, xla = (a - b for a, b in zip(cs.tally_counts(http_), before))
+        assert kernel == 0 and xla >= 1
+        with pytest.raises(AssertionError, match="cross tallies"):
+            cs.check_tally_program(
+                "group_by", kernel, xla,
+                {"platform": "tpu", "kind": "TPU v5 lite", "count": 1})
         cs.run_queries(http_, queries, cold=False)
         readback = cs.write_then_read(http_, data, ref)
         assert [n for _, n in readback] == [

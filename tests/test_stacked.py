@@ -421,6 +421,109 @@ class TestStackedGroupBy:
         assert self._as_t(got) == self._as_t(want)
         assert got
 
+    @staticmethod
+    def _engage_kernel(monkeypatch, qgb, gmax=2):
+        """What a one-chip TPU presents, steered from the test: the tally
+        takes the VMEM kernel (interpreted here) and the prefix tile holds
+        `gmax` rows, as 256 MB does at 954 shards."""
+        monkeypatch.setattr(qgb, "_kernel_covers", lambda *stacks: True)
+        monkeypatch.setattr(qgb, "_gmax", lambda s, w: gmax)
+
+    @pytest.mark.parametrize(
+        "query,launches",
+        [
+            ("GroupBy(Rows(a), Rows(b))", 1),
+            ("GroupBy(Rows(a), Rows(b), Rows(c))", 1),
+            ("GroupBy(Rows(a), Rows(b), Rows(c), filter=Row(a=1))", 1),
+            ("GroupBy(Rows(a), Rows(b), filter=Row(c=1))", 1),
+            # four levels: the cross of the first two is written out (one
+            # expand, within the tile), the last two ride in the kernel
+            ("GroupBy(Rows(c), Rows(c), Rows(a), Rows(b))", None),
+        ],
+    )
+    def test_kernel_tallies_up_to_three_levels_in_one_launch(
+        self, holder, monkeypatch, query, launches
+    ):
+        """With the kernel a 2- or 3-field GroupBy is ONE launch and one
+        read at a prefix tile of two rows, where the XLA program has to
+        chunk its prefixes by two and descend."""
+        from jax.experimental.pallas import tpu as pltpu
+
+        from pilosa_tpu.core.resultcache import RESULT_CACHE
+        from pilosa_tpu.exec import groupby as qgb
+
+        self._mk_gb(holder)
+        ex = Executor(holder)
+        want = self._serial(ex, monkeypatch, query)
+        assert want
+        with monkeypatch.context() as m:
+            m.setattr(qgb, "_gmax", lambda s, w: 2)
+            RESULT_CACHE.reset()  # each run below must execute
+            qgb.reset_stats()
+            assert self._as_t(ex.execute("gb", query)[0]) == self._as_t(want)
+            chunked = dict(qgb.STATS)
+        assert chunked["evals"] > 3 and chunked["kernel_tallies"] == 0
+        if launches is None:  # 3 x 3 prefixes do not fit a tile of two
+            self._engage_kernel(monkeypatch, qgb, gmax=9)
+            launches = 2
+        else:
+            self._engage_kernel(monkeypatch, qgb)
+        RESULT_CACHE.reset()
+        qgb.reset_stats()
+        with pltpu.force_tpu_interpret_mode():
+            got = ex.execute("gb", query)[0]
+        assert self._as_t(got) == self._as_t(want), query
+        assert qgb.STATS == {
+            "evals": launches, "kernel_tallies": 1, "xla_tallies": 0,
+        }
+
+    def test_kernel_replaces_the_xla_tally_inside_the_descent(
+        self, holder, monkeypatch
+    ):
+        """A cross-product whose count read is too large to take whole is
+        still pruned level by level; every tally of the descent is then
+        the two-operand kernel."""
+        from jax.experimental.pallas import tpu as pltpu
+
+        from pilosa_tpu.core.resultcache import RESULT_CACHE
+        from pilosa_tpu.exec import groupby as qgb
+
+        self._mk_gb(holder)
+        ex = Executor(holder)
+        query = "GroupBy(Rows(a), Rows(b), Rows(c), filter=Row(c=0))"
+        want = self._serial(ex, monkeypatch, query)
+        self._engage_kernel(monkeypatch, qgb)
+        monkeypatch.setattr(qgb, "_ONESHOT_READ_BYTES", 64)
+        RESULT_CACHE.reset()
+        qgb.reset_stats()
+        with pltpu.force_tpu_interpret_mode():
+            got = ex.execute("gb", query)[0]
+        assert self._as_t(got) == self._as_t(want)
+        assert qgb.STATS["xla_tallies"] == 0
+        assert qgb.STATS["kernel_tallies"] > 3  # depth 0, then per chunk
+
+    def test_kernel_covers_only_stacks_on_one_tpu(self, holder):
+        """The choice of program is read from the operands: host arrays,
+        another backend and mesh-sharded stacks are the XLA program's."""
+        from pilosa_tpu.exec import groupby as qgb
+
+        host = np.zeros((2, 4, 256), np.uint32)
+        dev = jax.numpy.asarray(host)
+        mesh = pmesh.make_mesh(jax.devices())
+        pmesh.set_active_mesh(mesh)
+        try:
+            sharded = pmesh.put_stack(np.zeros((2, 8, 256), np.uint32))
+        finally:
+            pmesh.set_active_mesh(None)
+        assert len(sharded.devices()) > 1
+        for stack in (host, dev, sharded):
+            assert not qgb._kernel_covers(stack)
+            assert qgb.tally_program([stack]) == "jit__counts_cross"
+        qgb.reset_stats()
+        out = qgb.cross_tally(dev, dev, filt=dev[0])
+        assert out.shape == (2, 2, 4)
+        assert qgb.STATS["xla_tallies"] == 1 and not qgb.STATS["kernel_tallies"]
+
     def test_tiny_tile_chunking(self, holder, monkeypatch):
         """Force one-prefix chunks: results identical, memory bounded."""
         from pilosa_tpu.exec import groupby as qgb

@@ -1,0 +1,82 @@
+"""The kernels of the served path, compiled for a TPU v5e that is
+described, not attached: Mosaic refuses here what it would refuse on the
+chip (tiling, VMEM), at the sizes the benchmark runs, and the compiled
+module shows whether XLA had to copy a stack to feed the kernel. Nothing
+runs, so no answer and no time is checked — `chip_smoke.py` does that.
+
+The topology is described inside a fixture and only in this file: one
+process at a time may hold the TPU library, and every xdist worker
+imports every test file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from pilosa_tpu.ops import pallas_kernels as pk
+
+SHARDS, WORDS = 954, 32768  # taxi-1b at the shipped shard width
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    described = {
+        "TPU_ACCELERATOR_TYPE": "v5litepod-4",
+        "TPU_WORKER_HOSTNAMES": "localhost",
+        "TPU_SKIP_MDS_QUERY": "1",
+        "TPU_LOG_DIR": "disabled",
+    }
+    with pytest.MonkeyPatch.context() as m:
+        for k, v in described.items():
+            if k not in os.environ:
+                m.setenv(k, v)
+        try:
+            topo = topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:2x2"
+            )
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield SingleDeviceSharding(topo.devices[0])
+
+
+def _stack(sharding, rows, shards=SHARDS):
+    shape = (rows, shards, WORDS) if rows else (shards, WORDS)
+    return jax.ShapeDtypeStruct(shape, jnp.uint32, sharding=sharding)
+
+
+# name: (G, R, M, filter, shards, kernel body, bytes XLA may copy)
+_CASES = {
+    # the benchmark's GroupBys: every stack is 8k rows x 954 shards, which
+    # the device keeps shard-major; the shard-major body reads it in place
+    "taxi_q3": (8, 8, 0, False, SHARDS, True, 0),
+    "taxi_q4": (8, 16, 8, False, SHARDS, True, 0),
+    # a filter [S, W] is re-tiled to one row per shard: its own 125 MB
+    "taxi_q4_filtered": (8, 16, 8, True, SHARDS, True, SHARDS * WORDS * 4),
+    # the filtered TopN's dense chunk and a descent chunk at 954 shards
+    "topn_chunk": (1, 2, 0, False, SHARDS, True, 0),
+    "descent_chunk": (2, 16, 0, False, SHARDS, True, 0),
+    # stacks the device keeps row-major: odd row counts, or 8 | S
+    "odd_rows": (3, 5, 6, True, SHARDS, False, 0),
+    "shards_960": (8, 16, 8, False, 960, False, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CASES))
+def test_cross_counts_compiles_for_v5e_without_copying_its_stacks(
+    one_chip, case
+):
+    g, r, m, filtered, shards, shard_major, copied = _CASES[case]
+    compiled = pk._cross_counts_vmem.lower(
+        _stack(one_chip, g, shards),
+        _stack(one_chip, r, shards),
+        _stack(one_chip, m, shards) if m else None,
+        _stack(one_chip, 0, shards) if filtered else None,
+        shard_major=shard_major,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes <= copied * 1.01
