@@ -18,7 +18,9 @@ one process holds the chip and this parent never initialises a JAX backend
    (unfiltered TopN is served from the rank cache: the one exception),
    and the GroupBy's cross tally must have run as the VMEM kernel on one
    chip (`groupby.kernel_tallies` on `/debug/vars`), as the XLA program
-   on several;
+   on several; every cold dispatch must have run as one program over all
+   the devices the server holds (`mesh.devices` on its span: 4 on a
+   four-chip host, 1 on one chip);
 4. writes (a PQL `Set`, then an `/import` burst large enough to cross the
    device-merge threshold), reads the writes back, restarts the server on
    the same data dir and reads them back again;
@@ -413,10 +415,11 @@ def check_device(info: dict) -> dict:
     }
 
 
-def _count_spans(span: dict, name: str) -> int:
-    return (span["name"] == name) + sum(
-        _count_spans(c, name) for c in span.get("children", ())
-    )
+def _spans(span: dict, name: str):
+    if span["name"] == name:
+        yield span
+    for c in span.get("children", ()):
+        yield from _spans(c, name)
 
 
 def _normalise(result):
@@ -428,12 +431,16 @@ def _normalise(result):
 
 
 def ask(http_: Http, pql: str) -> tuple:
-    """(answer, exec.dispatch spans, wall seconds) of one profiled query."""
+    """(answer, the `mesh.devices` tag of each exec.dispatch span, wall
+    seconds) of one profiled query."""
     t0 = time.perf_counter()
     out = http_.call("POST", f"/index/{INDEX}/query?profile=1", pql)
     took = time.perf_counter() - t0
-    n = sum(_count_spans(r, "exec.dispatch") for r in out["profile"]["roots"])
-    return _normalise(out["results"][0]), n, took
+    placed = [
+        s["tags"].get("mesh.devices")
+        for r in out["profile"]["roots"] for s in _spans(r, "exec.dispatch")
+    ]
+    return _normalise(out["results"][0]), placed, took
 
 
 def tally_counts(http_: Http) -> tuple:
@@ -459,6 +466,18 @@ def check_tally_program(family: str, kernel: int, xla: int,
         )
 
 
+def check_placement(family: str, placed: list, device: dict) -> None:
+    """Every compiled dispatch of a cold query ran as one program over
+    all the devices the server holds: 4 on a four-chip host, whose stacks
+    lie on the 2 x 2 mesh, 1 on one chip. A stack placed on device 0
+    alone, or a server whose mesh did not form, fails here."""
+    if any(n != device["count"] for n in placed):
+        raise AssertionError(
+            f"{family}: exec.dispatch spans with mesh.devices {placed} on "
+            f"{device['count']} x {device['platform']}"
+        )
+
+
 TALLY_FAMILIES = ("group_by", "topn_filtered", "pallas_topn_filtered")
 
 
@@ -467,7 +486,10 @@ def run_queries(http_: Http, queries: list, cold: bool,
     for family, pql, want, min_dispatches in queries:
         tallied = cold and device is not None and family in TALLY_FAMILIES
         before = tally_counts(http_) if tallied else None
-        got, n_dispatch, took = ask(http_, pql)
+        got, placed, took = ask(http_, pql)
+        n_dispatch = len(placed)
+        if cold and device is not None:
+            check_placement(family, placed, device)
         if tallied:
             kernel, xla = (a - b for a, b in zip(tally_counts(http_), before))
             check_tally_program(family, kernel, xla, device)

@@ -3282,6 +3282,7 @@ class Executor:
             lambda: qgb.group_by_device(planes_list, child_rows, filt),
             read=False, family="groupby",
             program=qgb.tally_program(planes_list, filt),
+            arrays=planes_list,
         )
 
     def _group_by_shard(  # dispatch-ok: per-shard path, single-device

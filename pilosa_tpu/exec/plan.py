@@ -111,22 +111,29 @@ def dispatch_mutex() -> TrackedLock:
     return _DISPATCH_MU
 
 
-def run_counted(fn, read: bool = True, family: str = "", program: str = ""):
+def run_counted(fn, read: bool = True, family: str = "", program: str = "",
+                arrays=None):
     """run_serialized plus dispatch accounting and the exec.dispatch
     attribution probe: STATS["evals"] books the compiled dispatch and —
     when `read` — STATS["host_reads"] books the blocking result read the
     caller is about to take. The plane-streamed BSI aggregates ride this
     so their "one dispatch per budget chunk / one scalar read" contracts
     are counter-asserted exactly like StackedPlan's. `family` and
-    `program` name what `fn` runs, for the span's plan.* tags."""
+    `program` name what `fn` runs, for the span's plan.* tags. `fn`
+    closes over its operands: the span's mesh.devices is read from
+    `arrays` (the operands, from a caller whose `fn` reads its results
+    to the host itself) or else from what `fn` returns (an SPMD
+    program's results lie on its devices)."""
     t_lock = _pre_dispatch()
     with _DISPATCH_MU:
-        probe = _DispatchProbe(t_lock, family, program)
+        probe = _DispatchProbe(t_lock, family, program, arrays=arrays)
         try:
             import jax
 
             out = jax.block_until_ready(fn())
             probe.evaled()
+            if arrays is None:
+                probe.placed(out)
             if read:
                 _note_host_read()
             return out
@@ -439,6 +446,24 @@ def _pre_dispatch() -> float:
     return _time.perf_counter()
 
 
+def _placement(arrays) -> Tuple[int, str]:
+    """(devices spanned, mesh axes) of a compiled dispatch, read from the
+    sharding of the first device array among `arrays` (a pytree of its
+    operands, or of its results where the operands are out of reach):
+    (1, "") on a single device, (4, "shards=2,cols=2") for a stack placed
+    over the 2 x 2 mesh a four-chip host forms."""
+    for a in jax.tree_util.tree_leaves(arrays):
+        sharding = getattr(a, "sharding", None)
+        if sharding is None:
+            continue
+        n = len(sharding.device_set)
+        mesh = getattr(sharding, "mesh", None)
+        if n > 1 and mesh is not None:
+            return n, ",".join(f"{k}={v}" for k, v in mesh.shape.items())
+        return n, ""
+    return 1, ""
+
+
 class _DispatchProbe:
     """Attribution for ONE compiled dispatch. Construct immediately
     after acquiring _DISPATCH_MU (with the pre-lock timestamp from
@@ -448,18 +473,23 @@ class _DispatchProbe:
     eval raised before evaled(). `family` is the plan family (stacked /
     bsi / groupby), `program` the jitted program as the profiler's "XLA
     Modules" line names it, which joins this span to its device ops;
-    dispatch.compiled says whether this dispatch had to compile. The
+    dispatch.compiled says whether this dispatch had to compile;
+    mesh.devices how many devices the program's `arrays` span (its
+    operands; placed() takes them later where only the results are at
+    hand) and, above one, mesh.axes the mesh they are sharded over. The
     span is entered and left by hand, as a `with` would, so that it is
     on the profiler's clock too (utils/tracing.py)."""
 
     __slots__ = ("_span", "_t_lock", "_t0", "_t1", "_compiles")
 
     def __init__(self, t_lock: float, family: str = "stacked",
-                 program: str = "jit__eval_jit"):
+                 program: str = "jit__eval_jit", arrays=None):
         self._span = sp = tracing.start_span("exec.dispatch")
         sp.__enter__()
         sp.set_tag("plan.family", family)
         sp.set_tag("plan.program", program)
+        if arrays is not None:
+            self.placed(arrays)
         self._compiles = _thread_compiles()
         self._t_lock = t_lock
         self._t0 = _time.perf_counter()
@@ -467,6 +497,12 @@ class _DispatchProbe:
 
     def tag(self, key: str, value) -> None:
         self._span.set_tag(key, value)
+
+    def placed(self, arrays) -> None:
+        n, axes = _placement(arrays)
+        self._span.set_tag("mesh.devices", n)
+        if n > 1:
+            self._span.set_tag("mesh.axes", axes)
 
     def evaled(self) -> None:
         self._t1 = _time.perf_counter()
@@ -538,7 +574,7 @@ class StackedPlan:
         exact Python ints (replaces the per-shard int() sync loop)."""
         t_lock = _pre_dispatch()
         with _DISPATCH_MU:
-            probe = _DispatchProbe(t_lock)
+            probe = _DispatchProbe(t_lock, arrays=self.operands)
             try:
                 counts = _eval_jit(
                     self.root, "count", tuple(self.operands), self._scalar_args()
@@ -565,7 +601,7 @@ class StackedPlan:
             return self.count()
         t_lock = _pre_dispatch()
         with _DISPATCH_MU:
-            probe = _DispatchProbe(t_lock)
+            probe = _DispatchProbe(t_lock, arrays=self.operands)
             probe.tag("dispatch.mode", "total")
             try:
                 out = _eval_jit(
@@ -582,7 +618,7 @@ class StackedPlan:
     def shard_counts(self) -> np.ndarray:
         t_lock = _pre_dispatch()
         with _DISPATCH_MU:
-            probe = _DispatchProbe(t_lock)
+            probe = _DispatchProbe(t_lock, arrays=self.operands)
             try:
                 counts = _eval_jit(
                     self.root, "count", tuple(self.operands), self._scalar_args()
@@ -598,7 +634,7 @@ class StackedPlan:
         """Materialized [S, W] result stack (padded shards trimmed)."""
         t_lock = _pre_dispatch()
         with _DISPATCH_MU:
-            probe = _DispatchProbe(t_lock)
+            probe = _DispatchProbe(t_lock, arrays=self.operands)
             try:
                 out = _eval_jit(
                     self.root, "row", tuple(self.operands), self._scalar_args()
@@ -615,7 +651,7 @@ class StackedPlan:
         rows), for composing with other padded [S, W] stacks on device."""
         t_lock = _pre_dispatch()
         with _DISPATCH_MU:
-            probe = _DispatchProbe(t_lock)
+            probe = _DispatchProbe(t_lock, arrays=self.operands)
             try:
                 out = _eval_jit(
                     self.root, "row", tuple(self.operands), self._scalar_args()
@@ -654,7 +690,9 @@ class MultiCountPlan:
     def counts(self) -> List[int]:
         t_lock = _pre_dispatch()
         with _DISPATCH_MU:
-            probe = _DispatchProbe(t_lock, program="jit__eval_multi_jit")
+            probe = _DispatchProbe(
+                t_lock, program="jit__eval_multi_jit", arrays=self.operands
+            )
             probe.tag("dispatch.roots", len(self.roots))
             try:
                 out = _eval_multi_jit(
@@ -682,7 +720,9 @@ class MultiCountPlan:
             return self.counts()
         t_lock = _pre_dispatch()
         with _DISPATCH_MU:
-            probe = _DispatchProbe(t_lock, program="jit__eval_multi_jit")
+            probe = _DispatchProbe(
+                t_lock, program="jit__eval_multi_jit", arrays=self.operands
+            )
             probe.tag("dispatch.roots", len(self.roots))
             probe.tag("dispatch.mode", "total")
             try:

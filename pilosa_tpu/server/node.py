@@ -830,6 +830,12 @@ class NodeServer:
         self.stats.gauge("mesh.group_size", group_size)
         self.stats.gauge("mesh.local_shards", gsnap["local_shards"])
         self.stats.gauge("mesh.collective_bytes", gsnap["collective_bytes"])
+        # the placement itself: devices of the active mesh every operand
+        # stack is sharded over (0: none, one device holds everything)
+        active = pmesh_mod.active_mesh()
+        self.stats.gauge(
+            "mesh.devices", 0 if active is None else active.devices.size
+        )
         # compiled dispatches (exec/plan.py) and the batcher's rounds
         # (exec/batcher.py): the counts those modules keep, and the
         # compile counts jax.monitoring feeds the process registry —

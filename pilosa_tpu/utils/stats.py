@@ -162,6 +162,10 @@ STAT_NAMES = frozenset(
         "mesh.group_size",
         "mesh.local_shards",
         "mesh.collective_bytes",
+        # devices of the process's active mesh (parallel/mesh.py), 0 with
+        # none: where every operand stack is placed, whether or not the
+        # node is in a mesh group
+        "mesh.devices",
         # mesh-group fallbacks (exec/distributed.py): eligible fan-outs
         # that bailed to HTTP legs at lowering time, tagged by reason
         # ("budget" / "no_stacked_form" / "unsupported") so a fallback-

@@ -109,7 +109,9 @@ SPAN_NAMES = frozenset(
         # one compiled dispatch under plan._DISPATCH_MU: lock wait vs
         # device eval vs blocking host read (exec/plan.py); tags:
         # plan.family / plan.program (the jitted program as the
-        # profiler's "XLA Modules" line names it) / dispatch.compiled
+        # profiler's "XLA Modules" line names it) / dispatch.compiled /
+        # mesh.devices (devices the program's operands span, 1 on a
+        # single device) and, above 1, mesh.axes ("shards=2,cols=2")
         "exec.dispatch",
         # a whole distributed fan-out incl. re-map rounds
         # (exec/distributed.py)
@@ -118,6 +120,8 @@ SPAN_NAMES = frozenset(
         # fan-out answered as ONE compiled sharded program with the
         # reduction in program (exec/distributed.py + exec/meshgroup.py);
         # tags: mesh.group_size / mesh.local_shards / mesh.collective_bytes
+        # (the multi-node fold; a single node's sharded program is an
+        # exec.dispatch with mesh.devices > 1)
         "exec.mesh_dispatch",
         # one per-peer fan-out leg, with retry/breaker outcome tags
         # (exec/distributed.py; server/client.py tags rpc.retries)

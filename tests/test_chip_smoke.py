@@ -26,8 +26,10 @@ def test_smoke_parts_agree_with_a_served_index_small():
         cs.create_schema(http_)
         assert set(cs.load(uri, data)) == {"f", "g", "h", "v"}
         # a CPU server tallies its GroupBy and filtered TopN with the
-        # XLA program, and the smoke's counter check says so
-        device = {"platform": "cpu", "kind": "cpu", "count": 1}
+        # XLA program, and the smoke's counter check says so; every cold
+        # dispatch spans the suite's 8-device mesh, and its check that
+        assert len(info["devices"]) == 8
+        device = {"platform": "cpu", "kind": "cpu", "count": 8}
         before = cs.tally_counts(http_)
         cs.run_queries(http_, queries, cold=True, device=device)
         kernel, xla = (a - b for a, b in zip(cs.tally_counts(http_), before))
@@ -36,6 +38,8 @@ def test_smoke_parts_agree_with_a_served_index_small():
             cs.check_tally_program(
                 "group_by", kernel, xla,
                 {"platform": "tpu", "kind": "TPU v5 lite", "count": 1})
+        with pytest.raises(AssertionError, match=r"mesh.devices \[8\]"):
+            cs.check_placement("count_intersect", [8], dict(device, count=4))
         cs.run_queries(http_, queries, cold=False)
         readback = cs.write_then_read(http_, data, ref)
         assert [n for _, n in readback] == [
