@@ -660,6 +660,28 @@ class Fragment:
             found = (pos < len(rs)) & (rs[posc] == ids)
             return np.where(found, cs[posc], 0).astype(np.uint64)
 
+    def row_summary(self) -> Tuple[int, np.ndarray, np.ndarray, bool]:
+        """(version, row ids, cardinalities, exact) of every non-empty row,
+        count descending with ties by lowest id, all read under one lock
+        acquisition so the cells belong to exactly that version — the
+        view's row summary (core/rowsummary.py) keeps them until the
+        version moves. `exact` is cache_counts_exact's condition for a
+        ranked cache: never pruned, so the cells ARE the rank cache in
+        rank order and TopN may select over them; otherwise the cells
+        come from the row store and serve Rows only."""
+        with self._mu:
+            self._sync_locked()
+            if self.cache.cache_type == cachemod.CACHE_TYPE_RANKED:
+                rids, cnts = self.cache_top_arrays()
+                if not self.cache.pruned:  # checked AFTER top(): it may prune
+                    return self.version, rids, cnts, True
+            ids = list(self._rows)
+            rids = np.asarray(ids, np.uint64)
+            cnts = self.row_counts_host(ids)
+            order = np.lexsort((rids, -cnts.astype(np.int64)))
+            order = order[cnts[order] > 0]
+            return self.version, rids[order], cnts[order], False
+
     def row_counts_host(self, row_ids) -> np.ndarray:
         """Cardinalities of the listed rows as one uint64 vector under one
         lock acquisition (TopN pass-2 reads n_shards x n_candidates counts;

@@ -17,7 +17,9 @@ from pilosa_tpu.core import wal as walmod
 from pilosa_tpu.core.devcache import DEVICE_CACHE, new_owner_token
 from pilosa_tpu.core.fragment import Fragment
 from pilosa_tpu.core.resultcache import RESULT_CACHE
+from pilosa_tpu.core.rowsummary import RowSummary
 from pilosa_tpu.shardwidth import SHARD_WIDTH, WORDS_PER_ROW
+from pilosa_tpu.utils.stats import PROCESS
 
 VIEW_STANDARD = "standard"
 VIEW_BSI_PREFIX = "bsig_"
@@ -85,6 +87,16 @@ class View:
         # consult the resolver first (resolve() hydrates on demand,
         # single-flight). None = tier disabled, zero overhead.
         self.cold_resolver = None
+        # row summary (core/rowsummary.py): one table of every non-empty
+        # (row, shard) cardinality, built on first use and validated per
+        # reader by row_summary() against the two integers it was built
+        # under. mutation_clock covers content; _frag_epoch covers the
+        # fragment SET, which changes without a clock bump (creation,
+        # deletion, tier demotion / hydration) — it bumps under
+        # _mu together with the dict update, so a table whose epoch still
+        # matches was read from exactly today's fragments.
+        self._frag_epoch = 0  # lock-free: monotonic int written under _mu; GIL-atomic reads
+        self._summary: Optional[RowSummary] = None  # lock-free: immutable table, swapped whole
 
     def open(self) -> "View":
         """Load existing fragments from disk (view.go:120 openFragments)."""
@@ -114,6 +126,7 @@ class View:
             DEVICE_CACHE.invalidate_owner(self._stack_token)
             RESULT_CACHE.drop_view(self._stack_token)
             self._dirty_staged.clear()
+            self._summary = None
         # outside the view lock: publishers ship drop tombstones so leased
         # mirrors forget this view instead of holding its last versions
         # forever (monotone merge would otherwise mask the deletion)
@@ -161,6 +174,7 @@ class View:
                 # instead of churning the whole owner or waiting on LRU)
                 frag.on_mutate = lambda s=shard: self._on_fragment_mutate(s)
                 self.fragments[shard] = frag
+                self._frag_epoch += 1
             return frag
 
     def _on_fragment_mutate(self, shard: int) -> None:
@@ -200,6 +214,7 @@ class View:
             frag = self.fragments.pop(shard, None)
             if frag is None:
                 return False
+            self._frag_epoch += 1
             frag.close()  # also frees the fragment's device-cache residency
             for p in (frag.snap_path, frag.wal_path, frag.cache_path):
                 if p is not None:
@@ -236,6 +251,8 @@ class View:
         a covering cached result remains correct)."""
         with self._mu:
             frag = self.fragments.pop(shard, None)
+            if frag is not None:
+                self._frag_epoch += 1
         if frag is None:
             return False
         if end_capture_tag is not None:
@@ -303,7 +320,52 @@ class View:
                 return existing
             frag.on_mutate = lambda s=shard: self._on_fragment_mutate(s)
             self.fragments[shard] = frag
+            self._frag_epoch += 1
         return frag
+
+    # -- row summary (core/rowsummary.py) ----------------------------------
+
+    def row_summary(self) -> Optional[RowSummary]:
+        """The view's table of per-(row, shard) cardinalities, current as
+        of this call, or None when the view cannot keep one (the tier
+        reports cold shards: they hydrate through fragment_if_exists, so
+        those readers keep the per-fragment path).
+
+        The clock is read FIRST (the ordering contract in __init__: a
+        clock read is never newer than content read after it), so a table
+        stamped with it can be stale-by-clock, never fresh-by-clock over
+        old data. Clock-equal is a hit. Otherwise the staged burst merges
+        as one batched barrier and only the fragments whose version moved
+        are read again; a moved epoch rebuilds from nothing. No lock is
+        held across the build: concurrent readers may each build, every
+        result is exact under its own stamp, and the last one stored is
+        revalidated like any other."""
+        clock = self.mutation_clock
+        old = self._summary
+        if old is not None and old.epoch == self._frag_epoch:
+            epoch, frags = old.epoch, old.frags
+        else:
+            old = None
+            with self._mu:
+                epoch = self._frag_epoch
+                frags = [self.fragments[s] for s in sorted(self.fragments)]
+        res = self.cold_resolver
+        if res is not None and res.cold_shards(self):
+            # asked AFTER the fragment set was read: demotion registers a
+            # shard cold before it detaches the fragment (tier/manager.py
+            # _demote), so a set read earlier that lacks it is caught here
+            return None
+        if old is not None and old.clock == clock:
+            PROCESS.count("rowsummary.hits", 1, ())
+            return old
+        self.sync_pending(frags=frags)
+        new = RowSummary(epoch, clock, frags, old)
+        if old is None:
+            PROCESS.count("rowsummary.rebuilds", 1, ())
+        else:
+            PROCESS.count("rowsummary.refreshed_shards", new.reads, ())
+        self._summary = new
+        return new
 
     # -- stacked operands for the compiled query path ----------------------
     #

@@ -32,6 +32,7 @@ from pilosa_tpu.core.fragment import BSI_EXISTS_BIT, BSI_OFFSET_BIT, BSI_SIGN_BI
 from pilosa_tpu.core.holder import Holder
 from pilosa_tpu.core.index import Index
 from pilosa_tpu.core.row import Row
+from pilosa_tpu.core.rowsummary import Cells, cells_of, head_per_segment
 from pilosa_tpu.core.view import VIEW_STANDARD
 from pilosa_tpu.exec import translation
 from pilosa_tpu.exec.plan import (
@@ -55,6 +56,7 @@ from pilosa_tpu.pql import Call, Query, parse
 from pilosa_tpu.pql.ast import BETWEEN, EQ, GT, GTE, LT, LTE, NEQ, Condition
 from pilosa_tpu.shardwidth import SHARD_WIDTH
 from pilosa_tpu.utils import tracing
+from pilosa_tpu.utils.stats import PROCESS
 
 DEFAULT_MIN_THRESHOLD = 1  # reference: defaultMinThreshold, executor.go
 
@@ -2667,14 +2669,14 @@ class Executor:
         popcount(planes & src) in O(candidates/tile) chunked dispatches
         with a single host read — never one dispatch per shard. Returns
         None when the child has no stacked form (per-shard fallback)."""
+        if spec.src_call is None:
+            TOPN_STATS["batched"] += 1
+            cells = self._topn_cells(spec, shard_list)
+            return {} if cells is None else self._topn_merged_hostfast(spec, cells)
         vp = self._topn_present(spec, shard_list)
         if vp is None:
             return {}
         v, present = vp
-        has_src = spec.src_call is not None
-        if not has_src:
-            TOPN_STATS["batched"] += 1
-            return self._topn_merged_hostfast(spec, present)
         lowered = self._stacked_filter(idx, spec.src_call, present)
         if lowered is None:
             return None
@@ -2720,79 +2722,101 @@ class Executor:
                 merged[rid] = merged.get(rid, 0) + count
         return merged
 
-    def _topn_merged_hostfast(self, spec: "_TopNSpec", present) -> Dict[int, int]:
+    def _topn_cells(self, spec: "_TopNSpec", shard_list) -> Optional[Cells]:
+        """What the no-filter-bitmap TopN selects over: per listed shard
+        with a fragment, the candidate pool's (row id, cardinality) cells
+        as one CSR triple (core/rowsummary.py). Read from the view's row
+        summary when that holds the pools — every listed fragment's rank
+        cache complete, so summary order IS pool order — else walked from
+        the fragments: the rank cache in rank order, or for explicit ids
+        each id's exact count. None when the view or every listed
+        fragment is absent."""
+        v = spec.f.view(VIEW_STANDARD)
+        if v is None:
+            return None
+        summary = v.row_summary()
+        if summary is not None:
+            pos = summary.positions(shard_list)
+            if summary.exact[pos].all():
+                return summary.cells(pos) if len(pos) else None
+        PROCESS.count("rowsummary.bypassed", 1, ())
+        vp = self._topn_present(spec, shard_list)
+        if vp is None:
+            return None
+        frags = [frag for _, frag in vp[1]]
+        if not spec.ids:
+            return cells_of([frag.cache_top_arrays() for frag in frags])
+        # explicit ids: exact cardinalities from the rank cache when it is
+        # provably complete (vectorized lookup), else the authoritative
+        # row_counts_host walk
+        ids_arr = np.unique(np.asarray([int(i) for i in spec.ids], np.uint64))
+        ids = ids_arr.tolist()
+        parts = []
+        for frag in frags:
+            c = frag.cache_counts_exact(ids_arr)
+            parts.append((ids_arr, frag.row_counts_host(ids) if c is None else c))
+        return cells_of(parts)
+
+    def _topn_merged_hostfast(self, spec: "_TopNSpec", cells: Cells) -> Dict[int, int]:
         """The no-filter-bitmap merge: counts are exact O(1) host metadata,
-        so both passes reduce to vectorized metadata walks — zero device
-        dispatches. Semantics identical to _topn_pool/_topn_survivors/
+        so both passes reduce to segment operations over the shards'
+        cells (_topn_cells) — zero device dispatches, no per-fragment
+        call. Semantics identical to _topn_pool/_topn_survivors/
         _topn_select with has_src=False (the differential tests force the
         general path and compare)."""
+        rids, counts, offsets = cells
         merged: Dict[int, int] = {}
         allowed = None
         if spec.filters is not None:
             store = spec.f.row_attr_store
-            memo: Dict[int, bool] = {}
 
             def allowed(rid: int) -> bool:
-                ok = memo.get(rid)
-                if ok is None:
-                    attr = store.attrs(rid)
-                    val = attr.get(spec.attr_name) if attr else None
-                    ok = memo[rid] = val is not None and val in spec.filters
-                return ok
+                attr = store.attrs(rid)
+                val = attr.get(spec.attr_name) if attr else None
+                return val is not None and val in spec.filters
 
+        thr = np.uint64(max(spec.threshold, 1))
         if spec.ids:
             # pass 2 / explicit ids: no truncation -> the per-shard select
-            # reduces to "sum counts >= threshold per shard" (exact).
-            # Cardinalities come from the rank cache when it is provably
-            # complete (vectorized lookup), else the authoritative
-            # row_counts_host walk.
+            # reduces to "sum counts >= threshold per shard" (exact)
             ids = [int(i) for i in spec.ids]
             if allowed is not None:
                 ids = [rid for rid in ids if allowed(rid)]
             if not ids:
                 return merged
-            ids_arr = np.asarray(ids, np.uint64)
-            totals = np.zeros(len(ids), np.uint64)
-            thr = np.uint64(spec.threshold)
-            for _, frag in present:
-                c = frag.cache_counts_exact(ids_arr)
-                if c is None:
-                    c = frag.row_counts_host(ids)
-                c[c < thr] = 0
-                totals += c
-            for rid, cnt in zip(ids, totals):
-                if cnt:
-                    merged[rid] = merged.get(rid, 0) + int(cnt)
-            return merged
-        # pass 1: per-shard top-n of the rank cache, fully vectorized: the
-        # cache arrays are sorted descending so the threshold cut is a
-        # prefix, the attr filter is a boolean mask, and the n-bound is a
-        # cumsum cut — same contract as the select heap with no src. The
-        # merge is one bincount over the concatenated selections.
-        n = spec.n
-        thr = np.uint64(max(spec.threshold, 1))
-        sel_rids, sel_cnts = [], []
-        for _, frag in present:
-            rids, cnts = frag.cache_top_arrays()
-            end = int(np.searchsorted(-cnts.view(np.int64), -int(thr), "right"))
-            rids, cnts = rids[:end], cnts[:end]
-            if allowed is not None and len(rids):
-                m = np.fromiter((allowed(int(r)) for r in rids), bool, len(rids))
-                rids, cnts = rids[m], cnts[m]
-            if n and len(rids) > n:
-                rids, cnts = rids[:n], cnts[:n]
-            if len(rids):
-                sel_rids.append(rids)
-                sel_cnts.append(cnts)
-        if sel_rids:
-            all_r = np.concatenate(sel_rids)
-            all_c = np.concatenate(sel_cnts).astype(np.uint64)
-            uniq, inv = np.unique(all_r, return_inverse=True)
-            totals = np.bincount(inv, weights=all_c.astype(np.float64))
+            uniq = np.unique(np.asarray(ids, np.uint64))
+            pos = np.minimum(np.searchsorted(uniq, rids), len(uniq) - 1)
+            keep = (uniq[pos] == rids) & (counts >= thr)
             # float64 weights are exact below 2^53; per-row totals are
             # bounded by n_shards * SHARD_WIDTH, far under that
-            for rid, t in zip(uniq, totals):
-                merged[int(rid)] = int(t)
+            totals = np.bincount(
+                pos[keep], weights=counts[keep].astype(np.float64),
+                minlength=len(uniq),
+            )
+            total_of = dict(zip(uniq.tolist(), totals.tolist()))
+            for rid in ids:
+                cnt = int(total_of[rid])
+                if cnt:
+                    merged[rid] = merged.get(rid, 0) + cnt
+            return merged
+        # pass 1: per-shard top-n of the rank cache: the threshold cut and
+        # the attr filter are masks over the cells, the n-bound keeps each
+        # shard's first n survivors in rank order — same contract as the
+        # select heap with no src. The merge is one bincount over the
+        # selections.
+        keep = counts >= thr
+        if allowed is not None and keep.any():
+            uniq = np.unique(rids[keep])
+            ok = np.fromiter((allowed(int(r)) for r in uniq), bool, len(uniq))
+            kept = np.flatnonzero(keep)
+            keep[kept[~ok[np.searchsorted(uniq, rids[kept])]]] = False
+        if spec.n:
+            keep = head_per_segment(keep, offsets, spec.n)
+        if keep.any():
+            uniq, inv = np.unique(rids[keep], return_inverse=True)
+            totals = np.bincount(inv, weights=counts[keep].astype(np.float64))
+            for rid, t in zip(uniq.tolist(), totals.tolist()):
+                merged[rid] = int(t)
         return merged
 
     def _topn_present(self, spec: "_TopNSpec", shard_list):
@@ -3054,16 +3078,46 @@ class Executor:
         if col is not None:
             shards = [col // SHARD_WIDTH]
         limit = c.uint_arg("limit")
-        merged: set = set()
-        for shard in self._shards_for(idx, shards):
-            merged.update(self._rows_shard(idx, field_name, c, shard))
-        out = sorted(merged)
+        out = self._rows_summary(idx, field_name, c, shards)
+        if out is None:
+            PROCESS.count("rowsummary.bypassed", 1, ())
+            merged: set = set()
+            for shard in self._shards_for(idx, shards):
+                merged.update(self._rows_shard(idx, field_name, c, shard))
+            out = sorted(merged)
         prev = c.uint_arg("previous")
         if prev is not None:
             out = [r for r in out if r > prev]
         if limit is not None:
             out = out[:limit]
         return out
+
+    def _rows_summary(
+        self, idx: Index, field_name: str, c: Call, shards
+    ) -> Optional[List[int]]:
+        """Rows() of a field's standard view from the view's row summary
+        (core/rowsummary.py): the ids with a non-zero count in any listed
+        shard, one vector pass instead of a call per (row, fragment).
+        None when the call reads what the table does not hold — one
+        column's membership, time-quantum views, cold shards — and the
+        per-fragment walk answers."""
+        if c.uint_arg("column") is not None:
+            return None
+        f = self._field_of(idx, field_name)
+        if f.options.type == FIELD_TYPE_TIME and (
+            c.args.get("from") is not None
+            or c.args.get("to") is not None
+            or f.options.no_standard_view
+        ):
+            return None
+        v = f.view(VIEW_STANDARD)
+        if v is None:
+            return []
+        summary = v.row_summary()
+        if summary is None:
+            return None
+        pos = summary.positions(self._shards_for(idx, shards))
+        return np.unique(summary.cells(pos)[0]).tolist()
 
     def _rows_shard(self, idx: Index, field_name: str, c: Call, shard: int) -> List[int]:
         f = self._field_of(idx, field_name)
@@ -3237,11 +3291,15 @@ class Executor:
         # A shard contributes a group only when EVERY child has a fragment
         # there (the per-shard walk returns early otherwise) — compact the
         # stacks to that intersection so sparse fields stay cheap.
-        gb_shards = [
-            s
-            for s in shard_list
-            if all(v.fragment_if_exists(s) is not None for v in child_views)
-        ]
+        gb_shards = shard_list
+        for v in child_views:
+            summary = v.row_summary()
+            if summary is not None:
+                gb_shards = summary.present(gb_shards)
+            else:
+                gb_shards = [
+                    s for s in gb_shards if v.fragment_if_exists(s) is not None
+                ]
         if not gb_shards:
             return {}
         from pilosa_tpu.core.devcache import DEVICE_CACHE

@@ -184,6 +184,12 @@ class GroupView:
         v = self._owner_view(shard)
         return v.fragment_if_exists(shard) if v is not None else None
 
+    def row_summary(self):
+        """No group-wide table (View.row_summary): each member view keeps
+        its own, over its own shards and under its own clock, so readers
+        of the group's union walk the fragments."""
+        return None
+
     def _frags_for(self, shards: Tuple[int, ...]):
         """(frags by position, member view -> its frags) for one stack."""
         frags = []

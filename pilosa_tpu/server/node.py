@@ -864,6 +864,22 @@ class NodeServer:
         self.stats.gauge(
             "groupby.xla_tallies", groupby_mod.STATS["xla_tallies"]
         )
+        # the views' row summaries (core/view.py row_summary): hits over
+        # hits + bypassed is the share of Rows / GroupBy-prefetch /
+        # unfiltered-TopN reads the table served
+        self.stats.gauge(
+            "rowsummary.hits", PROCESS.total_counter("rowsummary.hits")
+        )
+        self.stats.gauge(
+            "rowsummary.rebuilds", PROCESS.total_counter("rowsummary.rebuilds")
+        )
+        self.stats.gauge(
+            "rowsummary.refreshed_shards",
+            PROCESS.total_counter("rowsummary.refreshed_shards"),
+        )
+        self.stats.gauge(
+            "rowsummary.bypassed", PROCESS.total_counter("rowsummary.bypassed")
+        )
         self.stats.gauge("batcher.leader", batcher_mod.STATS["leader"])
         self.stats.gauge("batcher.batched", batcher_mod.STATS["batched"])
         self.stats.gauge(
@@ -1098,9 +1114,12 @@ class NodeServer:
 
     def _runtime_poll_loop(self) -> None:
         """Sample process runtime gauges (reference: server.go:813
-        monitorRuntime — goroutines/heap/GC/open-files)."""
-        import gc
-
+        monitorRuntime — goroutines/heap/open-files). No count of live
+        objects: gc.get_objects() walks the whole heap under the GIL (a
+        process-wide stop that grows with the index) and its list holds a
+        reference to every tracked object, half-built tuples included —
+        a request thread preempted inside tuple(<generator>) then fails
+        with SystemError (tupleobject.c: refcount != 1 at the resize)."""
         import resource
 
         while not self._closing.wait(self.metric_poll_interval):
@@ -1108,7 +1127,6 @@ class NodeServer:
                 usage = resource.getrusage(resource.RUSAGE_SELF)
                 self.stats.gauge("runtime.max_rss_kb", usage.ru_maxrss)
                 self.stats.gauge("runtime.threads", threading.active_count())
-                self.stats.gauge("runtime.gc_objects", len(gc.get_objects()))
                 try:
                     self.stats.gauge("runtime.open_files", len(os.listdir("/proc/self/fd")))
                 except OSError:

@@ -56,7 +56,6 @@ STAT_NAMES = frozenset(
         # runtime gauges (server/node.py monitorRuntime analog)
         "runtime.max_rss_kb",
         "runtime.threads",
-        "runtime.gc_objects",
         "runtime.open_files",
         # query admission control & QoS (sched/admission.py); admit/shed/
         # wait series carry "class:<interactive|batch|internal>" and
@@ -99,6 +98,16 @@ STAT_NAMES = frozenset(
         # other backend, mesh-sharded stacks)
         "groupby.kernel_tallies",
         "groupby.xla_tallies",
+        # per-view row summary (core/view.py row_summary, counted in the
+        # process registry and published at scrape time): readers that
+        # found the table under the view's current mutation clock, tables
+        # built from nothing, shards read again after a clock mismatch,
+        # and Rows / unfiltered TopN calls that kept the per-fragment walk
+        # (exec/executor.py)
+        "rowsummary.hits",
+        "rowsummary.rebuilds",
+        "rowsummary.refreshed_shards",
+        "rowsummary.bypassed",
         # device-cache residency (core/devcache.py, refreshed at scrape
         # time by server/node.py publish_cache_gauges)
         "devcache.resident_bytes",

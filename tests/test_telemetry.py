@@ -942,3 +942,33 @@ def test_internal_stats_export_is_mergeable_shape():
         merged.merge_state(st)
         assert merged.quantile("query_ms", 0.5, ("index:ms",)) >= 0
         assert math.isfinite(payload["collectedAt"])
+
+
+def test_runtime_poller_publishes_without_walking_the_heap():
+    """The poller's gauges appear, and no module of the package asks the
+    collector for every live object: `gc.get_objects()` holds a reference
+    to each — a `tuple(<generator>)` half built on a request thread then
+    fails its resize with SystemError (seen as a 500 on a Sum in the taxi
+    cell) — and stops every thread while it walks a large heap."""
+    import ast
+    import pathlib
+    import time
+
+    import pilosa_tpu
+
+    with ClusterHarness(1, in_memory=True, metric_poll_interval=0.02) as c:
+        deadline = time.monotonic() + 5.0
+        snap = {}
+        while "runtime.threads" not in snap and time.monotonic() < deadline:
+            time.sleep(0.02)
+            snap = c[0].stats.registry.snapshot()
+        assert {"runtime.threads", "runtime.max_rss_kb"} <= set(snap)
+        assert "ticker.error" not in snap
+    root = pathlib.Path(pilosa_tpu.__file__).parent
+    walkers = [
+        f"{path.relative_to(root)}:{node.lineno}"
+        for path in sorted(root.rglob("*.py"))
+        for node in ast.walk(ast.parse(path.read_text()))
+        if isinstance(node, ast.Attribute) and node.attr == "get_objects"
+    ]
+    assert not walkers, walkers
