@@ -24,13 +24,9 @@ one process holds the chip and this parent never initialises a JAX backend
 4. writes (a PQL `Set`, then an `/import` burst large enough to cross the
    device-merge threshold), reads the writes back, restarts the server on
    the same data dir and reads them back again;
-5. restarts once more with PILOSA_TPU_PALLAS=1, repeats Count, filtered
-   TopN and Sum and asks the two served queries that reach a Pallas
-   dispatch point, then runs all five `ops/bitmap.py` dispatch points,
-   the Pallas BSI sum and the GroupBy cross tally directly at the real
-   stack width in a child of their own (the served path reaches only two
-   of the dispatch points, and its TopN rows are too sparse to be
-   tallied as stacks).
+5. after the last server has exited, runs the GroupBy cross tally kernel
+   (`ops/pallas_kernels.cross_counts`) directly at the real stack width
+   in a child of its own (`cross_counts_child`).
 
 Anything a phase raises ends the run non-zero; nothing is folded into the
 output. Times printed are smoke timings on a cold process, not metrics.
@@ -244,35 +240,16 @@ def read_queries(ref: Reference) -> list:
         ("count_range", f"Count(Row(v > {V_THRESHOLD}))",
          ref.count_gt(V_THRESHOLD), 1),
         ("group_by", "GroupBy(Rows(g), Rows(h))", ref.group_by("g", "h"), 1),
-    ]
-
-
-def pallas_queries(ref: Reference) -> list:
-    """The PILOSA_TPU_PALLAS=1 pass, on query texts the earlier passes did
-    not use: the Count / filtered TopN / Sum repeat (the stacked path
-    answers these without touching a Pallas dispatch point), then the two
-    served queries that do reach one — a filtered MinRow/MaxRow
-    (`popcount` over the [S, W] filter stack) and a Tanimoto TopN
-    (`popcount_rows` over it); tests/test_chip_smoke.py pins that reach.
-    Returns (repeat, reaching)."""
-    repeat = [
-        ("pallas_count", "Count(Intersect(Row(f=1), Row(f=2)))",
-         len(np.intersect1d(ref.row("f", 1), ref.row("f", 2), True)), 1),
-        ("pallas_topn_filtered", "TopN(f, Row(h=2), n=10)",
-         ref.topn("f", 10, ref.row("h", 2)), 1),
-        ("pallas_sum_filtered", "Sum(Row(f=3), field=v)",
-         ref.sum(ref.row("f", 3)), 1),
-    ]
-    reaching = [
-        ("pallas_minrow_filtered", "MinRow(Row(g=2), field=f)",
+        # a filtered MinRow/MaxRow counts the [S, W] filter stack
+        # (`ops/bitmap.popcount`), a Tanimoto TopN counts it per shard
+        # (`popcount_rows`): the served path's only uses of the two
+        ("minrow_filtered", "MinRow(Row(g=2), field=f)",
          ref.extreme_row("f", ref.row("g", 2), True), 1),
-        ("pallas_maxrow_filtered", "MaxRow(Row(g=2), field=f)",
+        ("maxrow_filtered", "MaxRow(Row(g=2), field=f)",
          ref.extreme_row("f", ref.row("g", 2), False), 1),
-        ("pallas_topn_tanimoto",
-         "TopN(f, Row(h=1), n=10, tanimotoThreshold=1)",
+        ("topn_tanimoto", "TopN(f, Row(h=1), n=10, tanimotoThreshold=1)",
          ref.topn_tanimoto("f", 10, ref.row("h", 1), 1), 1),
     ]
-    return repeat, reaching
 
 
 # ---------------------------------------------------------------------------
@@ -478,7 +455,7 @@ def check_placement(family: str, placed: list, device: dict) -> None:
         )
 
 
-TALLY_FAMILIES = ("group_by", "topn_filtered", "pallas_topn_filtered")
+TALLY_FAMILIES = ("group_by", "topn_filtered")
 
 
 def run_queries(http_: Http, queries: list, cold: bool,
@@ -503,20 +480,6 @@ def run_queries(http_: Http, queries: list, cold: bool,
             )
         print(f"  {family:22s} ok  dispatches={n_dispatch}  "
               f"smoke timing {'cold' if cold else 'repeat'} {took:.3f} s")
-
-
-def run_or_refuse(http_: Http, queries: list) -> None:
-    """Queries that reach a Pallas kernel with mesh-sharded operands: JAX
-    cannot partition a Mosaic kernel over a mesh, so on a multi-device
-    host the server must either answer right or refuse loudly — never
-    answer from a quiet fallback."""
-    for query in queries:
-        try:
-            run_queries(http_, [query], cold=True)
-        except RuntimeError as e:
-            if "-> 500" not in str(e) or "Mosaic" not in str(e):
-                raise
-            print(f"  {query[0]:22s} refused loudly: {str(e)[-120:]}")
 
 
 def merge_device_count(http_: Http) -> int:
@@ -564,16 +527,14 @@ def import_burst(http_: Http, data: Data, ref: Reference,
 
 class Server:
     """`python -m pilosa_tpu.cli server` as a child on JAX_PLATFORMS=tpu,
-    whatever this process inherited, with no PILOSA_TPU_* variable but the
-    ones named in `extra_env`."""
+    whatever this process inherited, with no PILOSA_TPU_* variable."""
 
-    def __init__(self, data_dir: str, log_path: str, extra_env=None):
+    def __init__(self, data_dir: str, log_path: str):
         env = {
             k: v for k, v in os.environ.items()
             if not k.startswith("PILOSA_TPU_")
         }
         env["JAX_PLATFORMS"] = "tpu"
-        env.update(extra_env or {})
         self.log_path = log_path
         self._log = open(log_path, "w")
         self.proc = subprocess.Popen(
@@ -630,76 +591,47 @@ class Server:
 
 
 # ---------------------------------------------------------------------------
-# the Pallas dispatch points, driven directly (child process)
+# the GroupBy cross tally kernel, driven directly (child process)
 # ---------------------------------------------------------------------------
 
 
-def pallas_kernels_child(seed: int) -> None:
-    """Runs in a child of its own with PILOSA_TPU_PALLAS=1 after the last
-    server has exited: the five `ops/bitmap.py` dispatch points, the
-    Pallas BSI sum and the GroupBy cross tally at the real stack width
-    against numpy."""
+def cross_counts_child(seed: int) -> None:
+    """Runs in a child of its own after the last server has exited:
+    `ops/pallas_kernels.cross_counts` at the real stack width against
+    numpy, at the filtered TopN's shape (one filter row against a chunk
+    of two dense candidate rows — the smoke's own rows are sparse, so its
+    served TopN tallies no stack) and fused over three stacks with a
+    filter."""
     import jax
 
     if jax.devices()[0].platform != "tpu":
         raise RuntimeError(f"not on a TPU: {jax.devices()}")
-    from pilosa_tpu.ops import bitmap as ob
     from pilosa_tpu.ops import pallas_kernels as pk
     from pilosa_tpu.shardwidth import WORDS_PER_ROW
 
-    if not ob._USE_PALLAS:
-        raise RuntimeError("PILOSA_TPU_PALLAS=1 did not reach ops/bitmap.py")
     rng = np.random.default_rng(seed)
     shape = (SHARDS, WORDS_PER_ROW)
     a = rng.integers(0, 2**32, size=shape, dtype=np.uint32)
     b = rng.integers(0, 2**32, size=shape, dtype=np.uint32)
-    filt = b[0]
-    bc = lambda x: np.bitwise_count(x).astype(np.uint64)  # noqa: E731
-    depth = 20
-    planes, exists, sign = a[:depth], b[1], b[2]
-    consider = exists & filt
-    want_sum = (
-        int(bc(consider).sum()),
-        bc(planes & (consider & ~sign)).sum(axis=1),
-        bc(planes & (consider & sign)).sum(axis=1),
-    )
-    ad, bd = jax.device_put(a), jax.device_put(b)
-    checks = [
-        ("popcount", int(ob.popcount(ad)), int(bc(a).sum()) % 2**32),
-        ("count_and", int(ob.count_and(ad, bd)), int(bc(a & b).sum()) % 2**32),
-        ("count_andnot", int(ob.count_andnot(ad, bd)),
-         int(bc(a & ~b).sum()) % 2**32),
-        ("popcount_rows", np.asarray(ob.popcount_rows(ad)).tolist(),
-         bc(a).sum(axis=1).tolist()),
-        ("count_and_rows", np.asarray(ob.count_and_rows(ad, filt)).tolist(),
-         bc(a & filt).sum(axis=1).tolist()),
-    ]
-    got = pk.sum_counts(planes, exists, sign, filt, depth)
-    checks.append((
-        "bsi_sum_counts",
-        (int(got[0]), np.asarray(got[1]).tolist(), np.asarray(got[2]).tolist()),
-        (want_sum[0], want_sum[1].tolist(), want_sum[2].tolist()),
-    ))
-    # the GroupBy cross tally at the filtered TopN's shape (one filter
-    # row against a chunk of two dense candidate rows — the smoke's own
-    # rows are sparse, so its served TopN tallies no stack) and fused
-    # over three stacks with a filter
     pair = np.stack([b, a ^ b])
-    pair_d = jax.device_put(pair)
-    per_shard = lambda x: bc(x).sum(axis=-1).tolist()  # noqa: E731
-    checks += [
-        ("cross_counts g=1",
+    ad, bd, pair_d = (jax.device_put(x) for x in (a, b, pair))
+
+    def per_shard(x):
+        return np.bitwise_count(x).astype(np.uint64).sum(axis=-1).tolist()
+
+    checks = [
+        ("g=1",
          np.asarray(pk.cross_counts(ad[None], pair_d)).tolist(),
          per_shard(a[None, None] & pair[None])),
-        ("cross_counts fused",
+        ("fused",
          np.asarray(pk.cross_counts(ad[None], pair_d, pair_d, bd)).tolist(),
          per_shard((a & b)[None, None, None] & pair[None, :, None]
                    & pair[None, None])),
     ]
     for name, have, want in checks:
         if have != want:
-            raise AssertionError(f"pallas {name}: {have!r} != {want!r}")
-        print(f"  pallas {name:16s} compiled, equal to numpy")
+            raise AssertionError(f"cross_counts {name}: {have!r} != {want!r}")
+        print(f"  cross_counts {name:6s} compiled, equal to numpy")
 
 
 # ---------------------------------------------------------------------------
@@ -708,11 +640,11 @@ def pallas_kernels_child(seed: int) -> None:
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--pallas-kernels", action="store_true",
+    ap.add_argument("--cross-counts-kernel", action="store_true",
                     help=argparse.SUPPRESS)  # the smoke's own child mode
     args = ap.parse_args()
-    if args.pallas_kernels:
-        pallas_kernels_child(args.seed)
+    if args.cross_counts_kernel:
+        cross_counts_child(args.seed)
         return
 
     sys.stdout.reconfigure(line_buffering=True)
@@ -792,27 +724,13 @@ def main() -> None:
                     cold=True)
         http_.close()
         srv.stop_clean()
-
-        srv = Server(data_dir, os.path.join(work, "server3.log"),
-                     {"PILOSA_TPU_PALLAS": "1"})
-        print(f"restarted with PILOSA_TPU_PALLAS=1: {srv.line}")
-        http_ = Http(srv.uri)
-        check_device(http_.call("GET", "/info"))
-        repeat, reaching = pallas_queries(ref)
-        run_queries(http_, repeat, cold=True, device=device)
-        if device["count"] == 1:
-            run_queries(http_, reaching, cold=True)
-        else:
-            run_or_refuse(http_, reaching)
-        http_.close()
-        srv.stop_clean()
         srv = None
 
-        env = dict(os.environ, JAX_PLATFORMS="tpu", PILOSA_TPU_PALLAS="1")
         subprocess.run(
-            [sys.executable, os.path.abspath(__file__), "--pallas-kernels",
-             "--seed", str(args.seed)],
-            cwd=HERE, env=env, check=True, timeout=600,
+            [sys.executable, os.path.abspath(__file__),
+             "--cross-counts-kernel", "--seed", str(args.seed)],
+            cwd=HERE, env=dict(os.environ, JAX_PLATFORMS="tpu"), check=True,
+            timeout=600,
         )
     except BaseException:
         if srv is not None:  # what the server said, before its log goes

@@ -14,7 +14,6 @@ with collectives; both share the per-shard lowering here.
 from __future__ import annotations
 
 import dataclasses
-import os
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
@@ -207,9 +206,9 @@ _FALLBACK_READ_CHUNK = 64
 
 _COND_OP_NAME = {EQ: "eq", NEQ: "neq", LT: "lt", LTE: "lte", GT: "gt", GTE: "gte"}
 
-# Stacked (compiled mesh) query path: on by default; PILOSA_TPU_STACKED=0
-# forces the per-shard fallback everywhere (debugging aid).
-_STACKED_ENABLED = os.environ.get("PILOSA_TPU_STACKED", "1") in ("1", "true")
+# The differential tests patch this to False to run the per-shard loop as
+# their reference; it goes with that loop (ROADMAP C3).
+_STACKED_ENABLED = True
 
 
 class _StackedLowering:
@@ -1907,7 +1906,7 @@ class Executor:
 
         FALLBACK_STATS["count_reads"] += 1
         planmod.STATS["host_reads"] += 1
-        counts = ob.popcount_rows(jnp.stack(words_list))
+        counts = ob.popcount_rows(jnp.stack(words_list))  # dispatch-ok: per-shard path, single-device
         return int(np.asarray(counts, dtype=np.uint64).sum())
 
     def _sum_filter_words(self, idx: Index, c: Call, shard: int):
@@ -3055,7 +3054,7 @@ class Executor:
         if not pairs:
             return []
         has_src = src is not None
-        src_count = int(ob.popcount(src)) if has_src else 0
+        src_count = int(ob.popcount(src)) if has_src else 0  # dispatch-ok: per-shard path, single-device
         use_tan = spec.tanimoto > 0 and has_src
         survivors = self._topn_survivors(spec, pairs, use_tan, src_count)
         icounts: Optional[Dict[int, int]] = None

@@ -22,7 +22,6 @@ Conventions:
 
 from __future__ import annotations
 
-import os
 from functools import partial
 
 import jax
@@ -30,20 +29,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from pilosa_tpu.shardwidth import SHARD_WIDTH, WORDS_PER_ROW
-
-# Pallas dispatch: the explicit VMEM kernels in ops/pallas_kernels.py compute
-# the same reductions. Measured on a v5e chip they are at parity with these
-# jnp paths (XLA fuses and+popcount+reduce into one HBM pass already), so the
-# default stays jnp; set PILOSA_TPU_PALLAS=1 to route the fused counting ops
-# through pallas instead (dispatch points: count_and, count_and_rows,
-# count_andnot, popcount, popcount_rows).
-_USE_PALLAS = os.environ.get("PILOSA_TPU_PALLAS", "") in ("1", "true")
-
-
-def _pallas():
-    from pilosa_tpu.ops import pallas_kernels
-
-    return pallas_kernels
 
 # ---------------------------------------------------------------------------
 # Host-side packing (storage boundary only — never on the query path)
@@ -118,28 +103,16 @@ def b_not(a, exists):
 
 
 @jax.jit
-def _popcount_jnp(words) -> jnp.ndarray:
+def popcount(words) -> jnp.ndarray:
+    """Total set bits over ALL axes (uint32 scalar; wraps above 2^32 — use
+    popcount_rows + host reduce for large stacks)."""
     return jnp.sum(lax_popcount_u32(words), dtype=jnp.uint32)
 
 
-def popcount(words) -> jnp.ndarray:  # dispatch-ok: wrapper; callers serialize (run_serialized)
-    """Total set bits over ALL axes (uint32 scalar; wraps above 2^32 — use
-    popcount_rows + host reduce for large stacks)."""
-    if _USE_PALLAS:
-        return _pallas().popcount(words)
-    return _popcount_jnp(words)
-
-
 @jax.jit
-def _popcount_rows_jnp(words) -> jnp.ndarray:
-    return jnp.sum(lax_popcount_u32(words), axis=-1, dtype=jnp.uint32)
-
-
-def popcount_rows(words) -> jnp.ndarray:  # dispatch-ok: wrapper; callers serialize (run_serialized)
+def popcount_rows(words) -> jnp.ndarray:
     """Set bits per row: sums over the trailing word axis only."""
-    if _USE_PALLAS and words.ndim == 2:
-        return _pallas().popcount_rows(words)
-    return _popcount_rows_jnp(words)
+    return jnp.sum(lax_popcount_u32(words), axis=-1, dtype=jnp.uint32)
 
 
 def lax_popcount_u32(words):
@@ -147,33 +120,19 @@ def lax_popcount_u32(words):
 
 
 @jax.jit
-def _count_and_jnp(a, b) -> jnp.ndarray:
-    return jnp.sum(jax.lax.population_count(jnp.bitwise_and(a, b)), dtype=jnp.uint32)
-
-
-def count_and(a, b) -> jnp.ndarray:  # dispatch-ok: wrapper; callers serialize (run_serialized)
+def count_and(a, b) -> jnp.ndarray:
     """Fused popcount(a & b) — Count(Intersect(...)) without materializing
     the intersection (reference: intersectionCount, roaring.go:3121).
     All-axes uint32 sum; see count convention above."""
-    # the pallas kernel flattens both operands independently, so it only
-    # handles identically-shaped operands; broadcasting falls back to jnp
-    if _USE_PALLAS and getattr(a, "shape", None) == getattr(b, "shape", None):
-        return _pallas().count_and(a, b)
-    return _count_and_jnp(a, b)
+    return jnp.sum(jax.lax.population_count(jnp.bitwise_and(a, b)), dtype=jnp.uint32)
 
 
 @jax.jit
-def _count_and_rows_jnp(a, b) -> jnp.ndarray:
+def count_and_rows(a, b) -> jnp.ndarray:
+    """Fused per-row intersection count (trailing axis reduced only)."""
     return jnp.sum(
         jax.lax.population_count(jnp.bitwise_and(a, b)), axis=-1, dtype=jnp.uint32
     )
-
-
-def count_and_rows(a, b) -> jnp.ndarray:  # dispatch-ok: wrapper; callers serialize (run_serialized)
-    """Fused per-row intersection count (trailing axis reduced only)."""
-    if _USE_PALLAS and a.ndim == 2 and getattr(b, "ndim", 1) == 1:
-        return _pallas().count_and_rows(a, b)
-    return _count_and_rows_jnp(a, b)
 
 
 @jax.jit
@@ -201,16 +160,10 @@ def gather_tally_sorted(src, idx, mask, starts, ends) -> jnp.ndarray:
 
 
 @jax.jit
-def _count_andnot_jnp(a, b) -> jnp.ndarray:
+def count_andnot(a, b) -> jnp.ndarray:
     return jnp.sum(
         jax.lax.population_count(jnp.bitwise_and(a, jnp.bitwise_not(b))), dtype=jnp.uint32
     )
-
-
-def count_andnot(a, b) -> jnp.ndarray:  # dispatch-ok: wrapper; callers serialize (run_serialized)
-    if _USE_PALLAS and getattr(a, "shape", None) == getattr(b, "shape", None):
-        return _pallas().count_andnot(a, b)
-    return _count_andnot_jnp(a, b)
 
 
 @jax.jit

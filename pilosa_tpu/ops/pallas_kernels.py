@@ -1,31 +1,25 @@
-"""Pallas TPU kernels for the hot bitmap reductions.
+"""The one hand-written TPU kernel: the GroupBy cross tally.
 
-These are the [HOT] paths from the reference (intersectionCount
-roaring/roaring.go:3121, popcount :5291, the TopN tally fragment.go:1570,
-BSI sum fragment.go:1111) as explicit single-pass VMEM kernels: one HBM
-read per operand, popcount + reduce fused on the VPU, sequential-grid
-accumulation into SMEM/VMEM partials. The jnp paths in ops/bitmap.py /
-ops/bsi.py / exec/groupby.py compute the same functions and serve as the
-differential oracle.
+`cross_counts` (reference: the GroupBy tally, executor.go:3063) forms a
+G (x M) x R cross of AND + popcount over multi-GB `[rows, S, W]` stacks on
+tiles in VMEM, so every operand row is read from HBM once and only the
+per-shard counts are written. `exec/groupby.py` `cross_tally` selects it
+from what its operands are (every stack on one TPU, lane-aligned) and
+otherwise runs XLA's `_counts_cross`, which is also the kernel's reference
+in the tests. XLA ran that cross as a per-row slice-and-copy loop at 0.40 %
+of the HBM roofline where the kernel reads 12.9 % (ledger PR 27,
+`taxi-1b.q1-q4`). Every other counting op is one pass over its operands,
+which XLA fuses and tiles by itself (Count: 88.6 % of the roofline on one
+chip, 85.0 % on the mesh; ledger PR 30), and has no kernel here.
 
-All kernels:
-- operate on uint32 word arrays (bit b of word w = position 32w+b),
-- accumulate in int32 (wrap-compatible with the uint32 count convention
+The kernel
+- operates on uint32 word arrays (bit b of word w = position 32w+b),
+- accumulates in int32 (wrap-compatible with the uint32 count convention
   in ops/bitmap.py),
-- compile for the TPU only: nothing here chooses interpret mode. A test
+- compiles for the TPU only: nothing here chooses interpret mode. A test
   that wants the interpreter asks for it around the call
   (`pltpu.force_tpu_interpret_mode()`); anywhere else a backend that
   cannot compile the kernel raises.
-
-Disposition. The count / per-row / BSI-sum kernels are RETAINED AS ORACLE
-ONLY, default-off behind PILOSA_TPU_PALLAS=1 (ops/bitmap.py): that op mix
-is one pass over its operands either way, and XLA already fuses and tiles
-it (88 % of the HBM roofline in the segment cell, PERF.md). `cross_counts`
-is the exception and SHIPS ON THE DEFAULT PATH (exec/groupby.py
-cross_tally, whenever the operands are on one TPU): the GroupBy cross
-tally is a G x R cross over multi-GB stacks, which XLA ran as a per-row
-slice-and-copy loop at 0.4 % of the roofline (PERF.md, PR 27); tiling the
-cross in VMEM reads every row once.
 """
 
 from __future__ import annotations
@@ -38,225 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# One row of the default shard width = 32768 words = 128 KiB; a (256, 128)
-# word tile per operand keeps 2-3 operands well under VMEM while amortizing
-# grid overhead.
-_TILE_SUBLANES = 256
-_LANES = 128
-
-
-def _flatten_pad(x: jnp.ndarray, tile_words: int) -> jnp.ndarray:
-    """Flatten to [M, 128] words, zero-padded to a tile multiple (zero words
-    contribute nothing to any popcount reduction used here)."""
-    flat = x.reshape(-1)
-    n = flat.shape[0]
-    per_tile = tile_words * _LANES
-    pad = (-n) % per_tile
-    if pad:
-        flat = jnp.concatenate([flat, jnp.zeros(pad, dtype=flat.dtype)])
-    return flat.reshape(-1, _LANES)
-
-
-def _count2_kernel(op, a_ref, b_ref, out_ref):
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        out_ref[0, 0] = jnp.int32(0)
-
-    words = op(a_ref[:], b_ref[:])
-    out_ref[0, 0] += jnp.sum(
-        jax.lax.population_count(words.astype(jnp.int32)), dtype=jnp.int32
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("opname",))
-def _count2(a, b, opname: str):
-    op = {
-        "and": jnp.bitwise_and,
-        "or": jnp.bitwise_or,
-        "xor": jnp.bitwise_xor,
-        "andnot": lambda x, y: jnp.bitwise_and(x, jnp.bitwise_not(y)),
-    }[opname]
-    av = _flatten_pad(a.astype(jnp.uint32), _TILE_SUBLANES)
-    bv = _flatten_pad(b.astype(jnp.uint32), _TILE_SUBLANES)
-    m = av.shape[0]
-    grid = m // _TILE_SUBLANES
-    out = pl.pallas_call(
-        functools.partial(_count2_kernel, op),
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
-        grid=(grid,),
-        in_specs=[
-            pl.BlockSpec((_TILE_SUBLANES, _LANES), lambda i: (i, 0)),
-            pl.BlockSpec((_TILE_SUBLANES, _LANES), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM
-        ),
-    )(av, bv)
-    return out[0, 0].astype(jnp.uint32)
-
-
-def count_and(a, b) -> jnp.ndarray:  # dispatch-ok: wrapper; callers serialize (run_serialized)
-    """Fused popcount(a & b): Count(Intersect) in one HBM pass."""
-    return _count2(a, b, "and")
-
-
-def count_or(a, b) -> jnp.ndarray:  # dispatch-ok: wrapper; callers serialize (run_serialized)
-    return _count2(a, b, "or")
-
-
-def count_xor(a, b) -> jnp.ndarray:  # dispatch-ok: wrapper; callers serialize (run_serialized)
-    return _count2(a, b, "xor")
-
-
-def count_andnot(a, b) -> jnp.ndarray:  # dispatch-ok: wrapper; callers serialize (run_serialized)
-    return _count2(a, b, "andnot")
-
-
-def _popcount_kernel(a_ref, out_ref):
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        out_ref[0, 0] = jnp.int32(0)
-
-    out_ref[0, 0] += jnp.sum(
-        jax.lax.population_count(a_ref[:].astype(jnp.int32)), dtype=jnp.int32
-    )
-
-
-@jax.jit
-def popcount(a) -> jnp.ndarray:
-    """Total set bits over all axes."""
-    av = _flatten_pad(a.astype(jnp.uint32), _TILE_SUBLANES)
-    grid = av.shape[0] // _TILE_SUBLANES
-    out = pl.pallas_call(
-        _popcount_kernel,
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
-        grid=(grid,),
-        in_specs=[pl.BlockSpec((_TILE_SUBLANES, _LANES), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),
-    )(av)
-    return out[0, 0].astype(jnp.uint32)
-
-
-# -- per-row tallies (TopN / Rows paths; reference fragment.go:1570 top) ----
-
-_ROW_TILE = 8  # rows per grid step
-
-
-def _rows_kernel(masked: bool, a_ref, *rest):
-    if masked:
-        filt_ref, out_ref = rest
-        words = jnp.bitwise_and(a_ref[:], filt_ref[:])
-    else:
-        (out_ref,) = rest
-        words = a_ref[:]
-    pc = jax.lax.population_count(words.astype(jnp.int32))
-    sums = jnp.sum(pc, axis=-1, keepdims=True)  # (ROW_TILE, 1)
-    out_ref[:] = jnp.broadcast_to(sums, (sums.shape[0], _LANES))
-
-
-@functools.partial(jax.jit, static_argnames=("masked",))
-def _rows_counts(stack, filt, masked: bool):
-    r, w = stack.shape
-    assert w % _LANES == 0, f"row width {w} not a lane multiple"
-    pad_r = (-r) % _ROW_TILE
-    if pad_r:
-        stack = jnp.concatenate(
-            [stack, jnp.zeros((pad_r, w), dtype=stack.dtype)], axis=0
-        )
-    rp = stack.shape[0]
-    in_specs = [pl.BlockSpec((_ROW_TILE, w), lambda i: (i, 0))]
-    args = [stack.astype(jnp.uint32)]
-    if masked:
-        in_specs.append(pl.BlockSpec((1, w), lambda i: (0, 0)))
-        args.append(filt.astype(jnp.uint32).reshape(1, w))
-    out = pl.pallas_call(
-        functools.partial(_rows_kernel, masked),
-        out_shape=jax.ShapeDtypeStruct((rp, _LANES), jnp.int32),
-        grid=(rp // _ROW_TILE,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((_ROW_TILE, _LANES), lambda i: (i, 0)),
-    )(*args)
-    return out[:r, 0].astype(jnp.uint32)
-
-
-def popcount_rows(stack) -> jnp.ndarray:  # dispatch-ok: wrapper; callers serialize (run_serialized)
-    """Per-row set-bit counts for a [rows, W] stack."""
-    return _rows_counts(stack, None, False)
-
-
-def count_and_rows(  # dispatch-ok: wrapper; callers serialize (run_serialized)
-    stack, filter_words
-) -> jnp.ndarray:
-    """Per-row popcount(row & filter): the TopN tally against a filter row."""
-    return _rows_counts(stack, filter_words, True)
-
-
-# -- fused BSI sum tally (reference fragment.go:1111) ------------------------
-
-_BSI_TILE = 2048  # lanes of words per grid step; x (depth+3) rows in VMEM
-
-
-def _bsi_sum_kernel(depth: int, planes_ref, rows_ref, out_ref):
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        out_ref[:] = jnp.zeros_like(out_ref)
-
-    exists = rows_ref[0:1, :]
-    sign = rows_ref[1:2, :]
-    filt = rows_ref[2:3, :]
-    consider = jnp.bitwise_and(exists, filt)
-    nrow = jnp.bitwise_and(sign, consider)
-    prow = jnp.bitwise_and(consider, jnp.bitwise_not(sign))
-    pc = jax.lax.population_count
-
-    planes = planes_ref[:]
-    pos = jnp.sum(
-        pc(jnp.bitwise_and(planes, prow).astype(jnp.int32)), axis=-1, keepdims=True
-    )
-    neg = jnp.sum(
-        pc(jnp.bitwise_and(planes, nrow).astype(jnp.int32)), axis=-1, keepdims=True
-    )
-    cnt = jnp.sum(pc(consider.astype(jnp.int32)), axis=-1, keepdims=True)
-    # rows: 0 = consider-count, 1..depth = pos, depth+1..2depth = neg
-    step = jnp.concatenate([cnt, pos, neg], axis=0)  # (1+2*depth, 1)
-    out_ref[:] += jnp.broadcast_to(step, (1 + 2 * depth, _LANES))
-
-
-@functools.partial(jax.jit, static_argnames=("bit_depth",))
-def sum_counts(planes, exists, sign, filter_words, bit_depth: int):
-    """Fused BSI-sum tally: one pass over the plane stack.
-
-    Same contract as ops.bsi.sum_counts: returns (count, pos_counts[depth],
-    neg_counts[depth]) as uint32 device scalars/vectors."""
-    w = planes.shape[-1]
-    pad = (-w) % _BSI_TILE
-    if pad:
-        z = lambda x: jnp.concatenate(
-            [x, jnp.zeros(x.shape[:-1] + (pad,), dtype=x.dtype)], axis=-1
-        )
-        planes, exists, sign, filter_words = (
-            z(planes), z(exists), z(sign), z(filter_words),
-        )
-    wp = planes.shape[-1]
-    rows = jnp.stack(
-        [exists.astype(jnp.uint32), sign.astype(jnp.uint32), filter_words.astype(jnp.uint32)]
-    )
-    out = pl.pallas_call(
-        functools.partial(_bsi_sum_kernel, bit_depth),
-        out_shape=jax.ShapeDtypeStruct((1 + 2 * bit_depth, _LANES), jnp.int32),
-        grid=(wp // _BSI_TILE,),
-        in_specs=[
-            pl.BlockSpec((bit_depth, _BSI_TILE), lambda i: (0, i)),
-            pl.BlockSpec((3, _BSI_TILE), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((1 + 2 * bit_depth, _LANES), lambda i: (0, 0)),
-    )(planes.astype(jnp.uint32), rows)
-    col = out[:, 0].astype(jnp.uint32)
-    return col[0], col[1 : 1 + bit_depth], col[1 + bit_depth :]
-
-
-# -- GroupBy cross tally (exec/groupby.py; reference executor.go:3063) -------
 
 # The tally never reshapes or copies a stack: it reads each [rows, S, W]
 # operand in the layout the device already keeps it in. The TPU runtime
@@ -271,6 +46,7 @@ _CROSS_PARTS = 32  # partial-count vregs carried through the word loop
 _CROSS_WORDS = {True: 32768, False: 4096}  # words of a row per grid step
 _CROSS_VMEM_BYTES = 40 << 20
 _SUBLANES = 8
+_LANES = 128
 
 
 def _cross_refs(mt: int, has_filt: bool, refs):

@@ -26,6 +26,5 @@ from pilosa_tpu.tier.store import (  # noqa: F401
     ObjectCorrupt,
     ObjectMissing,
     ObjectStore,
-    SlowStoreWrapper,
     StoreError,
 )

@@ -28,7 +28,6 @@ uploaded" strictly before "local copy deleted".
 from __future__ import annotations
 
 import os
-import time
 from typing import Callable, Dict, List, Optional
 
 
@@ -230,34 +229,3 @@ class MemoryStore(ObjectStore):
     def delete(self, key: str) -> None:
         fault_point("store.delete", key)
         self._objects.pop(key, None)
-
-
-class SlowStoreWrapper(ObjectStore):
-    """Fixed-latency wrapper for benchmarks: models a remote object
-    store's per-op round trip without a network (bench.py tier families
-    report demote/hydrate throughput against it honestly)."""
-
-    def __init__(self, inner: ObjectStore, delay_s: float):
-        self.inner = inner
-        self.delay_s = float(delay_s)
-
-    def _pause(self) -> None:
-        if self.delay_s > 0:
-            time.sleep(self.delay_s)
-
-    def put(self, key: str, data: bytes) -> None:
-        self._pause()
-        self.inner.put(key, data)
-
-    def get(self, key: str) -> bytes:
-        self._pause()
-        return self.inner.get(key)
-
-    def head(self, key: str) -> Optional[Dict[str, int]]:
-        return self.inner.head(key)
-
-    def list(self, prefix: str = "") -> List[str]:
-        return self.inner.list(prefix)
-
-    def delete(self, key: str) -> None:
-        self.inner.delete(key)

@@ -6,7 +6,6 @@ client paying the full round trip (the reference gives concurrent
 requests no cross-request amortization; its worker pool only bounds
 fan-out, executor.go:2559-2613)."""
 
-import os
 import threading
 import time
 
@@ -39,6 +38,16 @@ class TestBatchable:
         assert not batchmod.batchable(parse("TopN(f, n=3)"))
 
 
+def _await_queued(b, n, index="i"):
+    """Wait until n waiters sit in the batcher's queue for `index`."""
+    for _ in range(1000):
+        with b._mu:
+            if len(b._queue.get(index, [])) >= n:
+                return
+        time.sleep(0.005)
+    raise AssertionError("waiters never queued")
+
+
 class TestGroupCommit:
     def test_leader_runs_alone_immediately(self):
         b = CountBatcher()
@@ -47,22 +56,16 @@ class TestGroupCommit:
         assert out == [7]
         assert len(calls) == 1 and len(calls[0].calls) == 1
 
-    @pytest.mark.skipif(
-        os.environ.get("PILOSA_TPU_RACE_CHECK") == "1",
-        reason="timing-window test: the two 50 ms sleep windows assume "
-        "followers enqueue while the leader is held, and the race "
-        "checker's per-access instrumentation can stretch follower "
-        "startup past the window (observed flaky); the merge behavior "
-        "is covered deterministically by the adaptive-hold tests",
-    )
     def test_waiters_merge_into_one_execution(self):
         b = CountBatcher()
+        entered = threading.Event()
         release = threading.Event()
         execs = []
 
         def execute(q):
             execs.append(len(q.calls))
             if len(execs) == 1:
+                entered.set()
                 release.wait(5)  # hold the leader so followers queue
             return list(range(len(q.calls)))
 
@@ -73,13 +76,13 @@ class TestGroupCommit:
 
         leader = threading.Thread(target=client, args=("leader",))
         leader.start()
-        time.sleep(0.05)  # leader is now inside execute()
+        assert entered.wait(5)  # leader is now inside execute()
         followers = [
             threading.Thread(target=client, args=(f"f{i}",)) for i in range(4)
         ]
-        for t in followers:
+        for i, t in enumerate(followers):
             t.start()
-        time.sleep(0.05)  # followers enqueued behind the busy leader
+            _await_queued(b, i + 1)  # f{i} enqueued behind the busy leader
         release.set()
         leader.join(5)
         for t in followers:
@@ -112,28 +115,19 @@ class TestGroupCommit:
         def client(name):
             results[name] = b.run("i", parse("Count(Row(f=1))"), execute)
 
-        def enqueue_until(n):
-            # deterministically wait until n waiters sit in the queue
-            for _ in range(500):
-                with b._mu:
-                    if len(b._queue.get("i", [])) >= n:
-                        return
-                time.sleep(0.005)
-            raise AssertionError("waiters never queued")
-
         leader = threading.Thread(target=client, args=("L",))
         leader.start()
         assert entered[0].wait(5)  # leader inside exec 0
         ab = [threading.Thread(target=client, args=(n,)) for n in ("A", "B")]
         for t in ab:
             t.start()
-        enqueue_until(2)  # A, B queued
+        _await_queued(b, 2)  # A, B queued
         gates[0].set()  # leader finishes; round [A, B] starts (exec 1)
         assert entered[1].wait(5)
         cd = [threading.Thread(target=client, args=(n,)) for n in ("C", "D")]
         for t in cd:
             t.start()
-        enqueue_until(2)  # C, D queued behind the running round
+        _await_queued(b, 2)  # C, D queued behind the running round
         gates[1].set()  # round [A, B] finishes -> C promoted
         for t in [leader] + ab + cd:
             t.join(5)
@@ -143,12 +137,14 @@ class TestGroupCommit:
 
     def test_error_isolation(self):
         b = CountBatcher()
+        entered = threading.Event()
         release = threading.Event()
         state = {"n": 0}
 
         def execute(q):
             state["n"] += 1
             if state["n"] == 1:
+                entered.set()
                 release.wait(5)
                 return [1]
             if any("boom" in c.children[0].args for c in q.calls):
@@ -165,12 +161,12 @@ class TestGroupCommit:
 
         leader = threading.Thread(target=client, args=("L", "Count(Row(f=1))"))
         leader.start()
-        time.sleep(0.05)
+        assert entered.wait(5)
         good = threading.Thread(target=client, args=("good", "Count(Row(f=1))"))
         bad = threading.Thread(target=client, args=("bad", "Count(Row(boom=1))"))
         good.start()
         bad.start()
-        time.sleep(0.05)
+        _await_queued(b, 2)
         release.set()
         for t in (leader, good, bad):
             t.join(5)
@@ -181,12 +177,14 @@ class TestGroupCommit:
 
     def test_batch_size_cap(self):
         b = CountBatcher()
+        entered = threading.Event()
         release = threading.Event()
         execs = []
 
         def execute(q):
             execs.append(len(q.calls))
             if len(execs) == 1:
+                entered.set()
                 release.wait(5)
             return [0] * len(q.calls)
 
@@ -197,10 +195,10 @@ class TestGroupCommit:
             for _ in range(batchmod.MAX_BATCH_CALLS + 10)
         ]
         threads[0].start()
-        time.sleep(0.05)
+        assert entered.wait(5)
         for t in threads[1:]:
             t.start()
-        time.sleep(0.2)
+        _await_queued(b, len(threads) - 1)
         release.set()
         for t in threads:
             t.join(5)
@@ -218,12 +216,14 @@ class TestGroupCommit:
         call wasted up to ~2x device work on odd batch sizes. Pads are
         masked out: every waiter gets exactly its own results."""
         b = CountBatcher()
+        entered = threading.Event()
         release = threading.Event()
         merged_queries = []
 
         def execute(q):
             merged_queries.append(q)
             if len(merged_queries) == 1:
+                entered.set()
                 release.wait(5)
             return list(range(len(q.calls)))
 
@@ -233,7 +233,7 @@ class TestGroupCommit:
             )
         ]
         threads[0].start()
-        time.sleep(0.05)  # let it take leadership and block in execute
+        assert entered.wait(5)  # it took leadership and blocks in execute
         outs = []
         for _ in range(3):  # 3 waiters -> merged round of 3, padded to 4
             th = threading.Thread(
@@ -243,7 +243,7 @@ class TestGroupCommit:
             )
             th.start()
             threads.append(th)
-        time.sleep(0.1)
+        _await_queued(b, 3)
         release.set()
         for th in threads:
             th.join(5)
@@ -280,18 +280,20 @@ class TestGroupCommit:
 
     def test_indexes_batch_independently(self):
         b = CountBatcher()
+        entered = threading.Event()
         release = threading.Event()
         execs = []
 
         def execute(q):
             execs.append(len(q.calls))
             if len(execs) == 1:
+                entered.set()
                 release.wait(5)
             return [0] * len(q.calls)
 
         t1 = threading.Thread(target=lambda: b.run("a", parse("Count(Row(f=1))"), execute))
         t1.start()
-        time.sleep(0.05)
+        assert entered.wait(5)
         # different index: must NOT queue behind index a's leader
         out = b.run("b", parse("Count(Row(f=1))"), lambda q: [42])
         assert out == [42]
@@ -467,13 +469,7 @@ class TestBatchSizeStat:
         ]
         for th in followers:
             th.start()
-        # wait for all three to be queued behind the leader
-        deadline = time.monotonic() + 5
-        while time.monotonic() < deadline:
-            with b._mu:
-                if len(b._queue.get("i", ())) == 3:
-                    break
-            time.sleep(0.002)
+        _await_queued(b, 3)  # all three queued behind the leader
         release.set()
         leader.join(5)
         for th in followers:
@@ -507,14 +503,9 @@ class TestBatchSizeStat:
             daemon=True,
         )
         follower.start()
-        deadline = time.monotonic() + 5
-        queue_obj = None
-        while time.monotonic() < deadline:
-            with b._mu:
-                queue_obj = b._queue.get("i")
-                if queue_obj is not None and len(queue_obj) == 1:
-                    break
-            time.sleep(0.002)
+        _await_queued(b, 1)
+        with b._mu:
+            queue_obj = b._queue.get("i")
         assert isinstance(queue_obj, deque), type(queue_obj)
         release.set()
         leader.join(5)

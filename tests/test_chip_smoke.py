@@ -45,53 +45,10 @@ def test_smoke_parts_agree_with_a_served_index_small():
         assert [n for _, n in readback] == [
             len(ref.row("f", 1)), len(ref.row("f", 0)),
         ]
-        # the reference moved with the writes: the Pallas-pass repeat
-        # (plain jnp here) still agrees
-        for queries in cs.pallas_queries(ref):
-            cs.run_queries(http_, queries, cold=True)
+        # the reference moved with the writes: the query list built from
+        # it again still agrees
+        cs.run_queries(http_, cs.read_queries(ref), cold=False)
         http_.close()
-
-
-def test_pallas_pass_reaches_pallas_dispatch_points(monkeypatch):
-    """With the flag on, the smoke's Pallas pass must not be vacuous: its
-    MinRow/MaxRow and Tanimoto TopN run `popcount` and `popcount_rows`
-    through the Pallas kernels (interpreted here, on request), and every
-    answer still equals the reference."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    from pilosa_tpu.ops import bitmap as ob
-    from pilosa_tpu.ops import pallas_kernels as pk
-    from pilosa_tpu.parallel import mesh as pmesh
-
-    reached = []
-    for name in ("popcount", "popcount_rows"):
-        real = getattr(pk, name)
-
-        def counted(*args, _real=real, _name=name):
-            reached.append(_name)
-            with pltpu.force_tpu_interpret_mode():
-                return _real(*args)
-
-        monkeypatch.setattr(pk, name, counted)
-    with ClusterHarness(1, in_memory=True) as c:
-        uri = c[0].node.uri
-        http_ = cs.Http(uri)
-        data = cs.Data(seed=5, shards=2, shard_width=1 << 20)
-        ref = cs.Reference(data)
-        cs.create_schema(http_)
-        cs.load(uri, data)
-        monkeypatch.setattr(ob, "_USE_PALLAS", True)
-        # single device: the interpreter's host callbacks cannot be
-        # partitioned over the suite's 8-virtual-device mesh
-        old_mesh = pmesh.active_mesh()
-        pmesh.set_active_mesh(None)
-        try:
-            for queries in cs.pallas_queries(ref):
-                cs.run_queries(http_, queries, cold=True)
-        finally:
-            pmesh.set_active_mesh(old_mesh)
-        http_.close()
-    assert {"popcount", "popcount_rows"} <= set(reached)
 
 
 def test_a_wrong_answer_fails_the_smoke():

@@ -15,8 +15,8 @@ layer, and certifies:
   path, the HTTP fan-out path (mesh disabled per node), and a host-side
   truth model (python sets over the imported positions);
 - warm per-query wall time for the mesh path vs the HTTP fan-out path
-  (`meshN_count_ms` / `httpN_count_ms` — the numbers bench.py records
-  as mesh16_count_ms / mesh32_count_ms).
+  (`meshN_count_ms` / `httpN_count_ms`: virtual CPU devices, so counts
+  of nothing anyone deploys).
 
 The parent writes MULTICHIP_r06.json; CI uploads it as an artifact.
 Run locally: `python tools/mesh_cert.py --out MULTICHIP_r06.json`.
@@ -71,7 +71,7 @@ def child(n_devices: int) -> dict:
         # every node owns live shards. Volumes stay modest on purpose:
         # the virtual-device collectives schedule 32 participants onto
         # ~2 CI cores, so the cert certifies correctness + counters, not
-        # throughput (bench.py owns the numbers).
+        # throughput.
         window = min(4, n_shards) * SHARD_WIDTH
         for r, hi in ((1, window), (2, window), (3, n_shards * SHARD_WIDTH)):
             c = rng.integers(0, hi, 4000).astype(np.uint64)
