@@ -17,8 +17,9 @@ one process holds the chip and this parent never initialises a JAX backend
    each cold answer must carry at least one `exec.dispatch` span
    (unfiltered TopN is served from the rank cache: the one exception),
    and the GroupBy's cross tally must have run as the VMEM kernel on one
-   chip (`groupby.kernel_tallies` on `/debug/vars`), as the XLA program
-   on several; every cold dispatch must have run as one program over all
+   chip (`groupby.kernel_tallies` on `/debug/vars`) over the view's four
+   resident extents as they lie (`groupby.assembled_stacks` flat), as the
+   XLA program on several; every cold dispatch must have run as one program over all
    the devices the server holds (`mesh.devices` on its span: 4 on a
    four-chip host, 1 on one chip);
 4. writes (a PQL `Set`, then an `/import` burst large enough to cross the
@@ -420,19 +421,26 @@ def ask(http_: Http, pql: str) -> tuple:
     return _normalise(out["results"][0]), placed, took
 
 
+TALLY_GAUGES = ("kernel_tallies", "xla_tallies", "inplace_tallies",
+                "assembled_stacks")
+
+
 def tally_counts(http_: Http) -> tuple:
-    """(kernel, xla) cross tallies so far (exec/groupby.py cross_tally)."""
+    """(kernel, xla, in-place, assembled): the cross tallies so far by
+    program, those that read a view's extents where they lie, and the
+    operands concatenated for a tally (exec/groupby.py)."""
     vars_ = http_.call("GET", "/debug/vars")
-    return (int(vars_.get("groupby.kernel_tallies", 0)),
-            int(vars_.get("groupby.xla_tallies", 0)))
+    return tuple(int(vars_.get(f"groupby.{g}", 0)) for g in TALLY_GAUGES)
 
 
-def check_tally_program(family: str, kernel: int, xla: int,
-                        device: dict) -> None:
+def check_tally_program(family: str, kernel: int, xla: int, device: dict,
+                        assembled: int = 0) -> None:
     """A cold GroupBy or filtered TopN tallies its cross on the device.
-    On one TPU chip that is the VMEM kernel and never the XLA loop; stacks
-    sharded over several devices, or another backend, are the XLA
-    program's. A JAX upgrade that breaks the kernel fails here."""
+    On one TPU chip that is the VMEM kernel and never the XLA loop, and it
+    reads the view's resident extents in place: no operand is written
+    again as one stack (`assembled`). Stacks sharded over several devices,
+    or another backend, are the XLA program's. A JAX upgrade that breaks
+    the kernel fails here."""
     one_chip = device["platform"] == "tpu" and device["count"] == 1
     ran, other = (kernel, xla) if one_chip else (xla, kernel)
     # a filtered TopN whose candidates are all sparse rows tallies no stack
@@ -440,6 +448,11 @@ def check_tally_program(family: str, kernel: int, xla: int,
         raise AssertionError(
             f"{family}: {kernel} kernel and {xla} XLA cross tallies on "
             f"{device['count']} x {device['platform']}"
+        )
+    if one_chip and assembled:
+        raise AssertionError(
+            f"{family}: {assembled} operand(s) concatenated for a tally on "
+            f"one chip, where the kernel reads the extents in place"
         )
 
 
@@ -468,9 +481,11 @@ def run_queries(http_: Http, queries: list, cold: bool,
         if cold and device is not None:
             check_placement(family, placed, device)
         if tallied:
-            kernel, xla = (a - b for a, b in zip(tally_counts(http_), before))
-            check_tally_program(family, kernel, xla, device)
-            print(f"  {family:22s} cross tallies: kernel={kernel} xla={xla}")
+            kernel, xla, inplace, assembled = (
+                a - b for a, b in zip(tally_counts(http_), before))
+            check_tally_program(family, kernel, xla, device, assembled)
+            print(f"  {family:22s} cross tallies: kernel={kernel} xla={xla} "
+                  f"in place={inplace} assembled stacks={assembled}")
         if got != want:
             raise AssertionError(f"{family}: {pql} -> {got!r}, want {want!r}")
         if cold and n_dispatch < min_dispatches:

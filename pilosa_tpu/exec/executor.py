@@ -3272,7 +3272,8 @@ class Executor:
         self, idx, child_fields, child_rows, filter_call, shard_list
     ) -> Optional[Dict[Tuple[int, ...], int]]:
         """Tally the whole GroupBy cross-product in O(depth) batched device
-        dispatches over stacked [R, S, W] operands (exec/groupby.py),
+        dispatches over stacked [R, S, W] operands, each handed over as
+        the tuple of its resident extents (exec/groupby.py),
         replacing the per-(prefix, depth) dispatch + host sync of the
         recursive walk. Returns None to fall back to the per-shard path
         (stacked lowering unsupported for this shape/budget)."""
@@ -3317,7 +3318,11 @@ class Executor:
                     ).rows_full()
                 for v, rows in zip(child_views, child_rows):
                     low._stack_guard(v, mult=max(len(rows), 1))
-                    p = v.plane_stack(rows, low.shards)
+                    # the resident extents as they are: every child is
+                    # staged over low.shards at one extent size, so the
+                    # parts line up span for span and the tally reads
+                    # them in place (exec/groupby.py)
+                    p = v.plane_stack(rows, low.shards, parts=True)
                     if p is None:
                         return {}
                     planes_list.append(p)

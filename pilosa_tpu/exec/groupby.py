@@ -6,8 +6,12 @@ group element at a time — in the round-1 rebuild that meant one device
 dispatch + host sync per (group-prefix, depth).
 
 Shapes: `planes` stacks are uint32[R, S, W] (candidate rows x shards x
-words, built by View.plane_stack and shard-axis-sharded under an active
-mesh); the accumulator `acc` is uint32[G, S, W] for the G live prefixes.
+words; shard-axis-sharded under an active mesh); the accumulator `acc` is
+uint32[G, S, W] for the G live prefixes. An operand arrives as one array
+or as the TUPLE of its resident per-extent parts `[R, S_e, W]`, as
+`View.plane_stack(parts=True)` stages them (hbm/residency.py): a view of
+more than one extent of shards is never written again as one stack for a
+GroupBy the kernel tallies in one shot.
 Counts are reduced over W on device in uint32 (one shard holds at most
 2^20 bits, so a per-shard count can never wrap) and over the shard axis
 on the host in exact uint64 — the same overflow discipline as
@@ -21,11 +25,18 @@ Two programs tally a cross (`cross_tally` picks by what its operands are):
   with a third stack the last prefix level (acc[g] & mid[m]) is formed
   there too and never written to HBM. A 2- or 3-level GroupBy whose
   [groups, S] count read fits `_ONESHOT_READ_BYTES` is therefore ONE
-  launch and ONE host read whatever the number of shards.
+  launch and ONE host read whatever the number of shards, and reads its
+  operands' parts in place (one `pallas_call` per extent inside the one
+  program; `inplace_tallies`).
 - `_counts_cross`, the XLA program (a lax.map over the candidate rows
   that re-reads `acc` per row), for every other backend and for stacks
   sharded over a mesh; it is also the kernel's differential oracle. Its
   [G, S, W] intermediate is why prefixes are chunked for it.
+
+Everything but the kernel's one-shot path works on whole stacks — the XLA
+program, the pruned descent's row selections, a cross of more than three
+levels — and concatenates an operand given as parts once, where it is
+first needed (`assembled_stacks`).
 
 Cross-products too deep or too large for one read are tallied level-wise:
 at depth d one tally counts every live prefix against every candidate
@@ -47,8 +58,13 @@ import jax.numpy as jnp
 import numpy as np
 
 # Dispatch accounting (tests assert O(depth), not O(groups), dispatches);
-# the two tally counts are published as groupby.* gauges (server/node.py).
-STATS = {"evals": 0, "kernel_tallies": 0, "xla_tallies": 0}
+# the tally counts are published as groupby.* gauges (server/node.py):
+# tallies by program, tallies that read more than one extent in place, and
+# operands concatenated into one stack for a tally.
+STATS = {
+    "evals": 0, "kernel_tallies": 0, "xla_tallies": 0,
+    "inplace_tallies": 0, "assembled_stacks": 0,
+}
 
 KERNEL_PROGRAM = "jit__cross_counts_vmem"
 XLA_PROGRAM = "jit__counts_cross"
@@ -101,16 +117,45 @@ def _counts_cross(acc, planes):
     return jnp.transpose(out, (1, 0, 2))
 
 
+def _parts(x) -> tuple:
+    """An operand as the tuple of its per-extent parts (one array: one)."""
+    return x if isinstance(x, tuple) else (x,)
+
+
+def _shape(x) -> Tuple[int, int, int]:
+    """(rows, shards, words) of a stack given whole or as parts."""
+    parts = _parts(x)
+    rows, _, w = parts[0].shape
+    return int(rows), sum(int(p.shape[1]) for p in parts), int(w)
+
+
+def _assembled(x):
+    """The whole [R, S, W] stack of an operand (None stays None). Parts
+    are concatenated along the shard axis, which writes the operand
+    again: counted."""
+    if not isinstance(x, tuple):
+        return x
+    if len(x) == 1:
+        return x[0]
+    STATS["assembled_stacks"] += 1
+    return jnp.concatenate(x, axis=1)
+
+
+def _spans_align(*stacks) -> bool:
+    """Whether the operands' parts cover the same shard spans, part for
+    part (the same shard list staged at one extent size always does)."""
+    spans = {tuple(int(p.shape[1]) for p in _parts(x)) for x in stacks}
+    return len(spans) == 1
+
+
 def _kernel_covers(*stacks) -> bool:
-    """Whether the VMEM kernel tallies these operands: all of them on one
-    TPU, rows a whole number of lanes. Read from the arrays themselves —
-    a mesh-sharded stack, another backend or a host array is the XLA
-    program's."""
-    for x in stacks:
-        if x is None:
-            continue
-        devices = getattr(x, "devices", None)
-        if devices is None or x.shape[-1] % 128:
+    """Whether the VMEM kernel tallies these operands: all of them (every
+    part of them) on one TPU, rows a whole number of lanes. Read from the
+    arrays themselves — a mesh-sharded stack, another backend or a host
+    array is the XLA program's."""
+    for part in (p for x in stacks if x is not None for p in _parts(x)):
+        devices = getattr(part, "devices", None)
+        if devices is None or part.shape[-1] % 128:
             return False
         devices = devices()
         if len(devices) != 1 or next(iter(devices)).platform != "tpu":
@@ -118,7 +163,7 @@ def _kernel_covers(*stacks) -> bool:
     return True
 
 
-def tally_program(planes_list: Sequence[jax.Array], filt=None) -> str:
+def tally_program(planes_list: Sequence, filt=None) -> str:
     """The jitted program that will tally this GroupBy, as the profiler's
     "XLA Modules" line names it (the exec.dispatch span's plan.program)."""
     return KERNEL_PROGRAM if _kernel_covers(*planes_list, filt) else XLA_PROGRAM
@@ -129,11 +174,19 @@ def cross_tally(acc, planes, mid=None, filt=None):  # dispatch-ok: caller holds 
     with `mid`, uint32[G, M, R, S] of acc[g] & mid[m] & planes[r]; `filt`
     uint32[S, W] masks acc. The kernel where it covers the operands, else
     the XLA program over prefixes materialised here (callers keep G x M
-    within `_gmax` for it)."""
-    if _kernel_covers(acc, planes, mid, filt):
+    within `_gmax` for it). acc, planes and mid are each one array or a
+    tuple of parts: the kernel reads parts that line up in place, anything
+    else is assembled first."""
+    stacks = [x for x in (acc, planes, mid) if x is not None]
+    kernel = _kernel_covers(*stacks, filt)
+    if not (kernel and _spans_align(*stacks)):
+        acc, planes, mid = (_assembled(x) for x in (acc, planes, mid))
+    if kernel:
         from pilosa_tpu.ops import pallas_kernels
 
         STATS["kernel_tallies"] += 1
+        if len(_parts(planes)) > 1:
+            STATS["inplace_tallies"] += 1
         return pallas_kernels.cross_counts(acc, planes, mid, filt)
     STATS["xla_tallies"] += 1
     if filt is not None:
@@ -180,22 +233,24 @@ _ONESHOT_READ_BYTES = 64 << 20
 # executor._group_by_stacked wraps the whole cross-tally pipeline in
 # plan.dispatch_mutex() (operands staged before entry)
 def group_by_device(  # dispatch-ok: caller holds dispatch_mutex
-    planes_list: Sequence[jax.Array],
+    planes_list: Sequence,
     row_lists: Sequence[Sequence[int]],
     filt: Optional[jax.Array] = None,
 ) -> Dict[Tuple[int, ...], int]:
     """Tally the full GroupBy cross-product on device.
 
     planes_list[k] is the uint32[R_k, S, W] stack of child k's candidate
-    rows; row_lists[k] the matching row ids; filt an optional uint32[S, W]
-    filter stack (same shard padding). Returns {(row0, row1, ...): count}
+    rows, whole or as the tuple of its per-extent parts; row_lists[k] the
+    matching row ids; filt an optional uint32[S, W] filter stack (same
+    shard padding, always whole). Returns {(row0, row1, ...): count}
     with zero-count groups pruned — the same contract as the per-shard
     groupByIterator walk, summed over all shards."""
     merged: Dict[Tuple[int, ...], int] = {}
-    if not planes_list or any(p.shape[0] == 0 for p in planes_list):
+    rows = [_shape(p)[0] for p in planes_list]
+    if not rows or not all(rows):
         return merged
     depth_n = len(planes_list)
-    s, w = planes_list[0].shape[-2], planes_list[0].shape[-1]
+    _, s, w = _shape(planes_list[0])
     gmax = _gmax(s, w)
     kernel = _kernel_covers(*planes_list, filt)
 
@@ -208,7 +263,6 @@ def group_by_device(  # dispatch-ok: caller holds dispatch_mutex
     # [G, S, W] intermediate even when G is an operand as staged); for the
     # kernel, which forms the last prefix level in VMEM, only the cross of
     # the levels before the last two — nothing up to three levels.
-    rows = [int(p.shape[0]) for p in planes_list]
     g_pre = int(np.prod(rows[:-1], dtype=np.int64))
     read_cells = g_pre * rows[-1] * s * 4
     held = rows[:-1] if not kernel else rows[:-2] if depth_n > 3 else []
@@ -216,6 +270,8 @@ def group_by_device(  # dispatch-ok: caller holds dispatch_mutex
     if g_held <= gmax and read_cells <= _ONESHOT_READ_BYTES:
         return _group_by_oneshot(planes_list, row_lists, filt, kernel)
 
+    # The descent selects rows out of whole stacks.
+    planes_list = [_assembled(p) for p in planes_list]
     # Depth 0: counts for every candidate row of the first child.
     if filt is not None:
         h = _host_sum(cross_tally(filt[None], planes_list[0])[0])
@@ -242,7 +298,7 @@ def group_by_device(  # dispatch-ok: caller holds dispatch_mutex
 
 
 def _group_by_oneshot(  # dispatch-ok: caller holds dispatch_mutex
-    planes_list: Sequence[jax.Array],
+    planes_list: Sequence,
     row_lists: Sequence[Sequence[int]],
     filt: Optional[jax.Array],
     kernel: bool,
@@ -251,9 +307,13 @@ def _group_by_oneshot(  # dispatch-ok: caller holds dispatch_mutex
     Zero-count groups are pruned at merge (same contract as the descent).
     All dispatches are async; only the final np.asarray blocks. With the
     kernel the filter and the last prefix level ride inside the tally, so
-    up to three levels are a single launch."""
+    two or three levels are a single launch over the operands as they
+    are staged, parts included; any other shape runs a program that needs
+    whole stacks before the tally."""
     merged: Dict[Tuple[int, ...], int] = {}
     n = len(planes_list)
+    if not (kernel and n in (2, 3)):
+        planes_list = [_assembled(p) for p in planes_list]
     acc = planes_list[0]
     if filt is not None and (n == 1 or not kernel):
         acc = _select_rows_filtered(acc, np.arange(acc.shape[0]), filt)

@@ -258,12 +258,15 @@ def _stage(
     build_slice(lo, hi) -> host ndarray covering shard positions [lo, hi)
     of the stack. Returns the assembled device array — or, with
     `parts=True`, the TUPLE of per-extent device arrays in shard order
-    with no assembly at all (the plane-streamed kernels reduce across
-    the parts inside their one compiled program; a device-side concat
-    of a ~GB operand would re-copy it on every staging). Every extent
-    ends pinned exactly once — ownership goes to `table` (released
-    after the plan's dispatch) or is released here when no table is
-    given.
+    with no assembly at all: the plane-streamed BSI kernels
+    (exec/bsistream.py) reduce across the parts inside their one compiled
+    program, and the GroupBy cross tally (exec/groupby.py,
+    ops/pallas_kernels.py) launches once per part inside its one program;
+    a device-side concat of a ~GB operand would re-copy it on every
+    staging (2 and 4 GB per GroupBy at 954 shards, ledger PR 31). Every
+    extent ends pinned exactly once — ownership goes to `table`
+    (released after the plan's dispatch) or is released here when no
+    table is given.
 
     `versions` (one entry per shard position) rides INSIDE each extent's
     cache key as that extent's own span slice: a write to one shard

@@ -3,7 +3,12 @@
 `cross_counts` (reference: the GroupBy tally, executor.go:3063) forms a
 G (x M) x R cross of AND + popcount over multi-GB `[rows, S, W]` stacks on
 tiles in VMEM, so every operand row is read from HBM once and only the
-per-shard counts are written. `exec/groupby.py` `cross_tally` selects it
+per-shard counts are written. An operand is one array or the tuple of the
+per-extent arrays `[rows, S_e, W]` a view of many shards is resident as
+(hbm/residency.py): the one jitted entry runs the kernel once per extent,
+each with the body of that extent's own layout, and concatenates the
+counts, so the extents are read where they lie and never written again
+as one stack. `exec/groupby.py` `cross_tally` selects it
 from what its operands are (every stack on one TPU, lane-aligned) and
 otherwise runs XLA's `_counts_cross`, which is also the kernel's reference
 in the tests. XLA ran that cross as a per-row slice-and-copy loop at 0.40 %
@@ -37,13 +42,20 @@ from jax.experimental.pallas import tpu as pltpu
 # operand in the layout the device already keeps it in. The TPU runtime
 # picks that layout from the shape. "Shard-major" (S outermost, a tile =
 # 8 rows x 128 words of one shard) is what a stack of 1, 2, 4 or 8k rows
-# gets when S is not a multiple of 8 (954 shards: every benchmark stack);
-# otherwise the row-major default (a tile = 8 shards x 128 words of one
-# row). One kernel body per layout; an operand in the other layout is
-# still answered exactly, after a relayout copy XLA inserts.
+# gets when S is not a multiple of 8 (the 186-shard tail extent of 954
+# shards, and a whole [8k, 954, W] stack); otherwise the row-major default
+# (a tile = 8 shards x 128 words of one row: a full 256-shard extent). One
+# kernel body per layout, chosen per extent; an operand in the other layout
+# is still answered exactly, after a relayout copy XLA inserts.
 _CROSS_ROWS = (8, 8, 16)  # acc / mid / planes rows per grid step
 _CROSS_PARTS = 32  # partial-count vregs carried through the word loop
-_CROSS_WORDS = {True: 32768, False: 4096}  # words of a row per grid step
+# Words of a row per grid step. The row-major body ends every grid step
+# with a lane reduction per pair, so it wants long steps: 16384 words is
+# what its largest blocks (8 + 8 + 16 rows x 8 shards, double-buffered =
+# 32 MB) leave room for under the VMEM limit (one [8|16, 256, W] extent
+# of Q4, unrolled body: 12.6 ms at 4096, 11.8 at 8192, 11.3 at 16384; my
+# chip runs, PR 32).
+_CROSS_WORDS = {True: 32768, False: 16384}
 _CROSS_VMEM_BYTES = 40 << 20
 _SUBLANES = 8
 _LANES = 128
@@ -86,15 +98,23 @@ def _word_loop(steps: int, body, init):
 def _tally_step(parts, pre, row, acc_ref, filt, mid_ref, ps):
     """parts += popcount(acc[g] (& filt) (& mid[m]) & p) for every (g, m)
     of `pre` and candidate tile p of `ps`, prefix-major; `row(ref, i)`
-    loads row i's tile, each operand row once."""
-    accs = {g: row(acc_ref, g) for g in {g for g, _ in pre}}
+    loads row i's tile, each operand row once (a row index is a Python
+    int or a traced scalar; the same index object is the same row)."""
+
+    def key(i):
+        return i if isinstance(i, int) else id(i)
+
+    def rows(ref, idx):
+        return {k: row(ref, i) for k, i in {key(i): i for i in idx}.items()}
+
+    accs = rows(acc_ref, [g for g, _ in pre])
     if filt is not None:
         accs = {g: a & filt for g, a in accs.items()}
     if mid_ref is None:
-        ts = [accs[g] for g, _ in pre]
+        ts = [accs[key(g)] for g, _ in pre]
     else:
-        mids = {m: row(mid_ref, m) for m in {m for _, m in pre}}
-        ts = [accs[g] & mids[m] for g, m in pre]
+        mids = rows(mid_ref, [m for _, m in pre])
+        ts = [accs[key(g)] & mids[key(m)] for g, m in pre]
     pcs = [
         jax.lax.population_count(jnp.bitwise_and(t, p).astype(jnp.int32))
         for t in ts
@@ -147,23 +167,36 @@ def _cross_kernel_row_major(gt, mt, rt, has_filt, *refs):
     """One grid step on [rows, 8 shards, wt words] blocks: a vreg holds the
     same 128 words of 8 shards of one row, so a pair's partial
     [8 shards, 128 lanes] reduces across lanes to 8 per-shard counts, put
-    in lane `pair` of the resident out block [8, pairs]."""
+    in lane `pair` of the resident out block [8, pairs]. The prefix
+    groups that fit the carried partials are a LOOP over one group's code,
+    not unrolled (64 prefixes x 16 rows are 16 groups x 2 slabs of rows):
+    unrolled, one Q4 program of four extents took 6 s to trace and lower
+    and 22 s inside a server whose heap holds an index; so 1 s and 5 s, for
+    a 4 % slower tally (40.5 -> 42.1 ms; my chip runs, PR 32). Candidate
+    rows keep static indices: with those dynamic too it is 48 ms. A ragged
+    last group tallies its last prefix again and does not write it."""
     acc_ref, filt_ref, mid_ref, planes_ref, out_ref = _cross_refs(
         mt, has_filt, refs
     )
     steps = planes_ref.shape[-1] // _LANES
     m_n = max(mt, 1)
-    prefixes = [(g, m) for g in range(gt) for m in range(m_n)]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (_SUBLANES, _LANES), 1)
-    zero = jnp.zeros((_SUBLANES, _LANES), jnp.int32)
+    n_pre = gt * m_n
     sub_r = min(rt, _SUBLANES)
-    sub_p = max(1, _CROSS_PARTS // sub_r)
-    for p0 in range(0, len(prefixes), sub_p):
-        pre = prefixes[p0 : p0 + sub_p]
+    sub_p = max(1, min(n_pre, _CROSS_PARTS // sub_r))
+    window = sub_p * rt  # lanes of the out block one group writes: <= 64
+
+    def group(i, carry):
+        p0 = i * sub_p
+        ps = [jnp.minimum(p0 + k, n_pre - 1) for k in range(sub_p)]
+        if m_n % sub_p == 0:  # one acc row per group: loaded once
+            g = p0 // m_n
+            pre = [(g, p % m_n) for p in ps]
+        else:
+            pre = [(p // m_n, p % m_n) for p in ps]
         for r0 in range(0, rt, sub_r):
             rows = range(r0, min(r0 + sub_r, rt))
 
-            def body(j, parts, pre=pre, rows=rows):
+            def body(j, parts, rows=rows):
                 sl = _lane_slice(j)
                 return _tally_step(
                     parts, pre, lambda ref, i: ref[i, :, sl], acc_ref,
@@ -171,24 +204,43 @@ def _cross_kernel_row_major(gt, mt, rt, has_filt, *refs):
                     [planes_ref[r, :, sl] for r in rows],
                 )
 
-            parts = _word_loop(steps, body, (zero,) * (len(pre) * len(rows)))
-            upd = {}
-            for k, (g, m) in enumerate(pre):
+            zero = jnp.zeros((_SUBLANES, _LANES), jnp.int32)
+            parts = _word_loop(steps, body, (zero,) * (sub_p * len(rows)))
+            # the group's pairs are lanes [p0 * rt, (p0 + sub_p) * rt) of
+            # the out block: gathered in registers (a store per pair to a
+            # lane chunk found at run time is a chain of dependent
+            # read-modify-writes), then added to the one or two 128-lane
+            # chunks the window touches
+            lane = jax.lax.broadcasted_iota(jnp.int32, zero.shape, 1)
+            first = p0 * rt // _LANES
+            tiles = [zero] * (1 if _LANES % window == 0 else 2)
+            for k in range(sub_p):
                 for n, r in enumerate(rows):
-                    pair = (g * m_n + m) * rt + r
                     col = jnp.sum(
                         parts[k * len(rows) + n], axis=1, keepdims=True
                     )
-                    c = pair // _LANES
-                    upd[c] = jnp.where(
-                        lane == pair % _LANES, col, upd.get(c, zero)
+                    to = jnp.where(
+                        p0 + k < n_pre, p0 * rt % _LANES + k * rt + r, -1
                     )
-            for c, u in upd.items():
-                out_ref[:, c * _LANES : (c + 1) * _LANES] += u
+                    tiles = [
+                        jnp.where(lane == to - c * _LANES, col, t)
+                        for c, t in enumerate(tiles)
+                    ]
+            last = out_ref.shape[-1] // _LANES - 1
+            for c, t in enumerate(tiles):
+                at = jnp.minimum(first + c, last) * _LANES
+                out_ref[:, pl.ds(pl.multiple_of(at, _LANES), _LANES)] += t
+        return carry
+
+    jax.lax.fori_loop(0, pl.cdiv(n_pre, sub_p), group, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("shard_major",))
-def _cross_counts_vmem(acc, planes, mid, filt, shard_major: bool):
+def _cross_counts_extent(acc, planes, mid, filt, shard_major: bool):
+    """One `pallas_call` over one extent of every operand: acc [G, S_e, W],
+    planes [R, S_e, W], mid [M, S_e, W] or None, filt [S_e, W] or None ->
+    uint32[G(, M), R, S_e]. Jitted so that the extents of one shape (three
+    of 954 shards' four) are traced and lowered once inside the entry."""
     g_n, s_n, w = acc.shape
     r_n = planes.shape[0]
     m_n = 1 if mid is None else mid.shape[0]
@@ -265,6 +317,26 @@ def _cross_counts_vmem(acc, planes, mid, filt, shard_major: bool):
     return out if mt else out[:, 0]
 
 
+@functools.partial(jax.jit, static_argnames=("shard_major",))
+def _cross_counts_vmem(acc, planes, mid, filt, shard_major):
+    """The one jitted entry. acc, planes and mid (or None) are TUPLES of
+    per-extent parts that line up span for span along the shard axis,
+    `shard_major` the kernel body of each extent; filt [S, W] is whole and
+    sliced per extent here. One `pallas_call` per extent inside the one
+    program; only the per-shard counts are concatenated."""
+    outs, lo = [], 0
+    for e, (a, p) in enumerate(zip(acc, planes)):
+        hi = lo + a.shape[1]
+        outs.append(
+            _cross_counts_extent(
+                a, p, None if mid is None else mid[e],
+                None if filt is None else filt[lo:hi], shard_major[e],
+            )
+        )
+        lo = hi
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=-1)
+
+
 def _shard_major(x) -> bool:
     """Whether the device keeps stack x [rows, S, W] with the shard axis
     outermost (read from the array; host arrays and tracers: no)."""
@@ -273,8 +345,12 @@ def _shard_major(x) -> bool:
     return order is not None and tuple(order)[0] == 1
 
 
+def _parts(x) -> Optional[tuple]:
+    return x if x is None or isinstance(x, tuple) else (x,)
+
+
 def cross_counts(  # dispatch-ok: wrapper; callers serialize (run_serialized)
-    acc, planes, mid=None, filt=None, shard_major: Optional[bool] = None
+    acc, planes, mid=None, filt=None, shard_major=None
 ) -> jnp.ndarray:
     """GroupBy cross tally in one pass over its rows.
 
@@ -284,8 +360,20 @@ def cross_counts(  # dispatch-ok: wrapper; callers serialize (run_serialized)
     is uint32[G, M, R, S] of popcount(acc[g] & mid[m] & planes[r]). filt
     uint32[S, W] is one more AND on the acc tiles. Each grid step brings
     its tile of every operand row into VMEM once and forms the whole cross
-    there; only the counts are written. `shard_major` names the kernel
-    body; left None it follows the layout of the candidate rows."""
+    there; only the counts are written.
+
+    acc, planes and mid are each one array or the tuple of its resident
+    per-extent parts `[rows, S_e, W]` (hbm/residency.py, `parts=True`),
+    the same spans in each: the parts are read where they are, one launch
+    per extent inside one program, and no `[rows, S, W]` stack is written.
+    `shard_major` names the kernel body — one bool for every extent or one
+    per extent; left None each extent follows the layout of its own
+    candidate rows."""
+    acc, planes, mid = _parts(acc), _parts(planes), _parts(mid)
     if shard_major is None:
-        shard_major = _shard_major(planes)
-    return _cross_counts_vmem(acc, planes, mid, filt, shard_major=shard_major)
+        shard_major = tuple(_shard_major(p) for p in planes)
+    elif isinstance(shard_major, bool):
+        shard_major = (shard_major,) * len(planes)
+    return _cross_counts_vmem(
+        acc, planes, mid, filt, shard_major=tuple(shard_major)
+    )

@@ -857,12 +857,21 @@ class NodeServer:
             PROCESS.total_counter("exec.compile_cache_hits"),
         )
         # which program tallied the GroupBy / filtered-TopN crosses
-        # (exec/groupby.py cross_tally): the VMEM kernel or the XLA loop
+        # (exec/groupby.py cross_tally): the VMEM kernel or the XLA loop;
+        # and whether a view of several extents was read where it lies
+        # (inplace_tallies) or written again as one stack first
+        # (assembled_stacks, one per operand)
         self.stats.gauge(
             "groupby.kernel_tallies", groupby_mod.STATS["kernel_tallies"]
         )
         self.stats.gauge(
             "groupby.xla_tallies", groupby_mod.STATS["xla_tallies"]
+        )
+        self.stats.gauge(
+            "groupby.inplace_tallies", groupby_mod.STATS["inplace_tallies"]
+        )
+        self.stats.gauge(
+            "groupby.assembled_stacks", groupby_mod.STATS["assembled_stacks"]
         )
         # the views' row summaries (core/view.py row_summary): hits over
         # hits + bypassed is the share of Rows / GroupBy-prefetch /

@@ -98,6 +98,12 @@ STAT_NAMES = frozenset(
         # other backend, mesh-sharded stacks)
         "groupby.kernel_tallies",
         "groupby.xla_tallies",
+        # of the kernel's tallies, those that read operands staged as more
+        # than one extent in place; and operands whose extents were
+        # concatenated into one stack for a tally (the XLA program, the
+        # pruned descent, more than three levels)
+        "groupby.inplace_tallies",
+        "groupby.assembled_stacks",
         # per-view row summary (core/view.py row_summary, counted in the
         # process registry and published at scrape time): readers that
         # found the table under the view's current mutation clock, tables

@@ -32,12 +32,18 @@ def test_smoke_parts_agree_with_a_served_index_small():
         device = {"platform": "cpu", "kind": "cpu", "count": 8}
         before = cs.tally_counts(http_)
         cs.run_queries(http_, queries, cold=True, device=device)
-        kernel, xla = (a - b for a, b in zip(cs.tally_counts(http_), before))
-        assert kernel == 0 and xla >= 1
+        kernel, xla, inplace, assembled = (
+            a - b for a, b in zip(cs.tally_counts(http_), before))
+        # 3 shards are one extent (and the mesh stages one array anyway)
+        assert kernel == 0 and xla >= 1 and (inplace, assembled) == (0, 0)
+        one_chip = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
         with pytest.raises(AssertionError, match="cross tallies"):
-            cs.check_tally_program(
-                "group_by", kernel, xla,
-                {"platform": "tpu", "kind": "TPU v5 lite", "count": 1})
+            cs.check_tally_program("group_by", kernel, xla, one_chip)
+        # one chip: the kernel, over the extents as they lie
+        cs.check_tally_program("group_by", 1, 0, one_chip, assembled=0)
+        with pytest.raises(AssertionError, match="concatenated"):
+            cs.check_tally_program("group_by", 1, 0, one_chip, assembled=2)
+        cs.check_tally_program("group_by", 0, 1, device, assembled=2)
         with pytest.raises(AssertionError, match=r"mesh.devices \[8\]"):
             cs.check_placement("count_intersect", [8], dict(device, count=4))
         cs.run_queries(http_, queries, cold=False)

@@ -121,22 +121,88 @@ _CROSS_CASES = {
     "fused_row_tiles_ragged": lambda r: (rand_words(r, 9, 2, 128),
                                          rand_words(r, 17, 2, 128),
                                          rand_words(r, 10, 2, 128), None),
+    # 24 prefixes x 6 rows = 144 pairs in groups of 5 x 6: the row-major
+    # body's fifth group writes lanes 120-149, across two lane chunks, and
+    # its last group is ragged (4 of 5 prefixes)
+    "fused_group_across_lane_chunks": lambda r: (rand_words(r, 8, 3, 128),
+                                                 rand_words(r, 6, 3, 128),
+                                                 rand_words(r, 3, 3, 128),
+                                                 rand_words(r, 3, 128)),
 }
+
+
+# The parts form: every stack operand is handed over as the tuple of its
+# per-extent parts (split along the shard axis at these sizes), as
+# hbm/residency.py stages a view of more than one extent. name: (case to
+# build, part sizes, whether the kernel body alternates from part to part).
+_PARTS_CASES = {
+    "parts_8_8_3": ("shards_19", (8, 8, 3), False),
+    "parts_body_per_part": ("shards_19", (8, 8, 3), True),
+    "parts_fused": ("fused_shards_19", (8, 8, 3), True),
+    "parts_filtered": ("shards_11_filtered", (4, 4, 3), False),
+    "parts_fused_filtered": ("fused_filtered", (3, 3, 3), True),
+    "parts_row_tiles_ragged": ("fused_row_tiles_ragged", (1, 1), True),
+    "parts_one": ("fused_filtered", (9,), False),
+}
+_CROSS_CASES.update({
+    "shards_19": lambda r: (rand_words(r, 3, 19, _CROSS_W),
+                            rand_words(r, 5, 19, _CROSS_W), None, None),
+    "fused_shards_19": lambda r: (rand_words(r, 2, 19, _CROSS_W),
+                                  rand_words(r, 5, 19, _CROSS_W),
+                                  rand_words(r, 3, 19, _CROSS_W), None),
+})
+
+
+def _split(x, sizes):
+    """A stack [rows, S, W] as the tuple of its parts along the shard axis."""
+    if x is None:
+        return None
+    assert x.shape[1] == sum(sizes)
+    return tuple(np.split(x, np.cumsum(sizes)[:-1], axis=1))
 
 
 @pytest.mark.parametrize("shard_major", [True, False],
                          ids=["shard_major", "row_major"])
-@pytest.mark.parametrize("case", sorted(_CROSS_CASES))
+@pytest.mark.parametrize("case", sorted(_CROSS_CASES) + sorted(_PARTS_CASES))
 def test_cross_counts_matches_xla_and_numpy(rng, case, shard_major):
-    acc, planes, mid, filt = _CROSS_CASES[case](rng)
-    got = np.asarray(
-        pk.cross_counts(acc, planes, mid, filt, shard_major=shard_major)
-    )
+    build, sizes, alternate = _PARTS_CASES.get(case, (case, None, False))
+    acc, planes, mid, filt = _CROSS_CASES[build](rng)
+    if sizes is None:
+        got = pk.cross_counts(acc, planes, mid, filt, shard_major=shard_major)
+    else:
+        bodies = tuple(
+            shard_major ^ (alternate and i % 2 == 1) for i in range(len(sizes))
+        )
+        got = pk.cross_counts(
+            _split(acc, sizes), _split(planes, sizes), _split(mid, sizes),
+            filt, shard_major=bodies,
+        )
+        # the parts read in place answer as the one assembled stack does
+        np.testing.assert_array_equal(
+            np.asarray(got),
+            np.asarray(
+                pk.cross_counts(acc, planes, mid, filt, shard_major=shard_major)
+            ),
+        )
+    got = np.asarray(got)
     assert got.dtype == np.uint32
     want = _np_cross(acc, planes, mid, filt)
     assert got.shape == want.shape
     np.testing.assert_array_equal(got, want)
     np.testing.assert_array_equal(got, _xla_cross(acc, planes, mid, filt))
+
+
+def test_one_part_is_the_single_stack_program(rng):
+    """A monolithic stack is a tuple of one: the bare array and the tuple
+    run the same compiled program, so a view of one extent compiles and
+    runs what it did before there were parts."""
+    acc = rand_words(rng, 2, 5, 128)
+    planes = rand_words(rng, 3, 5, 128)
+    whole = np.asarray(pk.cross_counts(acc, planes, shard_major=False))
+    programs = pk._cross_counts_vmem._cache_size()
+    one = pk.cross_counts((acc,), (planes,), shard_major=(False,))
+    assert pk._cross_counts_vmem._cache_size() == programs
+    np.testing.assert_array_equal(np.asarray(one), whole)
 
 
 def test_cross_counts_follows_the_layout_of_its_candidate_rows(rng):
@@ -148,4 +214,10 @@ def test_cross_counts_follows_the_layout_of_its_candidate_rows(rng):
     acc = rand_words(rng, 2, 3, 128)
     np.testing.assert_array_equal(
         np.asarray(pk.cross_counts(acc, planes)), _np_cross(acc, planes)
+    )
+    # parts: one body per part, each from its own part's layout
+    halves = lambda x: (x[:, :2], x[:, 2:])  # noqa: E731
+    np.testing.assert_array_equal(
+        np.asarray(pk.cross_counts(halves(acc), halves(planes))),
+        _np_cross(acc, planes),
     )

@@ -19,7 +19,7 @@ from pilosa_tpu.core.holder import Holder
 from pilosa_tpu.exec import plan as planmod
 from pilosa_tpu.exec.executor import ExecError, Executor
 from pilosa_tpu.parallel import mesh as pmesh
-from pilosa_tpu.shardwidth import SHARD_WIDTH
+from pilosa_tpu.shardwidth import SHARD_WIDTH, WORDS_PER_ROW
 
 
 @pytest.fixture
@@ -475,6 +475,7 @@ class TestStackedGroupBy:
         assert self._as_t(got) == self._as_t(want), query
         assert qgb.STATS == {
             "evals": launches, "kernel_tallies": 1, "xla_tallies": 0,
+            "inplace_tallies": 0, "assembled_stacks": 0,  # one extent
         }
 
     def test_kernel_replaces_the_xla_tally_inside_the_descent(
@@ -501,6 +502,132 @@ class TestStackedGroupBy:
         assert self._as_t(got) == self._as_t(want)
         assert qgb.STATS["xla_tallies"] == 0
         assert qgb.STATS["kernel_tallies"] > 3  # depth 0, then per chunk
+
+    @pytest.fixture
+    def three_extents(self, holder):
+        """5 shards staged as extents of 2 + 2 + 1 on one device, as 954
+        are as 256 + 256 + 256 + 186 on a chip."""
+        from pilosa_tpu.core.devcache import DEVICE_CACHE
+        from pilosa_tpu.core.resultcache import RESULT_CACHE
+        from pilosa_tpu.hbm import residency as hbm_res
+
+        old_rows = hbm_res.extent_rows()
+        DEVICE_CACHE.clear()
+        hbm_res.configure(extent_rows=2)
+        RESULT_CACHE.reset()
+        self._mk_gb(holder, n_shards=5, seed=11)
+        yield Executor(holder)
+        hbm_res.configure(extent_rows=old_rows)
+        DEVICE_CACHE.clear()
+
+    _EXTENT_QUERIES = [
+        "GroupBy(Rows(a), Rows(b))",
+        "GroupBy(Rows(a), Rows(b), Rows(c))",
+        "GroupBy(Rows(a), Rows(b), filter=Row(c=1))",
+    ]
+
+    @pytest.mark.parametrize("query", _EXTENT_QUERIES)
+    def test_kernel_reads_resident_extents_in_place(
+        self, three_extents, monkeypatch, query
+    ):
+        """A view of three extents is tallied where it lies: one kernel
+        tally, in place, and no operand concatenated."""
+        from jax.experimental.pallas import tpu as pltpu
+
+        from pilosa_tpu.core.resultcache import RESULT_CACHE
+        from pilosa_tpu.exec import groupby as qgb
+
+        ex = three_extents
+        want = self._serial(ex, monkeypatch, query)
+        assert want
+        self._engage_kernel(monkeypatch, qgb)
+        RESULT_CACHE.reset()  # the run below must execute
+        qgb.reset_stats()
+        with pltpu.force_tpu_interpret_mode():
+            got = ex.execute("gb", query)[0]
+        assert self._as_t(got) == self._as_t(want), query
+        assert qgb.STATS == {
+            "evals": 1, "kernel_tallies": 1, "xla_tallies": 0,
+            "inplace_tallies": 1, "assembled_stacks": 0,
+        }
+
+    @pytest.mark.parametrize(
+        "path,query",
+        [
+            ("xla", _EXTENT_QUERIES[0]),
+            ("xla", _EXTENT_QUERIES[1]),
+            ("descent", _EXTENT_QUERIES[0]),
+            ("descent", _EXTENT_QUERIES[1]),
+            ("four_levels", _EXTENT_QUERIES[0]),
+        ],
+    )
+    def test_paths_that_need_whole_stacks_assemble_the_extents(
+        self, three_extents, monkeypatch, query, path
+    ):
+        """The XLA program, the pruned descent and a cross deeper than the
+        kernel's three levels concatenate each operand once, and answer
+        as the per-shard walk does."""
+        from jax.experimental.pallas import tpu as pltpu
+
+        from pilosa_tpu.core.resultcache import RESULT_CACHE
+        from pilosa_tpu.exec import groupby as qgb
+
+        ex = three_extents
+        if path == "four_levels":
+            query = query.replace("GroupBy(", "GroupBy(Rows(c), Rows(c), ")
+        fields = query.count("Rows(")
+        want = self._serial(ex, monkeypatch, query)
+        assert want
+        if path != "xla":
+            self._engage_kernel(monkeypatch, qgb, gmax=9)
+        if path == "descent":
+            monkeypatch.setattr(qgb, "_ONESHOT_READ_BYTES", 64)
+        RESULT_CACHE.reset()
+        qgb.reset_stats()
+        with pltpu.force_tpu_interpret_mode():
+            got = ex.execute("gb", query)[0]
+        assert self._as_t(got) == self._as_t(want), query
+        assert qgb.STATS["assembled_stacks"] == fields
+        assert qgb.STATS["inplace_tallies"] == 0
+        assert bool(qgb.STATS["xla_tallies"]) == (path == "xla")
+
+    def test_write_restages_one_extent_under_an_in_place_tally(
+        self, three_extents, monkeypatch
+    ):
+        """The parts are the version-keyed extents themselves: a write to
+        one shard re-keys the extent that covers it, the next GroupBy
+        stages that one again and its answer holds the write."""
+        from jax.experimental.pallas import tpu as pltpu
+
+        from pilosa_tpu.exec import groupby as qgb
+        from pilosa_tpu.hbm import residency as hbm_res
+
+        ex = three_extents
+        query = "GroupBy(Rows(a), Rows(b))"
+        self._engage_kernel(monkeypatch, qgb)
+        with pltpu.force_tpu_interpret_mode():
+            first = self._as_t(ex.execute("gb", query)[0])
+            before = hbm_res.stats_snapshot()
+            # a column of shard 3 (extent 1 of 3) that row a=0 lacks
+            col = 3 * SHARD_WIDTH + 77
+            assert ex.execute("gb", f"Set({col}, a=0)")[0] is True
+            assert ex.execute("gb", f"Set({col}, b=0)")[0] is True
+            qgb.reset_stats()
+            second = self._as_t(ex.execute("gb", query)[0])
+        after = hbm_res.stats_snapshot()
+        key = ((("a", 0), ("b", 0)))
+        assert dict(second)[key] == dict(first).get(key, 0) + 1
+        assert qgb.STATS["inplace_tallies"] == 1
+        assert qgb.STATS["assembled_stacks"] == 0
+        # one extent of each written field went up again (or was patched
+        # on the device), never the other two
+        extent_bytes = lambda rows: rows * 2 * WORDS_PER_ROW * 4  # noqa: E731
+        restaged = after["restage_bytes"] - before["restage_bytes"]
+        patched = after["extent_patches"] - before["extent_patches"]
+        assert restaged <= extent_bytes(6) + extent_bytes(5)
+        assert restaged or patched
+        want = self._serial(ex, monkeypatch, query)
+        assert second == self._as_t(want)
 
     def test_kernel_covers_only_stacks_on_one_tpu(self, holder):
         """The choice of program is read from the operands: host arrays,

@@ -49,20 +49,32 @@ def _stack(sharding, rows, shards=SHARDS):
     return jax.ShapeDtypeStruct(shape, jnp.uint32, sharding=sharding)
 
 
-# name: (G, R, M, filter, shards, kernel body, bytes XLA may copy)
+EXTENTS = (256, 256, 256, 186)  # 954 shards as hbm/residency.py keeps them
+
+# name: (G, R, M, filter, shards of each part, kernel body of each part,
+#        bytes XLA may copy)
 _CASES = {
-    # the benchmark's GroupBys: every stack is 8k rows x 954 shards, which
-    # the device keeps shard-major; the shard-major body reads it in place
-    "taxi_q3": (8, 8, 0, False, SHARDS, True, 0),
-    "taxi_q4": (8, 16, 8, False, SHARDS, True, 0),
-    # a filter [S, W] is re-tiled to one row per shard: its own 125 MB
-    "taxi_q4_filtered": (8, 16, 8, True, SHARDS, True, SHARDS * WORDS * 4),
+    # the benchmark's GroupBys, read where the view's four extents lie: a
+    # [8k, 256, W] extent is row-major (8 divides 256), the [8k, 186, W]
+    # tail shard-major, and each is read in place by the body of its layout
+    "taxi_q3": (8, 8, 0, False, EXTENTS, (False,) * 3 + (True,), 0),
+    "taxi_q4": (8, 16, 8, False, EXTENTS, (False,) * 3 + (True,), 0),
+    # a filter [S, W] is sliced per extent, and the slice of a shard-major
+    # extent re-tiled to one row per shard: at most the filter's own 125 MB
+    "taxi_q4_filtered": (8, 16, 8, True, EXTENTS, (False,) * 3 + (True,),
+                         SHARDS * WORDS * 4),
+    # the same GroupBys over one assembled [8k, 954, W] stack, which the
+    # device keeps shard-major (what every tally read before PR 32)
+    "taxi_q3_assembled": (8, 8, 0, False, (SHARDS,), (True,), 0),
+    "taxi_q4_assembled": (8, 16, 8, False, (SHARDS,), (True,), 0),
+    "taxi_q4_assembled_filtered": (8, 16, 8, True, (SHARDS,), (True,),
+                                   SHARDS * WORDS * 4),
     # the filtered TopN's dense chunk and a descent chunk at 954 shards
-    "topn_chunk": (1, 2, 0, False, SHARDS, True, 0),
-    "descent_chunk": (2, 16, 0, False, SHARDS, True, 0),
+    "topn_chunk": (1, 2, 0, False, (SHARDS,), (True,), 0),
+    "descent_chunk": (2, 16, 0, False, (SHARDS,), (True,), 0),
     # stacks the device keeps row-major: odd row counts, or 8 | S
-    "odd_rows": (3, 5, 6, True, SHARDS, False, 0),
-    "shards_960": (8, 16, 8, False, 960, False, 0),
+    "odd_rows": (3, 5, 6, True, (SHARDS,), (False,), 0),
+    "shards_960": (8, 16, 8, False, (960,), (False,), 0),
 }
 
 
@@ -70,13 +82,18 @@ _CASES = {
 def test_cross_counts_compiles_for_v5e_without_copying_its_stacks(
     one_chip, case
 ):
-    g, r, m, filtered, shards, shard_major, copied = _CASES[case]
+    g, r, m, filtered, parts, bodies, copied = _CASES[case]
+
+    def stack(rows):
+        return tuple(_stack(one_chip, rows, s) for s in parts)
+
     compiled = pk._cross_counts_vmem.lower(
-        _stack(one_chip, g, shards),
-        _stack(one_chip, r, shards),
-        _stack(one_chip, m, shards) if m else None,
-        _stack(one_chip, 0, shards) if filtered else None,
-        shard_major=shard_major,
+        stack(g),
+        stack(r),
+        stack(m) if m else None,
+        _stack(one_chip, 0, sum(parts)) if filtered else None,
+        shard_major=bodies,
     ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= len(parts)
     assert compiled.memory_analysis().temp_size_in_bytes <= copied * 1.01
