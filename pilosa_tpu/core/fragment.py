@@ -507,6 +507,17 @@ class Fragment:
             rb = self._rows.get(row_id)
             return rb.to_words() if rb is not None else ob.empty_row()
 
+    def fill_row_words(self, row_id: int, out: np.ndarray) -> None:
+        """`row_words` into the caller's uint32[W] row (zeros if absent):
+        what a stack build calls once per shard of its staging buffer."""
+        with self._mu:
+            self._sync_locked()
+            rb = self._rows.get(row_id)
+            if rb is not None:
+                rb.write_words(out)
+            else:
+                out.fill(0)
+
     def row_positions(self, row_id: int) -> np.ndarray:
         with self._mu:
             self._sync_locked()

@@ -73,10 +73,14 @@ class RowBits:
 
     def _to_dense(self) -> np.ndarray:
         words = np.zeros(self.n_words, dtype=np.uint32)
+        self._scatter_positions(words)
+        return words
+
+    def _scatter_positions(self, words: np.ndarray) -> None:
+        """OR the sparse positions into `words` (zeroed by the caller)."""
         if len(self.positions):
             p = self.positions
             np.bitwise_or.at(words, p >> 5, np.uint32(1) << (p & np.uint32(31)))
-        return words
 
     # -- reads -------------------------------------------------------------
 
@@ -99,6 +103,16 @@ class RowBits:
             w.flags.writeable = False
             return w
         return self._to_dense()
+
+    def write_words(self, out: np.ndarray) -> None:
+        """The dense word vector written into the caller's uint32[n_words]
+        row (`to_words` without a buffer of its own): a stack build fills
+        one staging buffer instead of allocating a row per fragment."""
+        if self.dense is not None:
+            out[:] = self.dense
+            return
+        out.fill(0)
+        self._scatter_positions(out)
 
     def to_positions(self) -> np.ndarray:
         if self.dense is not None:

@@ -646,13 +646,7 @@ class View:
         key = self._stack_key("row", row_id, shards)
 
         def build_slice(lo: int, hi: int):
-            rows = [
-                f.row_words(row_id)
-                if f is not None
-                else np.zeros(WORDS_PER_ROW, np.uint32)
-                for f in frags[lo:hi]
-            ]
-            return np.stack(rows)
+            return hbm_res.build_row_slice(frags[lo:hi], row_id)
 
         return hbm_res.stage_row_stack(
             key, len(shards), build_slice, table=extents,
@@ -733,21 +727,7 @@ class View:
         key = self._stack_key("planes", row_ids, shards)
 
         def build_slice(lo: int, hi: int):
-            part = frags[lo:hi]
-            if not row_ids:  # bit_depth 0: empty plane axis
-                return np.zeros((0, len(part), WORDS_PER_ROW), np.uint32)
-            zeros = np.zeros(WORDS_PER_ROW, np.uint32)
-            return np.stack(
-                [
-                    np.stack(
-                        [
-                            f.row_words(r) if f is not None else zeros
-                            for f in part
-                        ]
-                    )
-                    for r in row_ids
-                ]
-            )
+            return hbm_res.build_plane_slice(frags[lo:hi], row_ids)
 
         return hbm_res.stage_plane_stack(
             key, len(shards), build_slice, table=extents,

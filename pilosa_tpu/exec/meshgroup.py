@@ -36,12 +36,9 @@ from __future__ import annotations
 from collections import OrderedDict
 from typing import Callable, Dict, List, Optional, Tuple
 
-import numpy as np
-
 from pilosa_tpu.core.devcache import DEVICE_CACHE, new_owner_token
 from pilosa_tpu.parallel import mesh as pmesh
 from pilosa_tpu.pql.ast import Call
-from pilosa_tpu.shardwidth import WORDS_PER_ROW
 from pilosa_tpu.utils.locks import TrackedLock
 
 
@@ -255,13 +252,7 @@ class GroupView:
         key = self._base_key("row", row_id, shards)
 
         def build_slice(lo: int, hi: int):
-            zeros = np.zeros(WORDS_PER_ROW, np.uint32)
-            return np.stack(
-                [
-                    f.row_words(row_id) if f is not None else zeros
-                    for f in frags[lo:hi]
-                ]
-            )
+            return hbm_res.build_row_slice(frags[lo:hi], row_id)
 
         return hbm_res.stage_row_stack(
             key, len(shards), build_slice, table=extents,
@@ -285,21 +276,7 @@ class GroupView:
         key = self._base_key("planes", row_ids, shards)
 
         def build_slice(lo: int, hi: int):
-            part = frags[lo:hi]
-            if not row_ids:
-                return np.zeros((0, len(part), WORDS_PER_ROW), np.uint32)
-            zeros = np.zeros(WORDS_PER_ROW, np.uint32)
-            return np.stack(
-                [
-                    np.stack(
-                        [
-                            f.row_words(r) if f is not None else zeros
-                            for f in part
-                        ]
-                    )
-                    for r in row_ids
-                ]
-            )
+            return hbm_res.build_plane_slice(frags[lo:hi], row_ids)
 
         return hbm_res.stage_plane_stack(
             key, len(shards), build_slice, table=extents,

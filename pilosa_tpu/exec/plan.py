@@ -404,15 +404,21 @@ def _flush_stage_span() -> None:
     staged outside one, just before the dispatch that consumes the
     operands. Always drains the accumulator — staging by an unsampled
     query must not leak into the next sampled one on the same thread."""
-    nbytes, seconds, hits = tracing.take_stage_account()
+    acc = tracing.take_stage_account()
     if tracing.active_span() is None:
         return
-    if nbytes == 0 and seconds < 1e-6 and hits == 0:
+    if acc.nbytes == 0 and acc.seconds < 1e-6 and acc.hits == 0:
         return
     tracing.record_span(
         "exec.stage",
-        seconds,
-        tags={"stage.bytes": nbytes, "stage.prefetch_hits": hits},
+        acc.seconds,
+        tags={
+            "stage.bytes": acc.nbytes,
+            "stage.rows": acc.rows,
+            "stage.build_ms": round(acc.build_seconds * 1000.0, 3),
+            "stage.put_ms": round(acc.put_seconds * 1000.0, 3),
+            "stage.prefetch_hits": acc.hits,
+        },
     )
 
 

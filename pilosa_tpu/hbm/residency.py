@@ -30,10 +30,15 @@ from __future__ import annotations
 
 import os
 import threading
+import time
+import weakref
 from contextlib import contextmanager
 from typing import Callable, Dict, Iterator, List, Optional, Set, Tuple
 
+import numpy as np
+
 from pilosa_tpu.core.devcache import DEVICE_CACHE
+from pilosa_tpu.shardwidth import WORDS_PER_ROW
 from pilosa_tpu.utils import tracing
 from pilosa_tpu.utils.locks import TrackedLock
 
@@ -205,6 +210,137 @@ class ExtentTable:
 
 
 # ---------------------------------------------------------------------------
+# host staging buffers
+# ---------------------------------------------------------------------------
+
+# idle buffers kept for the next build, in bytes: two of a four-chip host's
+# 78 MB row stacks fit, a taxi plane extent (19 x 256 rows = 637 MB) does
+# not and is allocated per build
+_POOL_KEEP_BYTES = 256 << 20
+
+
+class StagingPool:
+    """The host buffers a miss builds its row or plane stack into, by
+    shape. A build used to allocate one 128 KiB row per fragment and
+    `np.stack` them into a fresh stack: 150 allocations of glibc's mmap
+    threshold and up, every page faulted in and handed back once per
+    miss (PERF.md section 6, PR 34). `take` hands out a buffer, `give`
+    takes it back together with the device array that was put from it;
+    the buffer is lent again only once that array is ready
+    (`jax.device_put` returns before the copy has read the host memory)
+    and never when the array aliases it (the CPU backend adopts an
+    aligned host buffer instead of copying). A buffer that is never
+    given back is simply garbage; `give` ignores what `take` did not
+    hand out."""
+
+    def __init__(self, keep_bytes: int = _POOL_KEEP_BYTES) -> None:
+        self.keep_bytes = keep_bytes
+        self._mu = TrackedLock("hbm.staging_mu")
+        self._lent = weakref.WeakValueDictionary()  # id(buf) -> buf
+        self._free: Dict[Tuple[int, ...], List[np.ndarray]] = {}
+        self._free_bytes = 0
+        # given back, the upload may still be reading: (buf, weakref(arr))
+        self._in_flight: List[Tuple[np.ndarray, object]] = []
+        self.reused = 0  # takes served by a pooled buffer
+
+    def take(self, shape: Tuple[int, ...]) -> np.ndarray:
+        """An uninitialised uint32 buffer of `shape`; the caller writes
+        every word."""
+        shape = tuple(int(n) for n in shape)
+        if 0 in shape:
+            return np.empty(shape, np.uint32)  # nothing to pool
+        with self._mu:
+            self._reap_locked()
+            bufs = self._free.get(shape)
+            if bufs:
+                buf = bufs.pop()
+                self._free_bytes -= buf.nbytes
+                self.reused += 1
+            else:
+                buf = np.empty(shape, np.uint32)
+            self._lent[id(buf)] = buf
+        return buf
+
+    def give(self, buf, arr=None) -> None:
+        """`buf` back after `arr = device_put(buf)` (or with no upload
+        at all: `arr` None makes it free at once)."""
+        with self._mu:
+            if self._lent.pop(id(buf), None) is not buf:
+                return  # the caller's own array
+            self._in_flight.append(
+                (buf, None if arr is None else weakref.ref(arr))
+            )
+            self._reap_locked()
+
+    def _reap_locked(self) -> None:  # guarded-by: _mu
+        waiting = []
+        for buf, ref in self._in_flight:
+            if ref is not None:
+                arr = ref()
+                if arr is None or arr.is_deleted():
+                    continue  # the upload's end cannot be seen: drop it
+                if not arr.is_ready():
+                    waiting.append((buf, ref))
+                    continue
+                if _aliases(buf, arr):
+                    continue  # the array owns this memory now
+            if self._free_bytes + buf.nbytes <= self.keep_bytes:
+                self._free.setdefault(buf.shape, []).append(buf)
+                self._free_bytes += buf.nbytes
+        self._in_flight = waiting
+
+    def clear(self) -> None:
+        with self._mu:
+            self._lent.clear()
+            self._free.clear()
+            self._free_bytes = 0
+            self._in_flight = []
+            self.reused = 0
+
+
+def _aliases(buf: np.ndarray, arr) -> bool:
+    """Whether a device array's memory lies inside `buf` (a zero-copy
+    `device_put` on the CPU backend); an accelerator's never does."""
+    lo = buf.ctypes.data
+    for shard in arr.addressable_shards:
+        if shard.device.platform != "cpu":
+            return False
+        if lo <= shard.data.unsafe_buffer_pointer() < lo + buf.nbytes:
+            return True
+    return False
+
+
+STAGING = StagingPool()
+
+
+def _fill_rows(out: np.ndarray, frags, row_id: int) -> None:
+    for row, frag in zip(out, frags):
+        if frag is not None:
+            frag.fill_row_words(row_id, row)
+        else:
+            row.fill(0)
+
+
+def build_row_slice(frags, row_id: int) -> np.ndarray:
+    """uint32[len(frags), W] host stack of one row over the fragments
+    `frags` (None = no fragment: zeros), built into a staging buffer.
+    With `build_plane_slice` THE build of a stack's slice, for `View`
+    and the mesh group's `GroupView`, on one device and on a mesh."""
+    out = STAGING.take((len(frags), WORDS_PER_ROW))
+    _fill_rows(out, frags, row_id)
+    return out
+
+
+def build_plane_slice(frags, row_ids) -> np.ndarray:
+    """uint32[D, len(frags), W]: the rows `row_ids` (BSI planes) over
+    `frags`, built into one staging buffer."""
+    out = STAGING.take((len(row_ids), len(frags), WORDS_PER_ROW))
+    for plane, row_id in zip(out, row_ids):
+        _fill_rows(plane, frags, row_id)
+    return out
+
+
+# ---------------------------------------------------------------------------
 # staging
 # ---------------------------------------------------------------------------
 
@@ -274,8 +410,6 @@ def _stage(
     its dirty slices after a write burst. `shards` (the shard ids by
     position) is registered with the device cache as each entry's
     coverage, which is what invalidate_owner_shard matches against."""
-    import time
-
     t_stage0 = time.perf_counter()
     try:
         return _stage_inner(
@@ -288,6 +422,32 @@ def _stage(
         # query's milliseconds)
         if not _in_prefetch():
             tracing.note_stage(seconds=time.perf_counter() - t_stage0)
+
+
+def _build_and_put(
+    build_slice: Callable[[int, int], object], lo: int, hi: int
+) -> object:
+    """One miss: the host stack of shard positions [lo, hi) built
+    (`build_slice`, into a staging buffer when it is `View`'s) and handed
+    to the device with the active mesh's placement. The buffer goes back
+    to the pool with the array that reads it. The two halves feed the
+    query thread's staging account apart (`stage.build_ms`,
+    `stage.put_ms`; the put returns before the copy is done, the
+    dispatch waits for that)."""
+    from pilosa_tpu.parallel import mesh as pmesh
+
+    t0 = time.perf_counter()
+    host = build_slice(lo, hi)
+    t1 = time.perf_counter()
+    arr = pmesh.put_stack(host)
+    STAGING.give(host, arr)
+    if not _in_prefetch():
+        tracing.note_stage(
+            build_seconds=t1 - t0,
+            put_seconds=time.perf_counter() - t1,
+            rows=np.size(host) // WORDS_PER_ROW,
+        )
+    return arr
 
 
 def _stage_inner(
@@ -315,8 +475,7 @@ def _stage_inner(
 
         def build_all() -> object:
             built.append(True)
-            arr = pmesh.put_stack(build_slice(0, n_shards))
-            return arr
+            return _build_and_put(build_slice, 0, n_shards)
 
         arr = DEVICE_CACHE.get_or_build(
             key, build_all, extent=True, pin=True, shards=shards,
@@ -379,7 +538,7 @@ def _stage_inner(
                     built: List[bool] = freshly_built,
                 ) -> object:
                     built.append(True)
-                    return jax.device_put(build_slice(lo, hi))
+                    return _build_and_put(build_slice, lo, hi)
 
                 arr = DEVICE_CACHE.get_or_build(
                     key, build, extent=True, pin=True,

@@ -102,9 +102,11 @@ SPAN_NAMES = frozenset(
         # cross-request count batching rounds (exec/batcher.py):
         # leader-executed merges and ride-along waits
         "exec.batch",
-        # operand staging through the HBM residency layer: host->device
-        # upload bytes/ms and prefetch credit (exec/plan.py flushes the
-        # per-thread accumulator fed by hbm/residency.py + core/devcache.py)
+        # operand staging through the HBM residency layer (exec/plan.py
+        # flushes the per-thread accumulator fed by hbm/residency.py +
+        # core/devcache.py); tags: stage.bytes / stage.rows uploaded,
+        # stage.build_ms (row stacks built on the host) and stage.put_ms
+        # (handed to the device) within the span, stage.prefetch_hits
         "exec.stage",
         # one compiled dispatch under plan._DISPATCH_MU: lock wait vs
         # device eval vs blocking host read (exec/plan.py); tags:
@@ -514,28 +516,48 @@ def ingest_spans(span_dicts: List[dict]) -> int:
 _stage_tls = threading.local()
 
 
+class StageAccount:
+    """One thread's staging since the last take: host->device upload
+    `nbytes` and `rows` (row-planes of W words), wall `seconds` of the
+    whole staging (residency lookups included), of which `build_seconds`
+    went into building row stacks on the host and `put_seconds` into
+    handing them to the device, and `hits`, extents the prefetcher had
+    warmed."""
+
+    __slots__ = ("nbytes", "seconds", "hits", "build_seconds",
+                 "put_seconds", "rows")
+
+    def __init__(self) -> None:
+        self.nbytes = 0
+        self.seconds = 0.0
+        self.hits = 0
+        self.build_seconds = 0.0
+        self.put_seconds = 0.0
+        self.rows = 0
+
+
 def note_stage(nbytes: int = 0, seconds: float = 0.0,
-               prefetch_hits: int = 0) -> None:
-    """Accumulate staging work done on this thread: host->device upload
-    bytes, wall seconds spent staging, and extents credited to the
-    prefetcher. Cheap (three adds); flushed by take_stage_account."""
-    _stage_tls.nbytes = getattr(_stage_tls, "nbytes", 0) + int(nbytes)
-    _stage_tls.seconds = getattr(_stage_tls, "seconds", 0.0) + float(seconds)
-    _stage_tls.hits = getattr(_stage_tls, "hits", 0) + int(prefetch_hits)
+               prefetch_hits: int = 0, build_seconds: float = 0.0,
+               put_seconds: float = 0.0, rows: int = 0) -> None:
+    """Accumulate staging work done on this thread (the fields of
+    StageAccount). Cheap (six adds); flushed by take_stage_account."""
+    acc = getattr(_stage_tls, "account", None)
+    if acc is None:
+        acc = _stage_tls.account = StageAccount()
+    acc.nbytes += int(nbytes)
+    acc.seconds += float(seconds)
+    acc.hits += int(prefetch_hits)
+    acc.build_seconds += float(build_seconds)
+    acc.put_seconds += float(put_seconds)
+    acc.rows += int(rows)
 
 
-def take_stage_account():
-    """(bytes, seconds, prefetch_hits) accumulated on this thread since
-    the last take; resets the accumulator."""
-    out = (
-        getattr(_stage_tls, "nbytes", 0),
-        getattr(_stage_tls, "seconds", 0.0),
-        getattr(_stage_tls, "hits", 0),
-    )
-    _stage_tls.nbytes = 0
-    _stage_tls.seconds = 0.0
-    _stage_tls.hits = 0
-    return out
+def take_stage_account() -> StageAccount:
+    """What this thread accumulated since the last take; resets the
+    accumulator."""
+    acc = getattr(_stage_tls, "account", None)
+    _stage_tls.account = None
+    return acc if acc is not None else StageAccount()
 
 
 # ---------------------------------------------------------------------------
