@@ -131,6 +131,10 @@ class DeviceCache:
         self._entries: "OrderedDict[Tuple, object]" = OrderedDict()
         self._sizes: Dict[Tuple, int] = {}
         self._by_owner: Dict[Hashable, Set[Tuple]] = {}
+        # live (lookup-visible) bytes per owner: moves wherever _by_owner
+        # moves, so owner_resident_bytes — read by every admission
+        # (sched/cost.py) — never walks, or re-hashes, the owner's keys
+        self._owner_bytes: Dict[Hashable, int] = {}
         self._bytes = 0
         # pin refcounts + first-pin time (for the stale-pin safety valve)
         self._pins: Dict[Tuple, int] = {}
@@ -230,6 +234,7 @@ class DeviceCache:
         self._entries[key] = arr
         self._sizes[key] = nb
         self._by_owner.setdefault(key[0], set()).add(key)
+        self._owner_bytes[key[0]] = self._owner_bytes.get(key[0], 0) + nb
         if extent:
             self._extent_keys.add(key)
         if shards is not None:
@@ -369,6 +374,7 @@ class DeviceCache:
             self._entries.clear()
             self._sizes.clear()
             self._by_owner.clear()
+            self._owner_bytes.clear()
             self._extent_keys.clear()
             self._cover.clear()
             self._key_index.clear()
@@ -486,11 +492,15 @@ class DeviceCache:
                 self._key_index.pop(key, None)
         self._extent_keys.discard(key)
         self._cover.pop(key, None)
-        owner_keys = self._by_owner.get(key[0])
+        owner = key[0]
+        owner_keys = self._by_owner.get(owner)
         if owner_keys is not None:
             owner_keys.discard(key)
-            if not owner_keys:
-                del self._by_owner[key[0]]
+            if owner_keys:
+                self._owner_bytes[owner] -= nb
+            else:
+                del self._by_owner[owner]
+                del self._owner_bytes[owner]
 
     def _evict_locked(self, keep: Optional[Tuple]) -> None:
         if self._defer_evict > 0:
@@ -642,12 +652,11 @@ class DeviceCache:
     def owner_resident_bytes(self, owner: Hashable) -> int:
         """Resident bytes cached under one owner token (the admission
         cost estimator discounts queries whose operands are already on
-        device, sched/cost.py)."""
+        device, sched/cost.py): the owner's live entries, pinned ones
+        included, zombies not. A running total, one dict read whatever
+        the owner holds."""
         with self._mu:
-            keys = self._by_owner.get(owner)
-            if not keys:
-                return 0
-            return sum(self._sizes.get(k, 0) for k in keys)
+            return self._owner_bytes.get(owner, 0)
 
     def __len__(self) -> int:
         return len(self._entries)

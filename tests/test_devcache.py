@@ -235,3 +235,165 @@ class TestFragmentUnderBudget:
 
         assert set(unpack_positions(arr).tolist()) == {100, 200}
         assert before != 0
+
+
+def _owner_sums(c: DeviceCache, owner) -> int:
+    """What owner_resident_bytes computed before it kept a running
+    total: the sizes of the owner's live keys, summed. The oracle."""
+    return sum(c._sizes[k] for k in c._by_owner.get(owner, ()))
+
+
+class _CountingKey(tuple):
+    """A cache key that counts how often it is hashed (CPython keeps no
+    hash in a tuple: a real key re-hashes its 2 x S shard ints)."""
+
+    hashes = 0
+
+    def __hash__(self):
+        _CountingKey.hashes += 1
+        return tuple.__hash__(self)
+
+
+def _put_counting_stacks(c: DeviceCache, owner, n: int = 512) -> None:
+    """n entries under `owner`, keyed as hbm/residency.py keys a
+    monolithic row stack of 149 shards (shards and versions per shard)."""
+    shards = tuple(range(149))
+    for r in range(n):
+        c.put(
+            _CountingKey((owner, "row", r, shards, 0, "mono", shards)),
+            np.zeros(8, np.uint32),
+        )
+
+
+class TestOwnerResidentBytes:
+    """owner_resident_bytes is a running total per owner; every admission
+    reads it (sched/cost.py resident_bytes)."""
+
+    @pytest.mark.parametrize("seed", range(8))
+    def test_running_total_equals_sum_over_owner_keys(self, seed):
+        rng = np.random.default_rng(seed)
+        c = DeviceCache(budget_bytes=2000)
+        owners = [new_owner_token() for _ in range(3)]
+        pinned = []  # one entry per pin taken and not yet released
+
+        def key():
+            return (owners[rng.integers(3)], "row", int(rng.integers(12)))
+
+        def arr():
+            return np.zeros(int(rng.integers(1, 120)), np.uint32)
+
+        def insert():
+            k = key()
+            cover = None if rng.integers(3) == 0 else [int(rng.integers(4))]
+            c.put(k, arr(), extent=bool(rng.integers(2)), shards=cover)
+
+        def build_pinned():
+            k = key()
+            c.get_or_build(k, arr, pin=True, shards=[int(rng.integers(4))])
+            pinned.append(k)
+
+        def replace():
+            live = list(c._entries)
+            if live:
+                c.put(live[rng.integers(len(live))], arr())
+
+        def pin():
+            k = key()
+            if c.pin_if_present(k):
+                pinned.append(k)
+
+        def unpin():
+            if pinned:
+                c.unpin(pinned.pop(rng.integers(len(pinned))))
+
+        def drop_pinned():  # leaves a zombie until the last unpin
+            if pinned:
+                c.invalidate(pinned[rng.integers(len(pinned))])
+
+        def deferred_burst():  # over budget inside, evicts on exit
+            with c.deferred_eviction():
+                for _ in range(6):
+                    insert()
+                    check()
+
+        def clear():
+            c.clear()
+            pinned.clear()
+
+        ops = [
+            (insert, 8),
+            (build_pinned, 2),
+            (replace, 3),
+            (pin, 3),
+            (unpin, 4),
+            (drop_pinned, 2),
+            (lambda: c.invalidate(key()), 2),
+            (lambda: c.invalidate_many([key(), key()]), 1),
+            (lambda: c.invalidate_owner(owners[rng.integers(3)]), 1),
+            (lambda: c.invalidate_owners(owners[:2]), 1),
+            (
+                lambda: c.invalidate_owner_shard(
+                    owners[rng.integers(3)], int(rng.integers(4))
+                ),
+                2,
+            ),
+            (
+                lambda: c.invalidate_owner_uncovered(owners[rng.integers(3)]),
+                2,
+            ),
+            (deferred_burst, 1),
+            (clear, 1),
+        ]
+        weights = np.array([w for _, w in ops], float)
+        weights /= weights.sum()
+
+        def check():
+            assert set(c._owner_bytes) == set(c._by_owner)
+            for o in owners:
+                assert c.owner_resident_bytes(o) == _owner_sums(c, o)
+
+        zombies_seen = 0
+        for _ in range(600):
+            ops[rng.choice(len(ops), p=weights)][0]()
+            zombies_seen += bool(c._zombies)
+            check()
+        # the walk reached the states the total has to survive
+        assert c.evictions
+        assert zombies_seen
+        c.clear()
+        check()
+        assert c._owner_bytes == {}
+
+    def test_reading_the_total_hashes_no_entry_key(self):
+        c = DeviceCache(budget_bytes=1 << 30)
+        t = new_owner_token()
+        _put_counting_stacks(c, t)
+        before = _CountingKey.hashes
+        assert c.owner_resident_bytes(t) == 512 * 32
+        assert _CountingKey.hashes == before
+
+    def test_estimate_hashes_no_entry_key(self):
+        """512 stacks resident under the view a Count names: pricing the
+        query for admission reads one total, not 512 keys."""
+        from pilosa_tpu.core.field import FieldOptions
+        from pilosa_tpu.core.holder import Holder
+        from pilosa_tpu.pql import parse
+        from pilosa_tpu.sched.cost import estimate
+
+        h = Holder().open()
+        idx = h.create_index("ownerbytes")
+        f = idx.create_field("f", FieldOptions())
+        f.set_bit(1, 7)
+        (view,) = f.views.values()
+        t = view._stack_token
+        try:
+            _put_counting_stacks(DEVICE_CACHE, t)
+            q = parse("Count(Intersect(Row(f=1), Row(f=2)))")
+            before = _CountingKey.hashes
+            cost = estimate(idx, q, shards=[0])
+            assert _CountingKey.hashes == before
+            # two row stacks of one shard, less what the view holds
+            assert cost.device_bytes == 2 * WORDS_PER_ROW * 4 - 512 * 32
+        finally:
+            DEVICE_CACHE.invalidate_owner(t)
+            h.close()

@@ -16,11 +16,18 @@ import urllib.request
 
 import pytest
 
+from pilosa_tpu.core.devcache import DEVICE_CACHE
+from pilosa_tpu.core.field import FieldOptions
+from pilosa_tpu.core.holder import Holder
+from pilosa_tpu.core.resultcache import RESULT_CACHE
+from pilosa_tpu.exec import Executor
 from pilosa_tpu.exec import batcher as batchmod
 from pilosa_tpu.exec.batcher import CountBatcher
+from pilosa_tpu.parallel import mesh as pmesh
 from pilosa_tpu.pql import parse
 from pilosa_tpu.sched.admission import AdmissionController, ShedError
 from pilosa_tpu.sched.cost import QueryCost, estimate
+from pilosa_tpu.shardwidth import SHARD_WIDTH, WORDS_PER_ROW
 from pilosa_tpu.testing import ClusterHarness
 from pilosa_tpu.utils.stats import StatsClient
 
@@ -49,6 +56,39 @@ def _wait_until(pred, timeout=5.0, what="condition"):
 # cost estimation
 # ---------------------------------------------------------------------------
 
+_STACK = WORDS_PER_ROW * 4  # one row stack of one shard, bytes
+
+
+@pytest.fixture
+def two_warm_fields():
+    """An index on one device whose field f has one row stack resident
+    and whose field g has two, over four shards; the result cache off so
+    the estimate reaches the residency discount."""
+    old_mesh = pmesh.active_mesh()
+    pmesh.set_active_mesh(None)
+    old_budget = DEVICE_CACHE.budget_bytes
+    old_result_budget = RESULT_CACHE.budget_bytes
+    DEVICE_CACHE.clear()
+    DEVICE_CACHE.budget_bytes = 1 << 30
+    RESULT_CACHE.configure(budget_bytes=0)
+    h = Holder().open()
+    idx = h.create_index("twofields")
+    shards = [0, 1, 2, 3]
+    for name in ("f", "g"):
+        fld = idx.create_field(name, FieldOptions())
+        for row in range(3):
+            for s in shards:
+                fld.set_bit(row, s * SHARD_WIDTH + row)
+    ex = Executor(h)
+    ex.execute("twofields", "Count(Row(f=0))")
+    ex.execute("twofields", "Count(Intersect(Row(g=0), Row(g=1)))")
+    yield idx, shards
+    h.close()
+    DEVICE_CACHE.clear()
+    DEVICE_CACHE.budget_bytes = old_budget
+    RESULT_CACHE.configure(budget_bytes=old_result_budget)
+    pmesh.set_active_mesh(old_mesh)
+
 
 class TestCost:
     def test_bsi_heavier_than_plain_row(self):
@@ -70,6 +110,51 @@ class TestCost:
     def test_raw_text_and_garbage_never_raise(self):
         assert estimate(None, "Count(Row(f=1))").sweeps >= 1
         assert estimate(None, "This(Is(Not PQL").device_bytes == 0
+
+    @pytest.mark.parametrize(
+        "pql, shards, want",
+        [
+            ("Count(Row(f=1))", [0], QueryCost(_STACK, 1)),
+            ("Count(Row(f=1))", [0, 1, 2, 3], QueryCost(4 * _STACK, 1)),
+            ("Count(Intersect(Row(f=1), Row(f=2)))", [0], QueryCost(2 * _STACK, 1)),
+            ("TopN(f, n=3)", [0, 1], QueryCost(32 * _STACK, 1)),
+            ("Set(1, f=1)", [0], QueryCost(0, 0, write=True)),
+            (
+                "Set(1, f=1)Count(Not(Row(f=1)))",
+                [0],
+                QueryCost(2 * _STACK, 1, write=True),
+            ),
+        ],
+    )
+    def test_exact_cost_without_an_index(self, pql, shards, want):
+        assert estimate(None, parse(pql), shards=shards) == want
+
+    def test_discount_is_the_touched_fields_resident_bytes(self, two_warm_fields):
+        """Two fields resident: a query is discounted by exactly what the
+        cache holds for the views of the field it names (summed here key
+        by key, as the estimate itself did before the cache kept a total
+        per owner), and by nothing of the other field's."""
+        idx, shards = two_warm_fields
+
+        def held(field):
+            return sum(
+                DEVICE_CACHE._sizes[k]
+                for v in idx.field(field).views.values()
+                for k in DEVICE_CACHE._by_owner.get(v._stack_token, ())
+            )
+
+        stack = len(shards) * _STACK
+        assert held("f") == stack and held("g") == 2 * stack
+        q3 = "Count(Union(Row({0}=0), Row({0}=1), Row({0}=2)))"
+        for field in ("f", "g"):
+            got = estimate(idx, parse(q3.format(field)), shards)
+            assert got == QueryCost(3 * stack - held(field), 1)
+        q4 = "Count(Union(Row(f=0), Row(f=1), Row(f=2), Row(g=0)))"
+        both = estimate(idx, parse(q4), shards)
+        assert both == QueryCost(4 * stack - held("f") - held("g"), 1)
+        # a field with nothing resident keeps its whole weight
+        idx.create_field("cold", FieldOptions()).set_bit(1, 7)
+        assert estimate(idx, parse("Count(Row(cold=1))"), shards) == QueryCost(stack, 1)
 
 
 # ---------------------------------------------------------------------------
