@@ -32,7 +32,6 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
 
 import run as harness  # noqa: E402
-from lib import pql  # noqa: E402
 from lib.data import Data  # noqa: E402
 from lib.reference import Reference  # noqa: E402
 from lib.traffic import Mix  # noqa: E402
@@ -44,21 +43,8 @@ def all_but_last_shard(data: Data) -> np.ndarray:
     return keep
 
 
-def served_form(text: str, answer):
-    """A reference answer as the server's JSON would carry it."""
-    call = pql.parse(text)
-    if call.name == "GroupBy":
-        fields = [r.children[0] for r in call.children]
-        return [
-            {"group": [{"field": f, "rowID": r} for f, r in zip(fields, key)],
-             "count": n}
-            for key, n in answer.items()
-        ]
-    return answer
-
-
 def control_run(cell, seed: int, n_requests: int, shard_width: int) -> dict:
-    data = Data(cell.config, seed, shard_width)
+    data = Data(cell.config, seed, shard_width, cell.dialect)
     ref = Reference(data)
     approx = Reference(data, visible=all_but_last_shard(data))
     mix = Mix(cell.mix, data.n_rows, seed)
@@ -68,10 +54,10 @@ def control_run(cell, seed: int, n_requests: int, shard_width: int) -> dict:
         for _ in range(n_requests // mix.clients):
             template, text = next(stream)
             raw = json.dumps(
-                {"results": [served_form(text, approx.answer(text))]}
+                {"results": [approx.served_form(text, approx.answer(text))]}
             ).encode()
             records.append(harness.Record(template, text, 0.0, 0.0, 200, raw))
-    wrong, failed, _ = harness.judge(records, ref.answer)
+    wrong, failed, _ = harness.judge(records, ref)
     # stale: the writes are acknowledged, the read-back answers without them
     g = cell.config["guarantees"]["read_your_writes"]
     stale = Reference(data)

@@ -11,7 +11,9 @@ A field is one of
       set of densities, so the load is the same work whatever the seed);
   set / "one_of": every populated column carries exactly one row, drawn
       with the stated `shares`;
-  int: a value uniform in `min`..`max` on a `share` of the columns.
+  int: a value uniform in `min`..`max` on a `share` of the columns;
+or of a kind the configuration's dialect brings (`lib/dialects/`): its draw,
+its rows, its schema options and its loader are the dialect's.
 """
 
 from __future__ import annotations
@@ -24,6 +26,8 @@ from urllib.parse import urlparse
 
 import numpy as np
 
+from .dialects import BASE_KINDS, NONE, kind
+
 MAX_WRITES_PER_REQUEST = 5000  # the server's shipped request cap
 LOAD_WORKERS = 8
 ROW_CHUNK = 32  # rows drawn at a time: bounds the float scratch
@@ -34,9 +38,11 @@ class Data:
     shard-major and ascending, so a boolean selection of `cols` is a
     sorted unique array of column ids."""
 
-    def __init__(self, config: dict, seed: int, shard_width: int):
+    def __init__(self, config: dict, seed: int, shard_width: int,
+                 dialect=NONE):
         rng = np.random.default_rng(seed)
         self.config = config
+        self.dialect = dialect
         self.index = config["index"]
         self.shards = shards = config["shards"]
         self.per_shard = per = config["columns_per_shard"]
@@ -56,7 +62,10 @@ class Data:
 
     def _draw(self, spec: dict, rng) -> dict:
         n = self.n
-        if spec["type"] == "int":
+        k = kind(spec)
+        if k not in BASE_KINDS:
+            return self.dialect.hook("draw", k)(self, spec, rng)
+        if k == "int":
             has = rng.random(n) < spec["share"]
             vals = rng.integers(spec["min"], spec["max"] + 1, size=n)
             return {"spec": spec, "has": has, "values": vals.astype(np.int64)}
@@ -83,7 +92,9 @@ class Data:
         f = self.fields[field]
         if "member" in f:
             return f["member"][rid]
-        return f["labels"] == rid
+        if "labels" in f:
+            return f["labels"] == rid
+        return self.dialect.hook("row_mask", kind(f["spec"]))(self, f, rid)
 
     def shard_positions(self, field: str, s: int) -> np.ndarray:
         """Sorted fragment positions (row * width + in-shard position) of
@@ -195,13 +206,17 @@ def _fan_out(uri: str, n_items: int, one) -> None:
             fut.result()
 
 
-def create_schema(http_: Http, config: dict) -> None:
+def create_schema(http_: Http, config: dict, dialect=NONE) -> None:
     index = config["index"]
     http_.call("POST", f"/index/{index}", {"options": {}})
     for spec in config["fields"]:
-        options = {"type": spec["type"]}
-        if spec["type"] == "int":
-            options.update(min=spec["min"], max=spec["max"])
+        k = kind(spec)
+        if k not in BASE_KINDS:
+            options = dialect.hook("field_options", k)(spec)
+        else:
+            options = {"type": spec["type"]}
+            if spec["type"] == "int":
+                options.update(min=spec["min"], max=spec["max"])
         http_.call("POST", f"/index/{index}/field/{spec['name']}",
                    {"options": options})
 
@@ -209,9 +224,13 @@ def create_schema(http_: Http, config: dict) -> None:
 def load(uri: str, data: Data) -> None:
     """Every shard of every field through the public import routes: set
     fields as one roaring file per shard, int fields as `import-value`
-    requests under the server's request cap."""
+    requests under the server's request cap, a dialect's kind through
+    the dialect's importer."""
     for name, f in data.fields.items():
-        if f["spec"]["type"] == "int":
+        k = kind(f["spec"])
+        if k not in BASE_KINDS:
+            one = data.dialect.hook("importer", k)(data, name, f)
+        elif k == "int":
             one = _value_importer(data, name, f)
         else:
             one = _roaring_importer(data, name)

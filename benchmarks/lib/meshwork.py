@@ -50,7 +50,7 @@ def per_chip_roofline_pct(ctx, chips: int):
         return None
     t0, t1 = ctx.slice
     logical = sum(
-        work.request_bytes(ctx.config, r["text"])
+        work.request_bytes(ctx.config, r["text"], ctx.dialect)
         for r in ctx.requests
         if t0 <= r["received"] <= t1 and did_device_work(r["roots"])
     )

@@ -13,6 +13,7 @@ import statistics
 
 from . import trace as tracelib
 from . import work
+from .dialects import NONE
 
 
 class Context:
@@ -21,8 +22,8 @@ class Context:
     and the device trace of the slice."""
 
     def __init__(self, config, answered, before, after, kind, require_peak,
-                 slice_, planes):
-        self.config = config
+                 slice_, planes, dialect=NONE):
+        self.config, self.dialect = config, dialect
         self.requests = [
             {"text": r.text, "wall_ms": (r.received - r.sent) * 1000.0,
              "received": r.received,
@@ -66,10 +67,7 @@ def _span(source: dict, ctx: Context):
     per_request = []
     for r in ctx.requests:
         found = list(spans(r["roots"], name))
-        if reduce == "median_wall_minus_duration":
-            if found:
-                per_request.append(r["wall_ms"] - found[0]["durationMs"])
-        elif reduce == "count_per_request":
+        if reduce == "count_per_request":
             per_request.append(len(found))
         elif reduce == "mean_tag_per_request":
             per_request.append(
@@ -79,8 +77,6 @@ def _span(source: dict, ctx: Context):
             raise ValueError(f"unknown span reducer {reduce!r}")
     if not per_request:
         return None
-    if reduce.startswith("median"):
-        return statistics.median(per_request)
     return statistics.fmean(per_request)
 
 
@@ -115,7 +111,7 @@ def _trace(source: dict, ctx: Context):
             return None
         t0, t1 = ctx.slice
         logical = sum(
-            work.request_bytes(ctx.config, r["text"])
+            work.request_bytes(ctx.config, r["text"], ctx.dialect)
             for r in ctx.requests
             if t0 <= r["received"] <= t1 and did_device_work(r["roots"])
         )

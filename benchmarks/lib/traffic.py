@@ -84,23 +84,43 @@ class Mix:
     def warmup_requests(self) -> list:
         """Requests that touch every row this mix can name, in the shapes
         the mix sends, each template at least once: what has to be staged
-        and compiled before the window opens."""
-        out = []
-        for t, names in enumerate(self._vars):
-            if not names:
-                out.append(self.templates[t]["pql"])
-        widest = max(range(len(self._vars)), key=lambda t: len(self._vars[t]))
-        names = self._vars[widest]
-        if names:
-            field, _, ids = self._draw[names[0]]
-            if any(self._draw[v][0] != field for v in names):
-                raise ValueError("warm-up expects one field per template")
-            rows, k = len(ids), len(names)
-            for lo in range(0, rows, k):
-                ids = [(lo + j) % rows for j in range(k)]
-                for t, tnames in enumerate(self._vars):
-                    if tnames and (t == widest or lo == 0):
-                        out.append(Template(self.templates[t]["pql"]).substitute(
-                            dict(zip(tnames, ids))
-                        ))
+        and compiled before the window opens. First the templates without
+        a variable; then every other template once with the first rows of
+        its fields, and again, walking on, for as long as it is the one
+        that walks a field: for each field that is the first of the
+        templates with the most variables drawing from it. In a step every
+        variable of such a template moves on through its own field's rows
+        (the k variables of one field take k new rows a step, and go round
+        again where another field has more rows), until every row of every
+        field has been sent once."""
+        out = [
+            self.templates[t]["pql"]
+            for t, names in enumerate(self._vars) if not names
+        ]
+        n_rows = {field: len(ids) for field, _, ids in self._draw.values()}
+        by_field = []  # per template: field -> its variables, in order
+        for names in self._vars:
+            groups = {}
+            for v in dict.fromkeys(names):
+                groups.setdefault(self._draw[v][0], []).append(v)
+            by_field.append(groups)
+        steps = [1 if groups else 0 for groups in by_field]
+        for field in n_rows:
+            walker = max(
+                range(len(by_field)),
+                key=lambda t: len(by_field[t].get(field, ())),
+            )
+            k = len(by_field[walker].get(field, ()))
+            if k:
+                steps[walker] = max(steps[walker], -(-n_rows[field] // k))
+        for step in range(max(steps, default=0)):
+            for t, groups in enumerate(by_field):
+                if step >= steps[t]:
+                    continue
+                values = {
+                    v: (step * len(names) + j) % n_rows[field]
+                    for field, names in groups.items()
+                    for j, v in enumerate(names)
+                }
+                out.append(Template(self.templates[t]["pql"]).substitute(values))
         return out

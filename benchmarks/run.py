@@ -8,7 +8,9 @@ the one child it starts (`serve.py`, the program's normal server) is the
 only process that holds the chip. Everything about a cell is data:
 `BENCHMARK.json` names the cell's configuration and traffic mix, and the
 files `configs/<config>.json`, `traffic/<mix>.json` and
-`metrics/<metric>.json` are found by those names.
+`metrics/<metric>.json` are found by those names; a configuration that
+asks or stores what the base language has not names its dialect,
+`lib/dialects/<name>.py` (the contract is that package's docstring).
 
 A run: start the child, read the device from `/info` (a TPU, or fail),
 create the schema, load through the public import routes, warm up this
@@ -39,9 +41,9 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 
-from lib import readers, trace as tracelib  # noqa: E402
+from lib import dialects, readers, trace as tracelib  # noqa: E402
 from lib.data import Data, Http, HttpError, create_schema, load  # noqa: E402
-from lib.reference import Reference, normalise  # noqa: E402
+from lib.reference import Reference  # noqa: E402
 from lib.traffic import Mix  # noqa: E402
 
 START_TIMEOUT_S = 300
@@ -60,9 +62,11 @@ def read_json(*parts: str):
 
 
 class Cell:
-    """One entry of BENCHMARK.json's `workloads`, with its files."""
+    """One entry of BENCHMARK.json's `workloads`, with its files, all
+    found under `root` (the checkout; a test's own tree)."""
 
     def __init__(self, root: str, name: str):
+        self.dir = os.path.join(root, os.path.basename(HERE))
         self.bench = read_json(root, "BENCHMARK.json")
         cells = {w["name"]: w for w in self.bench["workloads"]}
         if name not in cells:
@@ -74,7 +78,8 @@ class Cell:
             root, configs[self.entry["config"]]["file"]
         )
         self.config = read_json(self.config_file)
-        self.mix = read_json(HERE, "traffic", self.entry["traffic"] + ".json")
+        self.mix = read_json(self.dir, "traffic", self.entry["traffic"] + ".json")
+        self.dialect = dialects.load(self.dir, self.config.get("dialect"))
 
     def metrics(self, group: str) -> list:
         """This cell's metrics of `end_to_end` or `per_layer`: those with
@@ -297,11 +302,12 @@ def write_then_read(http_: Http, data: Data, ref: Reference) -> int:
     return wrong
 
 
-def judge(records: list, answer) -> tuple:
-    """Every answer of the window against the reference's (`answer(text)`).
-    Returns (wrong, failed, [(record, parsed body)] of the right ones): a
-    request that failed or was shed never got its answer; one that was
-    answered wrongly says the wrong thing."""
+def judge(records: list, ref: Reference) -> tuple:
+    """Every answer of the window, in the reference's normal form, against
+    the reference's. Returns (wrong, failed, [(record, parsed body)] of the
+    right ones): a request that failed or was shed never got its answer;
+    one that was answered wrongly, or not in the shape of an answer to its
+    call, says the wrong thing."""
     wrong, failed, good = 0, 0, []
     for r in records:
         if r.status != 200:
@@ -310,8 +316,12 @@ def judge(records: list, answer) -> tuple:
                 log(f"FAILED {r.text}: {r.status} {r.raw[:600]!r}")
             continue
         body = json.loads(r.raw)
-        want = answer(r.text)
-        if normalise(body["results"][0]) != want:
+        want = ref.answer(r.text)
+        try:
+            got = ref.normalise(r.text, body["results"][0])
+        except (KeyError, TypeError, IndexError):
+            got = None  # no answer is None
+        if got != want:
             wrong += 1
             if wrong <= 3:
                 log(f"WRONG {r.text}: {str(body['results'][0])[:200]} != "
@@ -341,10 +351,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, server,
 
     # -- set-up: data, schema, load, warm-up ------------------------------
     t = time.perf_counter()
-    data = Data(config, seed, info["shardWidth"])
+    data = Data(config, seed, info["shardWidth"], cell.dialect)
     ref = Reference(data)
     t_gen = time.perf_counter() - t
-    create_schema(http_, config)
+    create_schema(http_, config, cell.dialect)
     t = time.perf_counter()
     load(server.uri, data)
     t_load = time.perf_counter() - t
@@ -352,7 +362,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, server,
     mix = Mix(mix_spec, data.n_rows, seed)
     path = f"/index/{data.index}/query" + ("?profile=1" if trace else "")
     t = time.perf_counter()
-    warm = mix.warmup_requests()
+    warm = cell.dialect.warmup_requests(mix)
     for text in warm:
         http_.call_raw("POST", path, text)
     t_stage = time.perf_counter() - t
@@ -397,7 +407,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, server,
     server.stop_clean()
 
     t = time.perf_counter()
-    wrong, failed, answered = judge(records, ref.answer)
+    wrong, failed, answered = judge(records, ref)
     log(f"reference compared {len(records)} answers in "
         f"{time.perf_counter() - t:.1f} s")
     lat = [(r.received - r.sent) * 1000.0 for r, _ in answered]
@@ -422,7 +432,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, server,
     if trace:
         t = time.perf_counter()
         ctx = readers.Context(
-            config=config, answered=answered, before=before, after=after,
+            config=config, dialect=cell.dialect, answered=answered,
+            before=before, after=after,
             kind=device["kind"], require_peak=require_tpu,
             slice_=(slice_["t0"], slice_["t1"]),
             planes=tracelib.extract(os.path.join(work, "trace"), work),
@@ -430,7 +441,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, server,
         log(f"trace read and reduced in {time.perf_counter() - t:.1f} s")
         metrics = {}
         for m in cell.metrics("per_layer"):
-            spec = read_json(HERE, "metrics", m["name"] + ".json")
+            spec = read_json(cell.dir, "metrics", m["name"] + ".json")
             value = readers.read(spec, ctx)
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}

@@ -5,6 +5,7 @@ timed path and the control make `correct` false, a CPU server is refused,
 and the trace and work arithmetic give the stated numbers."""
 
 import copy
+import glob
 import json
 import os
 import re
@@ -190,7 +191,23 @@ def test_a_cpu_server_is_refused_and_a_bare_checkout_measures_nothing(tmp_path):
         "platform": "tpu", "kind": "TPU v5 lite", "count": 1}
     with open(os.path.join(ROOT, "benchmarks", "lib", "peaks.json")) as f:
         assert "TPU v5 lite" in json.load(f)
-    source = open(os.path.join(ROOT, "benchmarks", "run.py")).read()
+
+
+# numpy + stdlib: the process that measures never holds the chip, and the
+# yardstick shares nothing with the program (`serve.py`, the child, and
+# `lib/xplane.py`, which reads the profiler's file, import jax by design)
+YARDSTICK = ["run.py", "control.py"] + [
+    os.path.join("lib", f) for f in (
+        "pql.py", "reference.py", "work.py", "data.py", "traffic.py")
+] + sorted(
+    os.path.relpath(p, os.path.join(ROOT, "benchmarks")) for p in glob.glob(
+        os.path.join(ROOT, "benchmarks", "lib", "dialects", "*.py"))
+)
+
+
+@pytest.mark.parametrize("path", YARDSTICK)
+def test_the_yardstick_imports_neither_jax_nor_the_program(path):
+    source = open(os.path.join(ROOT, "benchmarks", path)).read()
     assert not re.search(r"^\s*(import|from)\s+(jax|pilosa_tpu)", source, re.M)
 
 
