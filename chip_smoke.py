@@ -19,7 +19,8 @@ one process holds the chip and this parent never initialises a JAX backend
    and the GroupBy's cross tally must have run as the VMEM kernel on one
    chip (`groupby.kernel_tallies` on `/debug/vars`) over the view's four
    resident extents as they lie (`groupby.assembled_stacks` flat), as the
-   XLA program on several; every cold dispatch must have run as one program over all
+   XLA program on several, and so must the group tally of the filtered
+   `GroupBy(..., aggregate=Sum(field=v))`; every cold dispatch must have run as one program over all
    the devices the server holds (`mesh.devices` on its span: 4 on a
    four-chip host, 1 on one chip);
 4. writes (a PQL `Set`, then an `/import` burst large enough to cross the
@@ -214,10 +215,23 @@ class Reference:
         return out
 
 
+    def group_by_sum(self, a: str, b: str, filt: np.ndarray) -> dict:
+        """{(row of a, row of b): (columns under the filter, sum of v over
+        those of them that hold a value)}."""
+        out = {}
+        for i, ra in enumerate(self.rows[a]):
+            for j, rb in enumerate(self.rows[b]):
+                cols = np.intersect1d(np.intersect1d(ra, rb, True), filt, True)
+                if len(cols):
+                    out[(i, j)] = (len(cols), int(self._values(cols).sum()))
+        return out
+
+
 def read_queries(ref: Reference) -> list:
     """(family, pql, expected answer, fewest `exec.dispatch` spans a cold
     run may show)."""
     f = [ref.row("f", r) for r in range(len(F_DENSITY))]
+    agg_shards = min(ref.shards, 300)
     return [
         ("count_intersect", "Count(Intersect(Row(f=0), Row(f=1)))",
          len(np.intersect1d(f[0], f[1], True)), 1),
@@ -241,6 +255,15 @@ def read_queries(ref: Reference) -> list:
         ("count_range", f"Count(Row(v > {V_THRESHOLD}))",
          ref.count_gt(V_THRESHOLD), 1),
         ("group_by", "GroupBy(Rows(g), Rows(h))", ref.group_by("g", "h"), 1),
+        # the filter's plan, then the group tally: each dimension against
+        # the filter, and the groups the surviving rows make with v's planes.
+        # Over the first 300 shards (two extents): v's 19 planes over all
+        # 954 are more than a quarter of the shipped 4 GB budget, which
+        # the executor answers shard by shard, without a tally
+        ("group_by_sum",
+         f"Options(GroupBy(Rows(g), Rows(h), filter=Row(f=1), "
+         f"aggregate=Sum(field=v)), shards={list(range(agg_shards))})",
+         ref.group_by_sum("g", "h", f[1][f[1] < agg_shards * ref.width]), 2),
         # a filtered MinRow/MaxRow counts the [S, W] filter stack
         # (`ops/bitmap.popcount`), a Tanimoto TopN counts it per shard
         # (`popcount_rows`): the served path's only uses of the two
@@ -403,7 +426,9 @@ def _spans(span: dict, name: str):
 def _normalise(result):
     if isinstance(result, list) and result and "group" in result[0]:
         return {
-            tuple(m["rowID"] for m in g["group"]): g["count"] for g in result
+            tuple(m["rowID"] for m in g["group"]):
+                (g["count"], g["sum"]) if "sum" in g else g["count"]
+            for g in result
         }
     return result
 
@@ -444,7 +469,7 @@ def check_tally_program(family: str, kernel: int, xla: int, device: dict,
     one_chip = device["platform"] == "tpu" and device["count"] == 1
     ran, other = (kernel, xla) if one_chip else (xla, kernel)
     # a filtered TopN whose candidates are all sparse rows tallies no stack
-    if other or not (ran or family != "group_by"):
+    if other or not (ran or not family.startswith("group_by")):
         raise AssertionError(
             f"{family}: {kernel} kernel and {xla} XLA cross tallies on "
             f"{device['count']} x {device['platform']}"
@@ -468,7 +493,7 @@ def check_placement(family: str, placed: list, device: dict) -> None:
         )
 
 
-TALLY_FAMILIES = ("group_by", "topn_filtered")
+TALLY_FAMILIES = ("group_by", "group_by_sum", "topn_filtered")
 
 
 def run_queries(http_: Http, queries: list, cold: bool,

@@ -844,8 +844,12 @@ class DistributedExecutor(Executor):
                     key = tuple((fr.field, fr.row_id) for fr in gc.group)
                     if key in merged:
                         merged[key].count += gc.count
+                        if gc.sum is not None:  # aggregate=Sum: exact ints
+                            merged[key].sum = (merged[key].sum or 0) + gc.sum
                     else:
-                        merged[key] = GroupCount(group=list(gc.group), count=gc.count)
+                        merged[key] = GroupCount(
+                            group=list(gc.group), count=gc.count, sum=gc.sum
+                        )
             out = sorted(merged.values(), key=lambda g: g.compare_key())
             offset = c.uint_arg("offset")
             limit = c.uint_arg("limit")

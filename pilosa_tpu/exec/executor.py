@@ -148,11 +148,20 @@ class FieldRow:
 
 @dataclass
 class GroupCount:
+    """One group of a GroupBy. `sum` is set only when the GroupBy carried
+    `aggregate=Sum(field=)`: the exact sum of that field over the group's
+    columns that hold a value; without the argument the JSON has no such
+    key."""
+
     group: List[FieldRow]
     count: int
+    sum: Optional[int] = None
 
     def to_json(self):
-        return {"group": [g.to_json() for g in self.group], "count": self.count}
+        out = {"group": [g.to_json() for g in self.group], "count": self.count}
+        if self.sum is not None:
+            out["sum"] = self.sum
+        return out
 
     def compare_key(self):
         return tuple(g.row_id for g in self.group)
@@ -580,6 +589,10 @@ _CACHE_TIME_ARGS = ("from", "to", "_start", "_end")
 # counts outside the version vector — ineligible
 _CACHE_TOPN_ARGS = frozenset({"_field", "n", "ids", "threshold"})
 _CACHE_GROUPBY_ARGS = frozenset({"filter", "limit", "offset", "previous"})
+# every argument _execute_group_by reads; anything else is refused by name.
+# A GroupBy with aggregate= is never kept by the result cache (nor served
+# from it): its entry would have to depend on the value field's planes
+_GROUPBY_ARGS = _CACHE_GROUPBY_ARGS | {"aggregate"}
 _CACHE_ROWS_ARGS = frozenset({"_field", "field", "limit", "previous", "column"})
 
 
@@ -611,6 +624,25 @@ class _CacheCtx:
         self.clocks = None  # per-view mutation clocks, read pre-vector
         self.hit = False
         self.hit_result = None
+
+
+def _group_by_launches() -> int:
+    from pilosa_tpu.exec import groupby as qgb
+
+    return qgb.STATS["kernel_tallies"] + qgb.STATS["xla_tallies"]
+
+
+def _tag_group_by(launches: int, info: dict) -> None:
+    """What one GroupBy's tallies did, on its exec.dispatch span (the
+    caller is inside it and holds the dispatch mutex, so a delta of
+    groupby.STATS is this query's): groupby.levels / live_groups /
+    planes / fold_ms from `info`, groupby.tallies the launches."""
+    sp = tracing.active_span()
+    if sp is None:
+        return
+    for k, v in info.items():
+        sp.set_tag(f"groupby.{k}", v)
+    sp.set_tag("groupby.tallies", launches)
 
 
 class Executor:
@@ -3161,10 +3193,20 @@ class Executor:
                 raise ExecError(
                     f"'{child.name}' is not a valid child query for GroupBy, must be 'Rows'"
                 )
+        unread = sorted(set(c.args) - _GROUPBY_ARGS)
+        if unread:
+            raise ExecError(
+                f"GroupBy does not take the argument '{unread[0]}' (it reads "
+                "filter, limit, offset, previous and aggregate)"
+            )
         limit = c.uint_arg("limit")
         filter_call = c.args.get("filter")
         if filter_call is not None and not isinstance(filter_call, Call):
             raise ExecError("GroupBy filter must be a query")
+        value_field = (
+            self._group_by_aggregate_field(idx, c)
+            if "aggregate" in c.args else None
+        )
 
         # Pagination cursor: per-child Rows(previous=) args plus the
         # GroupBy-level previous=[...] list form; both resume the sorted
@@ -3232,7 +3274,8 @@ class Executor:
 
         shard_list = self._shards_for(idx, shards)
         merged = self._group_by_stacked(
-            idx, child_fields, child_rows, filter_call, shard_list
+            idx, child_fields, child_rows, filter_call, shard_list,
+            value_field,
         )
         if merged is None:
             merged = {}
@@ -3245,10 +3288,21 @@ class Executor:
                 if filter_call is not None and fw is None:
                     continue
                 self._group_by_shard(
-                    idx, child_fields, child_rows, fw, shard, merged
+                    idx, child_fields, child_rows, fw, shard, merged,
+                    value_field,
                 )
         if anchor is not None:
             merged = {k: v for k, v in merged.items() if k >= anchor}
+        if value_field is not None:
+            # (count, stored sum, values) -> count and the sum of the
+            # field's values: the stored ones are relative to its base
+            base = value_field.options.base
+            merged = {
+                k: (cnt, stored + n * base)
+                for k, (cnt, stored, n) in merged.items()
+            }
+        else:
+            merged = {k: (cnt, None) for k, cnt in merged.items()}
         out = [
             GroupCount(
                 group=[
@@ -3256,8 +3310,9 @@ class Executor:
                     for fn, rid in zip(child_fields, key)
                 ],
                 count=cnt,
+                sum=total,
             )
-            for key, cnt in merged.items()
+            for key, (cnt, total) in merged.items()
             if cnt > 0
         ]
         out.sort(key=lambda g: g.compare_key())
@@ -3268,15 +3323,52 @@ class Executor:
             out = out[:limit]
         return out
 
+    def _group_by_aggregate_field(self, idx: Index, c: Call) -> Field:
+        """The int field of a GroupBy's `aggregate=Sum(field=<f>)`; any
+        other shape of the argument raises, naming it."""
+        agg = c.args["aggregate"]
+        if not isinstance(agg, Call):
+            raise ExecError(
+                f"GroupBy aggregate must be a call, Sum(field=<int field>), "
+                f"not {agg!r}"
+            )
+        if agg.name != "Sum":
+            raise ExecError(
+                f"GroupBy aggregate '{agg.name}' is not supported: the "
+                "aggregate is Sum(field=<int field>)"
+            )
+        field_name = agg.string_arg("field")
+        extra = sorted(set(agg.args) - {"field"})
+        if field_name is None or extra or agg.children:
+            what = (
+                f"the argument '{extra[0]}'" if extra
+                else "a child query" if agg.children else "no field="
+            )
+            raise ExecError(
+                f"GroupBy aggregate Sum takes field=<int field> alone, "
+                f"found {what}"
+            )
+        f = self._field_of(idx, field_name)
+        if f.options.type != FIELD_TYPE_INT:
+            raise ExecError(
+                f"GroupBy aggregate Sum: field {field_name} is not an int field"
+            )
+        return f
+
     def _group_by_stacked(
-        self, idx, child_fields, child_rows, filter_call, shard_list
-    ) -> Optional[Dict[Tuple[int, ...], int]]:
+        self, idx, child_fields, child_rows, filter_call, shard_list,
+        value_field: Optional[Field] = None,
+    ) -> Optional[Dict[Tuple[int, ...], Any]]:
         """Tally the whole GroupBy cross-product in O(depth) batched device
         dispatches over stacked [R, S, W] operands, each handed over as
         the tuple of its resident extents (exec/groupby.py),
         replacing the per-(prefix, depth) dispatch + host sync of the
-        recursive walk. Returns None to fall back to the per-shard path
-        (stacked lowering unsupported for this shape/budget)."""
+        recursive walk. With `value_field` (aggregate=Sum) its BSI planes
+        are staged beside the dimensions, one stack through the same
+        residency layer, and a group's value is (count, stored sum,
+        values) instead of its count. Returns None to fall back to the
+        per-shard path (stacked lowering unsupported for this
+        shape/budget)."""
         if not _STACKED_ENABLED or not shard_list:
             return None
         if filter_call is not None and self._count_shifts(filter_call):
@@ -3326,10 +3418,37 @@ class Executor:
                     if p is None:
                         return {}
                     planes_list.append(p)
+                value = None
+                if value_field is not None:
+                    # exists, sign and the magnitude planes as ONE stack
+                    # over the same shards: a shard without a BSI fragment
+                    # is a zero slab (no column there holds a value)
+                    depth = value_field.options.bit_depth
+                    bsiv = value_field.view(value_field.bsi_view_name())
+                    if bsiv is not None:
+                        low._stack_guard(bsiv, mult=depth + 2)
+                        value = bsiv.plane_stack(
+                            range(BSI_OFFSET_BIT + depth), low.shards,
+                            parts=True,
+                        )
         except Unsupported:
             return None
         finally:
             low.extents.release()  # staging-window pins (see _stacked_bsi)
+        if value_field is not None and value is None:
+            # no column holds a value anywhere: the groups, with nothing summed
+            counted = self._group_by_dispatch(planes_list, child_rows, filt)
+            return {k: (cnt, 0, 0) for k, cnt in counted.items()}
+        return self._group_by_dispatch(
+            planes_list, child_rows, filt, value, value_field
+        )
+
+    @staticmethod
+    def _group_by_dispatch(
+        planes_list, child_rows, filt, value=None, value_field=None
+    ):
+        """The whole tally pipeline — the cross tally, or with `value` (the
+        aggregate's plane stack) the group tally — as ONE exec.dispatch."""
         from pilosa_tpu.exec import groupby as qgb
         from pilosa_tpu.exec import plan as planmod
 
@@ -3340,18 +3459,43 @@ class Executor:
         # rationale); operands above were staged before entry. run_counted
         # books it as one exec.dispatch span (its reads happen inside), so
         # a profile shows the answer came from the device
+        def tally():
+            launched = _group_by_launches()
+            if value is None:
+                out = qgb.group_by_device(planes_list, child_rows, filt)
+                info = {"levels": len(planes_list), "live_groups": len(out),
+                        "planes": 0, "fold_ms": 0.0}
+            else:
+                o, info = value_field.options, {}
+                out = qgb.group_by_aggregate(
+                    planes_list, child_rows, filt, value, o.bit_depth,
+                    signed=o.min < o.base, info=info,
+                )
+            _tag_group_by(_group_by_launches() - launched, info)
+            return out
+
         return planmod.run_counted(
-            lambda: qgb.group_by_device(planes_list, child_rows, filt),
-            read=False, family="groupby",
-            program=qgb.tally_program(planes_list, filt),
+            tally, read=False, family="groupby",
+            program=(
+                qgb.tally_program(planes_list, filt) if value is None
+                else qgb.group_program(planes_list, value, filt)
+            ),
             arrays=planes_list,
         )
 
     def _group_by_shard(  # dispatch-ok: per-shard path, single-device
-        self, idx, child_fields, child_rows, filter_words, shard, merged
+        self, idx, child_fields, child_rows, filter_words, shard, merged,
+        value_field: Optional[Field] = None,
     ) -> None:
         """Nested cross-product with zero-count pruning (the reference's
-        groupByIterator, executor.go:3063)."""
+        groupByIterator, executor.go:3063). With `value_field` a group's
+        entry is (count, stored sum, values), the last two from the
+        shard's BSI fragment over the group's words."""
+        value_frag = None
+        if value_field is not None:
+            bsiv = value_field.view(value_field.bsi_view_name())
+            if bsiv is not None:
+                value_frag = bsiv.fragment_if_exists(shard)
         frags = []
         for fname in child_fields:
             f = self._field_of(idx, fname)
@@ -3371,7 +3515,20 @@ class Executor:
                 if cnt == 0:
                     continue
                 key = prefix + (rid,)
-                if depth == len(frags) - 1:
+                if depth == len(frags) - 1 and value_field is not None:
+                    stored = n = 0
+                    if value_frag is not None:
+                        words = frag.row_device(rid)
+                        if acc_words is not None:
+                            words = ob.b_and(acc_words, words)
+                        stored, n = value_frag.sum(
+                            words, value_field.options.bit_depth
+                        )
+                    was = merged.get(key, (0, 0, 0))
+                    merged[key] = (
+                        was[0] + int(cnt), was[1] + stored, was[2] + n
+                    )
+                elif depth == len(frags) - 1:
                     merged[key] = merged.get(key, 0) + int(cnt)
                 else:
                     words = frag.row_device(rid)

@@ -38,6 +38,18 @@ program, the pruned descent's row selections, a cross of more than three
 levels — and concatenates an operand given as parts once, where it is
 first needed (`assembled_stacks`).
 
+A GroupBy that carries an aggregate (`group_by_aggregate`) owes every live
+group one count per BSI plane of the value field besides its own. Its
+work follows the groups, not the cross: under a filter each dimension is
+first tallied against the filter alone (the rows the filter leaves are
+the only ones a group can hold), and the groups those rows make — or,
+where they are too many to list, the live groups of the count-only tally
+above — are handed to `group_tally` as a table of row indices, which
+counts each listed group and its planes in one pass over the rows where
+they lie (`ops/pallas_kernels.group_counts`; `_counts_groups` is the XLA
+program for every other backend and the kernel's oracle). Per-shard
+counts are uint32 and are folded on the host in exact integers.
+
 Cross-products too deep or too large for one read are tallied level-wise:
 at depth d one tally counts every live prefix against every candidate
 row, and one host read prunes zero groups before descending. Dispatch
@@ -50,7 +62,9 @@ index vectors are padded to powers of two to bound recompilation.
 
 from __future__ import annotations
 
+import functools
 import os
+import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -61,9 +75,12 @@ import numpy as np
 # the tally counts are published as groupby.* gauges (server/node.py):
 # tallies by program, tallies that read more than one extent in place, and
 # operands concatenated into one stack for a tally.
+# `assembled_bytes` is what those concatenations wrote; `aggregate_queries`
+# and `plane_tallies` (group x plane pairs counted) are the aggregate's.
 STATS = {
     "evals": 0, "kernel_tallies": 0, "xla_tallies": 0,
-    "inplace_tallies": 0, "assembled_stacks": 0,
+    "inplace_tallies": 0, "assembled_stacks": 0, "assembled_bytes": 0,
+    "aggregate_queries": 0, "plane_tallies": 0,
 }
 
 KERNEL_PROGRAM = "jit__cross_counts_vmem"
@@ -138,6 +155,7 @@ def _assembled(x):
     if len(x) == 1:
         return x[0]
     STATS["assembled_stacks"] += 1
+    STATS["assembled_bytes"] += sum(int(p.nbytes) for p in x)
     return jnp.concatenate(x, axis=1)
 
 
@@ -373,3 +391,189 @@ def _descend(  # dispatch-ok: caller holds dispatch_mutex
             prefixes[g] + (int(row_lists[depth][r]),) for g, r in zip(gi, ri)
         ]
         _descend(depth + 1, acc2, pfx, planes_list, row_lists, merged, gmax)
+
+
+# ---------------------------------------------------------------------------
+# GroupBy with an aggregate: counts of listed groups and of their planes
+# ---------------------------------------------------------------------------
+
+GROUP_KERNEL_PROGRAM = "jit__group_counts_vmem"
+GROUP_XLA_PROGRAM = "jit__counts_groups"
+# rows of the value field's plane stack, as its fragments number them
+from pilosa_tpu.core.fragment import (  # noqa: E402
+    BSI_EXISTS_BIT as _EXISTS_ROW,
+    BSI_OFFSET_BIT as _OFFSET_ROW,
+    BSI_SIGN_BIT as _SIGN_ROW,
+)
+
+
+@functools.partial(jax.jit, static_argnames=("mask_row",))
+def _counts_groups(dims, idx, planes, filt, mask_row):
+    """dims a tuple of uint32[R_d, S, W], idx int32[n_dims, G], planes
+    uint32[P, S, W] or None, filt uint32[S, W] or None -> per-shard counts
+    uint32[G, 1 + P, S]: [g, 0] of t = AND_d dims[d][idx[d, g]] (& filt),
+    [g, 1 + p] of t & planes[mask_row] & planes[p]. The listed prefixes are
+    materialised ([G, S, W]: callers keep G within `_gmax`)."""
+    t = dims[0][idx[0]]
+    for d, i in zip(dims[1:], idx[1:]):
+        t = jnp.bitwise_and(t, d[i])
+    if filt is not None:
+        t = jnp.bitwise_and(t, filt[None])
+    own = _counts_planes(t)[:, None]
+    if planes is None:
+        return own
+    if mask_row is not None:
+        t = jnp.bitwise_and(t, planes[mask_row][None])
+    return jnp.concatenate([own, _counts_cross(t, planes)], axis=1)
+
+
+def _group_kernel_covers(dims, planes, filt) -> bool:
+    """Whether the VMEM group tally counts these operands: what
+    `_kernel_covers` asks, parts that line up, and one tile of every row
+    within the kernel's buffer."""
+    from pilosa_tpu.ops import pallas_kernels
+
+    stacks = list(dims) + ([planes] if planes is not None else [])
+    if not (_kernel_covers(*stacks, filt) and _spans_align(*stacks)):
+        return False
+    rows = sum(_shape(x)[0] for x in stacks) + (filt is not None)
+    return bool(pallas_kernels.group_words(rows, _shape(dims[0])[2]))
+
+
+def group_program(dims, planes=None, filt=None) -> str:
+    """The jitted program that tallies an aggregate GroupBy's groups."""
+    if _group_kernel_covers(dims, planes, filt):
+        return GROUP_KERNEL_PROGRAM
+    return GROUP_XLA_PROGRAM
+
+
+def group_tally(dims, idx: np.ndarray, planes=None, filt=None, mask_row=None):  # dispatch-ok: caller holds dispatch_mutex
+    """Per-shard counts of the groups `idx` int[n_dims, G] lists (row
+    idx[d, g] of dimension d makes group g), and of each with every plane
+    of `planes`: a list of device arrays uint32[<= G padded, 1 + P, S], one
+    a launch, for `_read_groups`. The kernel where it covers the operands
+    (parts read in place, the table padded to a power of two and only the
+    listed groups tallied), else the XLA program over whole stacks in
+    chunks of `_gmax` groups. Launches are asynchronous."""
+    from pilosa_tpu.ops import pallas_kernels
+
+    g_n = idx.shape[1]
+    STATS["plane_tallies"] += g_n * (0 if planes is None else _shape(planes)[0])
+    kernel = _group_kernel_covers(dims, planes, filt)
+    if kernel:
+        step = pallas_kernels.GROUP_MAX_GROUPS
+        in_place = len(_parts(dims[0])) > 1
+    else:
+        dims = tuple(_assembled(d) for d in dims)
+        planes = _assembled(planes)
+        _, s, w = _shape(dims[0])
+        step = 1 << (_gmax(s, w).bit_length() - 1)
+    outs = []
+    for lo in range(0, g_n, step):
+        chunk = idx[:, lo : lo + step]
+        table = np.stack([_pad_pow2(r) for r in chunk]).astype(np.int32)
+        STATS["evals"] += 1
+        if kernel:
+            STATS["kernel_tallies"] += 1
+            STATS["inplace_tallies"] += in_place
+            outs.append(pallas_kernels.group_counts(
+                dims, table, np.array([chunk.shape[1]], np.int32), planes,
+                filt, mask_row,
+            ))
+        else:
+            STATS["xla_tallies"] += 1
+            outs.append(_counts_groups(dims, table, planes, filt, mask_row))
+    return outs
+
+
+def _read_groups(outs, g_n: int) -> np.ndarray:
+    """The launches of one `group_tally` read and summed over the shard
+    axis in exact uint64: [G, 1 + P]."""
+    return np.concatenate([_host_sum(o) for o in outs], axis=0)[:g_n]
+
+
+def group_by_aggregate(  # dispatch-ok: caller holds dispatch_mutex
+    planes_list: Sequence,
+    row_lists: Sequence[Sequence[int]],
+    filt: Optional[jax.Array],
+    value,
+    depth: int,
+    signed: bool,
+    info: dict,
+) -> Dict[Tuple[int, ...], Tuple[int, int, int]]:
+    """GroupBy with `aggregate=Sum(field=)` on device.
+
+    planes_list, row_lists and filt as for `group_by_device`; `value` the
+    value field's uint32[2 + depth, S, W] stack (exists, sign, then the
+    magnitude planes, least significant first), whole or as parts that
+    line up with the dimensions'; `signed` whether a stored value can be
+    negative. Returns {(row0, row1, ...): (count, stored sum, values)}
+    with zero-count groups pruned: `count` the group's columns (under the
+    filter), `values` those of them that hold a value and `stored sum` the
+    exact integer sum of the stored (base-relative) values over them.
+    `info` gains levels / live_groups / planes / fold_ms.
+
+    Launches and blocking reads are O(levels): under a filter one tally
+    of each dimension against the filter; then the groups the surviving
+    rows make are counted with their planes in one launch — or, where
+    they are more than one launch lists, `group_by_device` finds the live
+    groups first and they alone are tallied with the planes. Work follows
+    the listed groups x planes, never the unpruned cross."""
+    from pilosa_tpu.ops import pallas_kernels
+
+    STATS["aggregate_queries"] += 1
+    info.update(levels=len(planes_list), planes=_shape(value)[0],
+                live_groups=0, fold_ms=0.0)
+    rows = [_shape(p)[0] for p in planes_list]
+    if not rows or not all(rows):
+        return {}
+    # the rows of each dimension a group can hold: those the filter leaves
+    live = [np.arange(r) for r in rows]
+    if filt is not None:
+        outs = [
+            group_tally([p], np.arange(r)[None], filt=filt)
+            for p, r in zip(planes_list, rows)
+        ]
+        live = [
+            np.nonzero(_read_groups(o, r)[:, 0])[0] for o, r in zip(outs, rows)
+        ]
+        if not all(len(x) for x in live):
+            return {}
+    n_cand = int(np.prod([len(x) for x in live], dtype=np.int64))
+    if n_cand <= pallas_kernels.GROUP_MAX_GROUPS:
+        grid = np.meshgrid(*live, indexing="ij")
+        idx = np.stack([g.ravel() for g in grid])
+    else:
+        counted = group_by_device(planes_list, row_lists, filt)
+        if not counted:
+            return {}
+        at = [{int(r): i for i, r in enumerate(rl)} for rl in row_lists]
+        idx = np.array(
+            [[at[d][k[d]] for k in sorted(counted)] for d in range(len(rows))]
+        )
+    g_n = idx.shape[1]
+    pos = group_tally(planes_list, idx, value, filt, _EXISTS_ROW)
+    neg = None
+    if signed:
+        neg = group_tally(planes_list, idx, value, filt, _SIGN_ROW)
+    pos = _read_groups(pos, g_n)
+    neg = None if neg is None else _read_groups(neg, g_n)
+
+    t0 = time.perf_counter()
+    keep = np.nonzero(pos[:, 0])[0]
+    weights = np.array([1 << p for p in range(depth)], dtype=object)
+    mags = pos[keep, 1 + _OFFSET_ROW : 1 + _OFFSET_ROW + depth].astype(object)
+    if neg is not None:
+        mags = mags - 2 * neg[
+            keep, 1 + _OFFSET_ROW : 1 + _OFFSET_ROW + depth
+        ].astype(object)
+    sums = (mags * weights).sum(axis=1) if depth else np.zeros(len(keep), object)
+    merged = {}
+    for n, g in enumerate(keep):
+        key = tuple(int(row_lists[d][idx[d, g]]) for d in range(len(rows)))
+        merged[key] = (
+            int(pos[g, 0]), int(sums[n]), int(pos[g, 1 + _EXISTS_ROW])
+        )
+    info.update(live_groups=len(merged),
+                fold_ms=round((time.perf_counter() - t0) * 1000.0, 3))
+    return merged

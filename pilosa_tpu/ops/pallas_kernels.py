@@ -1,4 +1,7 @@
-"""The one hand-written TPU kernel: the GroupBy cross tally.
+"""The hand-written TPU kernels, both GroupBy's: the cross tally
+(`cross_counts`) and, for a GroupBy that carries an aggregate, the tally
+of listed groups and their value planes (`group_counts`, at the end of
+this file).
 
 `cross_counts` (reference: the GroupBy tally, executor.go:3063) forms a
 G (x M) x R cross of AND + popcount over multi-GB `[rows, S, W]` stacks on
@@ -17,11 +20,11 @@ of the HBM roofline where the kernel reads 12.9 % (ledger PR 27,
 which XLA fuses and tiles by itself (Count: 88.6 % of the roofline on one
 chip, 85.0 % on the mesh; ledger PR 30), and has no kernel here.
 
-The kernel
-- operates on uint32 word arrays (bit b of word w = position 32w+b),
-- accumulates in int32 (wrap-compatible with the uint32 count convention
+Both kernels
+- operate on uint32 word arrays (bit b of word w = position 32w+b),
+- accumulate in int32 (wrap-compatible with the uint32 count convention
   in ops/bitmap.py),
-- compiles for the TPU only: nothing here chooses interpret mode. A test
+- compile for the TPU only: nothing here chooses interpret mode. A test
   that wants the interpreter asks for it around the call
   (`pltpu.force_tpu_interpret_mode()`); anywhere else a backend that
   cannot compile the kernel raises.
@@ -376,4 +379,187 @@ def cross_counts(  # dispatch-ok: wrapper; callers serialize (run_serialized)
         shard_major = (shard_major,) * len(planes)
     return _cross_counts_vmem(
         acc, planes, mid, filt, shard_major=tuple(shard_major)
+    )
+
+
+# ---------------------------------------------------------------------------
+# The group tally: counts of LISTED groups, and of their value planes
+# ---------------------------------------------------------------------------
+# `cross_counts` tallies a whole cross. A GroupBy that carries an aggregate
+# knows its live groups (or, under a filter, the few rows of each dimension
+# that the filter leaves) and owes each of them one count per BSI plane of
+# the value field: work that follows the listed groups, not the cross. Here
+# every dimension's rows, the filter and the planes come into VMEM once per
+# tile, all rows of a dimension as they are resident (no row is selected or
+# written in HBM), and a table of row indices in SMEM says which rows make
+# each group.
+_GROUP_ROWS_BYTES = 12 << 20  # one buffer of row tiles; the pipeline keeps two
+_GROUP_PARTS = 31  # plane partials carried beside the group's own count
+GROUP_MAX_GROUPS = 2048  # groups per launch: the index table lives in SMEM
+
+
+def group_words(rows_total: int, w: int) -> int:
+    """Words of a row per grid step: the most (a power of two dividing w,
+    at most 16384: every step ends with a lane reduction per count) that
+    lets one 8-shard tile of every row the tally reads fit its buffer; 0
+    when not even one lane column fits (too many rows for the kernel)."""
+    wt = math.gcd(w, 16384)
+    while wt >= _LANES and rows_total * _SUBLANES * wt * 4 > _GROUP_ROWS_BYTES:
+        wt //= 2
+    return wt if wt >= _LANES and w % wt == 0 else 0
+
+
+def _group_kernel(n_dims, p_n, q_pad, g_pad, has_filt, mask_row,
+                  idx_ref, live_ref, *refs):
+    """One grid step on [rows, 8 shards, wt words] blocks. For each listed
+    group g (a loop of `live` rounds, not unrolled): t = the AND of row
+    idx[d, g] of every dimension d (and the filter); lane g * q_pad of the
+    out block gains popcount(t), lane g * q_pad + 1 + p popcount(t & v &
+    planes[p]) with v = planes[mask_row] (no mask: v is left out). A vreg
+    holds the same 128 words of 8 shards of one row, as in the row-major
+    cross body."""
+    refs = list(refs)
+    dims = [refs.pop(0) for _ in range(n_dims)]
+    filt_ref = refs.pop(0) if has_filt else None
+    planes_ref = refs.pop(0) if p_n else None
+    (out_ref,) = refs
+
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        out_ref[:] = jnp.zeros_like(out_ref)
+
+    steps = dims[0].shape[-1] // _LANES
+    zero = jnp.zeros((_SUBLANES, _LANES), jnp.int32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, zero.shape, 1)
+    # counts of one group in slabs the carried partials hold: the group's
+    # own count rides in the first
+    qs = list(range(1 + p_n))
+    slabs = [qs[i : i + 1 + _GROUP_PARTS] for i in range(0, len(qs), 1 + _GROUP_PARTS)]
+
+    def group(g, carry):
+        rows = [idx_ref[d * g_pad + g] for d in range(n_dims)]
+        at = g * q_pad
+        for slab in slabs:
+
+            def body(j, parts, slab=slab):
+                sl = _lane_slice(j)
+                t = dims[0][rows[0], :, sl]
+                for ref, i in zip(dims[1:], rows[1:]):
+                    t = t & ref[i, :, sl]
+                if has_filt:
+                    t = t & filt_ref[:, sl]
+                tv = t if mask_row is None else t & planes_ref[mask_row, :, sl]
+                out = []
+                for k, q in enumerate(slab):
+                    x = t if q == 0 else tv & planes_ref[q - 1, :, sl]
+                    out.append(
+                        parts[k]
+                        + jax.lax.population_count(x.astype(jnp.int32))
+                    )
+                return tuple(out)
+
+            parts = _word_loop(steps, body, (zero,) * len(slab))
+            tile = zero
+            for k, q in enumerate(slab):
+                col = jnp.sum(parts[k], axis=1, keepdims=True)
+                tile = jnp.where(lane == at % _LANES + q, col, tile)
+            chunk = pl.multiple_of(at // _LANES * _LANES, _LANES)
+            out_ref[:, pl.ds(chunk, _LANES)] += tile
+        return carry
+
+    jax.lax.fori_loop(0, live_ref[0], group, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("mask_row",))
+def _group_counts_extent(dims, idx, live, planes, filt, mask_row):
+    """One `pallas_call` over one extent: dims a tuple of [R_d, S_e, W],
+    idx int32[n_dims, G] (G a power of two), live int32[1] (groups listed:
+    the rest of the table is padding and is not tallied), planes [P, S_e,
+    W] or None, filt [S_e, W] or None -> uint32[G, 1 + P, S_e]."""
+    n_dims, g_pad = idx.shape
+    _, s_n, w = dims[0].shape
+    p_n = 0 if planes is None else planes.shape[0]
+    rows_total = sum(d.shape[0] for d in dims) + p_n + (filt is not None)
+    wt = group_words(rows_total, w)
+    assert wt, f"{rows_total} rows of {w} words do not fit the group tally"
+    q_pad = 1 << p_n.bit_length()  # > p_n: the count and the planes
+    assert q_pad <= _LANES and g_pad <= GROUP_MAX_GROUPS
+    lanes = max(g_pad * q_pad, _LANES)
+    n_s = pl.cdiv(s_n, _SUBLANES)
+
+    def rows_spec(x):
+        return pl.BlockSpec(
+            (x.shape[0], _SUBLANES, wt), lambda s, j, *_: (0, s, j)
+        )
+
+    in_specs, args = [rows_spec(d) for d in dims], list(dims)
+    if filt is not None:
+        in_specs.append(pl.BlockSpec((_SUBLANES, wt), lambda s, j, *_: (s, j)))
+        args.append(filt)
+    if p_n:
+        in_specs.append(rows_spec(planes))
+        args.append(planes)
+    out = pl.pallas_call(
+        functools.partial(
+            _group_kernel, n_dims, p_n, q_pad, g_pad, filt is not None,
+            mask_row,
+        ),
+        out_shape=jax.ShapeDtypeStruct((n_s, _SUBLANES, lanes), jnp.int32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n_s, w // wt),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec(
+                (None, _SUBLANES, lanes), lambda s, j, *_: (s, 0, 0)
+            ),
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_CROSS_VMEM_BYTES,
+        ),
+        name="group_counts",
+    )(idx.reshape(-1), live, *[a.astype(jnp.uint32) for a in args])
+    # [S/8, 8, G * q_pad] -> [G, 1 + P, S]
+    out = out.reshape(n_s * _SUBLANES, lanes)[:s_n, : g_pad * q_pad]
+    out = out.reshape(s_n, g_pad, q_pad)[:, :, : 1 + p_n]
+    return out.transpose(1, 2, 0).astype(jnp.uint32)
+
+
+@functools.partial(jax.jit, static_argnames=("mask_row",))
+def _group_counts_vmem(dims, idx, live, planes, filt, mask_row):
+    """The jitted entry: every dimension (and planes) a TUPLE of per-extent
+    parts that line up span for span, filt [S, W] whole and sliced per
+    extent here; one `pallas_call` per extent inside the one program, the
+    per-shard counts concatenated."""
+    outs, lo = [], 0
+    for e in range(len(dims[0])):
+        hi = lo + dims[0][e].shape[1]
+        outs.append(
+            _group_counts_extent(
+                tuple(d[e] for d in dims), idx, live,
+                None if planes is None else planes[e],
+                None if filt is None else filt[lo:hi], mask_row,
+            )
+        )
+        lo = hi
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=-1)
+
+
+def group_counts(  # dispatch-ok: wrapper; callers serialize (run_serialized)
+    dims, idx, live, planes=None, filt=None, mask_row=None
+) -> jnp.ndarray:
+    """Counts of listed groups in one pass over the rows they name.
+
+    dims[d] uint32[R_d, S, W], one array or the tuple of its resident
+    per-extent parts; idx int32[n_dims, G] the row of each dimension that
+    makes group g, of which the first `live` (int32[1]) are tallied;
+    planes uint32[P, S, W] (or parts) the value field's planes; filt
+    uint32[S, W]. Returns uint32[G, 1 + P, S]: [g, 0] the per-shard count
+    of AND_d dims[d][idx[d, g]] (& filt), [g, 1 + p] that of the same &
+    planes[mask_row] & planes[p] (mask_row None: no mask). Every row is
+    read from HBM once per tally where it lies; nothing but the counts is
+    written."""
+    dims = tuple(_parts(d) for d in dims)
+    return _group_counts_vmem(
+        dims, idx, live, _parts(planes), filt, mask_row=mask_row
     )

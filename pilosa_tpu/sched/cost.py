@@ -184,12 +184,21 @@ def _call_rows(idx: Any, c: Call) -> float:
         rows += _bsi_planes(idx, fname)
     elif c.name in ("TopN", "GroupBy", "Rows"):
         rows += _TALLY_ROW_EQUIV
+        agg = c.args.get("aggregate")
+        if c.name == "GroupBy" and isinstance(agg, Call):
+            # aggregate=Sum(field=): the value field's exists, sign and
+            # magnitude planes are staged as ONE stack beside the
+            # dimensions (exec/executor.py _group_by_stacked), not in slabs
+            fname = agg.args.get("field")
+            f = idx.field(fname) if idx is not None and isinstance(fname, str) else None
+            depth = getattr(getattr(f, "options", None), "bit_depth", 0)
+            rows += (depth or _DEFAULT_BSI_PLANES) + 2
     elif c.name == "Not":
         rows += 1.0  # the existence stack
     for child in c.children:
         rows += _call_rows(idx, child)
-    for v in c.args.values():
-        if isinstance(v, Call):
+    for k, v in c.args.items():
+        if isinstance(v, Call) and (c.name, k) != ("GroupBy", "aggregate"):
             rows += _call_rows(idx, v)
     return rows
 

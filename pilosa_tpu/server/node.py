@@ -873,6 +873,18 @@ class NodeServer:
         self.stats.gauge(
             "groupby.assembled_stacks", groupby_mod.STATS["assembled_stacks"]
         )
+        # what those concatenations wrote, and the aggregate GroupBy's
+        # work: queries, and group x plane pairs counted
+        self.stats.gauge(
+            "groupby.assembled_bytes", groupby_mod.STATS["assembled_bytes"]
+        )
+        self.stats.gauge(
+            "groupby.aggregate_queries",
+            groupby_mod.STATS["aggregate_queries"],
+        )
+        self.stats.gauge(
+            "groupby.plane_tallies", groupby_mod.STATS["plane_tallies"]
+        )
         # the views' row summaries (core/view.py row_summary): hits over
         # hits + bypassed is the share of Rows / GroupBy-prefetch /
         # unfiltered-TopN reads the table served

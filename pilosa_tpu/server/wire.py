@@ -124,6 +124,8 @@ def encode_result(r: Any) -> Dict[str, Any]:
                             for fr in g.group
                         ],
                         "count": g.count,
+                        # present only under aggregate=Sum (exact int)
+                        **({} if g.sum is None else {"sum": g.sum}),
                     }
                     for g in r
                 ],
@@ -173,6 +175,7 @@ def decode_result(d: Dict[str, Any]) -> Any:
                     for fr in g["group"]
                 ],
                 count=int(g["count"]),
+                sum=None if g.get("sum") is None else int(g["sum"]),
             )
             for g in d["groups"]
         ]

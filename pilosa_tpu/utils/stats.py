@@ -104,6 +104,13 @@ STAT_NAMES = frozenset(
         # pruned descent, more than three levels)
         "groupby.inplace_tallies",
         "groupby.assembled_stacks",
+        # bytes those concatenations wrote (it moves wherever
+        # assembled_stacks does); GroupBys that carried aggregate=Sum and
+        # took the device path, and the group x plane pairs their tallies
+        # counted (exec/groupby.py group_by_aggregate)
+        "groupby.assembled_bytes",
+        "groupby.aggregate_queries",
+        "groupby.plane_tallies",
         # per-view row summary (core/view.py row_summary, counted in the
         # process registry and published at scrape time): readers that
         # found the table under the view's current mutation clock, tables

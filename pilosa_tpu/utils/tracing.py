@@ -113,7 +113,11 @@ SPAN_NAMES = frozenset(
         # plan.family / plan.program (the jitted program as the
         # profiler's "XLA Modules" line names it) / dispatch.compiled /
         # mesh.devices (devices the program's operands span, 1 on a
-        # single device) and, above 1, mesh.axes ("shards=2,cols=2")
+        # single device) and, above 1, mesh.axes ("shards=2,cols=2"); on
+        # a GroupBy's (exec/executor.py _tag_group_by) groupby.levels /
+        # live_groups / planes (0 without aggregate=) / tallies
+        # (launches) / fold_ms (host time from the last read to the
+        # finished groups)
         "exec.dispatch",
         # a whole distributed fan-out incl. re-map rounds
         # (exec/distributed.py)

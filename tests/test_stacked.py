@@ -476,6 +476,7 @@ class TestStackedGroupBy:
         assert qgb.STATS == {
             "evals": launches, "kernel_tallies": 1, "xla_tallies": 0,
             "inplace_tallies": 0, "assembled_stacks": 0,  # one extent
+            "assembled_bytes": 0, "aggregate_queries": 0, "plane_tallies": 0,
         }
 
     def test_kernel_replaces_the_xla_tally_inside_the_descent(
@@ -549,6 +550,7 @@ class TestStackedGroupBy:
         assert qgb.STATS == {
             "evals": 1, "kernel_tallies": 1, "xla_tallies": 0,
             "inplace_tallies": 1, "assembled_stacks": 0,
+            "assembled_bytes": 0, "aggregate_queries": 0, "plane_tallies": 0,
         }
 
     @pytest.mark.parametrize(

@@ -97,3 +97,44 @@ def test_cross_counts_compiles_for_v5e_without_copying_its_stacks(
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= len(parts)
     assert compiled.memory_analysis().temp_size_in_bytes <= copied * 1.01
+
+
+SSB_EXTENTS = (256, 256, 61)  # 573 shards: ssb-sf100
+
+# name: (rows of each dimension, table length, planes, mask row)
+_GROUP_CASES = {
+    # ssb-sf100.flights-q3-q4: each dimension against the filter, then the
+    # groups the surviving rows make with the value field's planes
+    "ssb_prune_25": ((25,), 32, 0, None),
+    "ssb_prune_7": ((7,), 8, 0, None),
+    "ssb_q31": ((25, 25, 7), 256, 26, 0),
+    "ssb_q41_cost": ((7, 25), 64, 19, 0),
+    "ssb_q42": ((7, 25, 25), 128, 26, 0),
+    # a signed field's second pass, and the deepest field there is
+    "signed": ((25, 25, 7), 256, 26, 1),
+    "deep_34": ((25,), 32, 34, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_GROUP_CASES))
+def test_group_counts_compiles_for_v5e_without_copying_its_stacks(
+    one_chip, case
+):
+    """The aggregate GroupBy's tally at the SSB cell's shapes: every
+    dimension, the planes and the filter read where the three extents lie
+    (row-major: none of these row counts is 1, 2, 4 or 8k)."""
+    dims, g, planes, mask_row = _GROUP_CASES[case]
+
+    def stack(rows):
+        return tuple(_stack(one_chip, rows, s) for s in SSB_EXTENTS)
+
+    compiled = pk._group_counts_vmem.lower(
+        tuple(stack(r) for r in dims),
+        jax.ShapeDtypeStruct((len(dims), g), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip),
+        stack(planes) if planes else None,
+        _stack(one_chip, 0, sum(SSB_EXTENTS)),
+        mask_row=mask_row,
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= len(SSB_EXTENTS)
+    assert compiled.memory_analysis().temp_size_in_bytes <= 1 << 20
