@@ -636,7 +636,8 @@ def _tag_group_by(launches: int, info: dict) -> None:
     """What one GroupBy's tallies did, on its exec.dispatch span (the
     caller is inside it and holds the dispatch mutex, so a delta of
     groupby.STATS is this query's): groupby.levels / live_groups /
-    planes / fold_ms from `info`, groupby.tallies the launches."""
+    planes / group_block / fold_ms from `info`, groupby.tallies the
+    launches."""
     sp = tracing.active_span()
     if sp is None:
         return

@@ -447,6 +447,17 @@ def group_program(dims, planes=None, filt=None) -> str:
     return GROUP_XLA_PROGRAM
 
 
+def group_block(dims, planes=None, filt=None) -> int:
+    """Groups the tally of these operands counts against each plane tile
+    it loads: the kernel's block, 1 for the XLA program."""
+    from pilosa_tpu.ops import pallas_kernels
+
+    if not _group_kernel_covers(dims, planes, filt):
+        return 1
+    p_n = 0 if planes is None else _shape(planes)[0]
+    return pallas_kernels.group_block(len(dims), p_n)[0]
+
+
 def group_tally(dims, idx: np.ndarray, planes=None, filt=None, mask_row=None):  # dispatch-ok: caller holds dispatch_mutex
     """Per-shard counts of the groups `idx` int[n_dims, G] lists (row
     idx[d, g] of dimension d makes group g), and of each with every plane
@@ -511,7 +522,8 @@ def group_by_aggregate(  # dispatch-ok: caller holds dispatch_mutex
     with zero-count groups pruned: `count` the group's columns (under the
     filter), `values` those of them that hold a value and `stored sum` the
     exact integer sum of the stored (base-relative) values over them.
-    `info` gains levels / live_groups / planes / fold_ms.
+    `info` gains levels / live_groups / planes / group_block (groups the
+    tally counts against each plane tile it loads) / fold_ms.
 
     Launches and blocking reads are O(levels): under a filter one tally
     of each dimension against the filter; then the groups the surviving
@@ -523,7 +535,7 @@ def group_by_aggregate(  # dispatch-ok: caller holds dispatch_mutex
 
     STATS["aggregate_queries"] += 1
     info.update(levels=len(planes_list), planes=_shape(value)[0],
-                live_groups=0, fold_ms=0.0)
+                live_groups=0, group_block=0, fold_ms=0.0)
     rows = [_shape(p)[0] for p in planes_list]
     if not rows or not all(rows):
         return {}
@@ -552,6 +564,7 @@ def group_by_aggregate(  # dispatch-ok: caller holds dispatch_mutex
             [[at[d][k[d]] for k in sorted(counted)] for d in range(len(rows))]
         )
     g_n = idx.shape[1]
+    info["group_block"] = group_block(planes_list, value, filt)
     pos = group_tally(planes_list, idx, value, filt, _EXISTS_ROW)
     neg = None
     if signed:

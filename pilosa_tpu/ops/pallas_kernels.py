@@ -394,8 +394,17 @@ def cross_counts(  # dispatch-ok: wrapper; callers serialize (run_serialized)
 # written in HBM), and a table of row indices in SMEM says which rows make
 # each group.
 _GROUP_ROWS_BYTES = 12 << 20  # one buffer of row tiles; the pipeline keeps two
-_GROUP_PARTS = 31  # plane partials carried beside the group's own count
 GROUP_MAX_GROUPS = 2048  # groups per launch: the index table lives in SMEM
+# A block's row indices are held in scalar registers through its word loop
+# (32 of them, the loop's own addresses among them).
+_GROUP_ROW_SCALARS = 16
+# 128-word columns a round of the word loop covers: its unrolled bodies,
+# each row loaded as ONE tile of that width, so that the address of a load
+# is worked out once for 8 vregs. Loaded one vreg at a time, each load
+# spent three scalar ops on its address and two scalar slots a bundle bound
+# the loop (2.0 bundles a pair where its VALU work needs 1.2; v5e bundle
+# dump, PR 38); a tile of 8 leaves it VALU-bound (0.88 a pair).
+_GROUP_COLS = 8
 
 
 def group_words(rows_total: int, w: int) -> int:
@@ -409,15 +418,39 @@ def group_words(rows_total: int, w: int) -> int:
     return wt if wt >= _LANES and w % wt == 0 else 0
 
 
+def group_block(n_dims: int, p_n: int) -> tuple:
+    """(gb, pb): the group tally takes its groups gb at a time, each plane
+    tile loaded once for the gb of them, and carries pb counts of each
+    group (its own and up to pb - 1 planes) through a word loop: gb x pb
+    partials within the cross kernel's budget, the block's row indices
+    within `_GROUP_ROW_SCALARS`, its counts within one 128-lane chunk of
+    the out block. The (1 + p_n) counts are cut into slabs of equal size.
+    A deep field's tally keeps one group a block: with 8-column tiles its
+    loop is bound by VALU work (AND, popcount, add a pair), which a block
+    does not share, and 2 or 4 groups of 27 counts in slabs of 14 or 7
+    were 3-24 % slower than one (my chip run, PR 38)."""
+    q = 1 + p_n
+    q_pad = 1 << p_n.bit_length()
+    gb = 1
+    while (2 * gb * q <= _CROSS_PARTS and 2 * gb * n_dims <= _GROUP_ROW_SCALARS
+           and 2 * gb * q_pad <= _LANES):
+        gb *= 2
+    slabs = pl.cdiv(q, max(1, _CROSS_PARTS // gb))
+    return gb, pl.cdiv(q, slabs)
+
+
 def _group_kernel(n_dims, p_n, q_pad, g_pad, has_filt, mask_row,
                   idx_ref, live_ref, *refs):
-    """One grid step on [rows, 8 shards, wt words] blocks. For each listed
-    group g (a loop of `live` rounds, not unrolled): t = the AND of row
-    idx[d, g] of every dimension d (and the filter); lane g * q_pad of the
-    out block gains popcount(t), lane g * q_pad + 1 + p popcount(t & v &
-    planes[p]) with v = planes[mask_row] (no mask: v is left out). A vreg
-    holds the same 128 words of 8 shards of one row, as in the row-major
-    cross body."""
+    """One grid step on [rows, 8 shards, wt words] blocks. The listed
+    groups are taken gb at a time (a loop of cdiv(live, gb) rounds, not
+    unrolled; `group_block`). For group g, t = the AND of row idx[d, g] of
+    every dimension d (and the filter); lane g * q_pad of the out block
+    gains popcount(t), lane g * q_pad + 1 + p popcount(t & v & planes[p])
+    with v = planes[mask_row] (no mask: v is left out). Within a block the
+    filter, the mask and every plane tile are loaded once for its gb
+    groups. A vreg holds the same 128 words of 8 shards of one row, as in
+    the row-major cross body. A ragged last block tallies its last group
+    again and does not write it."""
     refs = list(refs)
     dims = [refs.pop(0) for _ in range(n_dims)]
     filt_ref = refs.pop(0) if has_filt else None
@@ -431,43 +464,67 @@ def _group_kernel(n_dims, p_n, q_pad, g_pad, has_filt, mask_row,
     steps = dims[0].shape[-1] // _LANES
     zero = jnp.zeros((_SUBLANES, _LANES), jnp.int32)
     lane = jax.lax.broadcasted_iota(jnp.int32, zero.shape, 1)
-    # counts of one group in slabs the carried partials hold: the group's
-    # own count rides in the first
+    gb, pb = group_block(n_dims, p_n)
     qs = list(range(1 + p_n))
-    slabs = [qs[i : i + 1 + _GROUP_PARTS] for i in range(0, len(qs), 1 + _GROUP_PARTS)]
+    slabs = [qs[i : i + pb] for i in range(0, len(qs), pb)]
+    cols = min(steps, _GROUP_COLS)  # 128-word columns of one load
+    width = cols * _LANES
+    live = live_ref[0]
 
-    def group(g, carry):
-        rows = [idx_ref[d * g_pad + g] for d in range(n_dims)]
-        at = g * q_pad
+    def block(b, carry):
+        g0 = b * gb
+        rows = [
+            [idx_ref[d * g_pad + jnp.minimum(g0 + n, live - 1)]
+             for d in range(n_dims)]
+            for n in range(gb)
+        ]
+        at = g0 * q_pad  # the block's counts: lanes [at, at + gb * q_pad)
         for slab in slabs:
 
             def body(j, parts, slab=slab):
-                sl = _lane_slice(j)
-                t = dims[0][rows[0], :, sl]
-                for ref, i in zip(dims[1:], rows[1:]):
-                    t = t & ref[i, :, sl]
-                if has_filt:
-                    t = t & filt_ref[:, sl]
-                tv = t if mask_row is None else t & planes_ref[mask_row, :, sl]
-                out = []
+                sl = pl.ds(pl.multiple_of(j * width, width), width)
+                f = filt_ref[:, sl] if has_filt else None
+                v = None
+                if mask_row is not None and slab[-1]:
+                    v = planes_ref[mask_row, :, sl]
+                out = list(parts)
+
+                def add(n, k, x):
+                    c = jax.lax.population_count(x.astype(jnp.int32))
+                    for u in range(cols):
+                        out[n * len(slab) + k] += c[:, u * _LANES : (u + 1) * _LANES]
+
+                tvs = []
+                for n, r in enumerate(rows):
+                    t = dims[0][r[0], :, sl]
+                    for ref, i in zip(dims[1:], r[1:]):
+                        t = t & ref[i, :, sl]
+                    if f is not None:
+                        t = t & f
+                    if slab[0] == 0:
+                        add(n, 0, t)
+                    if slab[-1]:
+                        tvs.append(t if v is None else t & v)
                 for k, q in enumerate(slab):
-                    x = t if q == 0 else tv & planes_ref[q - 1, :, sl]
-                    out.append(
-                        parts[k]
-                        + jax.lax.population_count(x.astype(jnp.int32))
-                    )
+                    if q:
+                        p = planes_ref[q - 1, :, sl]
+                        for n, tv in enumerate(tvs):
+                            add(n, k, tv & p)
                 return tuple(out)
 
-            parts = _word_loop(steps, body, (zero,) * len(slab))
+            parts = jax.lax.fori_loop(
+                0, steps // cols, body, (zero,) * (gb * len(slab)))
             tile = zero
-            for k, q in enumerate(slab):
-                col = jnp.sum(parts[k], axis=1, keepdims=True)
-                tile = jnp.where(lane == at % _LANES + q, col, tile)
+            for n in range(gb):
+                for k, q in enumerate(slab):
+                    col = jnp.sum(parts[n * len(slab) + k], axis=1, keepdims=True)
+                    to = jnp.where(g0 + n < live, at % _LANES + n * q_pad + q, -1)
+                    tile = jnp.where(lane == to, col, tile)
             chunk = pl.multiple_of(at // _LANES * _LANES, _LANES)
             out_ref[:, pl.ds(chunk, _LANES)] += tile
         return carry
 
-    jax.lax.fori_loop(0, live_ref[0], group, 0)
+    jax.lax.fori_loop(0, pl.cdiv(live, gb), block, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("mask_row",))

@@ -115,9 +115,10 @@ SPAN_NAMES = frozenset(
         # mesh.devices (devices the program's operands span, 1 on a
         # single device) and, above 1, mesh.axes ("shards=2,cols=2"); on
         # a GroupBy's (exec/executor.py _tag_group_by) groupby.levels /
-        # live_groups / planes (0 without aggregate=) / tallies
-        # (launches) / fold_ms (host time from the last read to the
-        # finished groups)
+        # live_groups / planes (0 without aggregate=) / group_block
+        # (aggregate only: groups tallied per loaded plane tile) /
+        # tallies (launches) / fold_ms (host time from the last read to
+        # the finished groups)
         "exec.dispatch",
         # a whole distributed fan-out incl. re-map rounds
         # (exec/distributed.py)

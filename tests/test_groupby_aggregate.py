@@ -392,39 +392,115 @@ def test_a_malformed_aggregate_is_refused_by_name(node, pql, message):
     assert message in e.value.read().decode()
 
 
-def test_group_counts_kernel_equals_its_xla_oracle(interpreted):
+# the oracle's cases: (rows of each dimension, planes, mask row, filtered,
+# live groups; "gb" the kernel's block for these dimensions and planes)
+_ORACLE_CASES = {
+    # PR 37's three cases
+    "p11_mask0": ((5, 7, 3), 11, 0, True, 13),
+    "p40_mask1": ((5, 7, 3), 40, 1, True, 16),
+    "count_only": ((5, 7, 3), 0, None, True, 9),
+    # a prune launch: one dimension against the filter, a block of groups
+    **{f"prune_{n}": ((7,), 0, None, True, n)
+       for n in ("1", "gb-1", "gb", "gb+1", "full")},
+    "count_only_3dims_gb-1": ((5, 7, 3), 0, None, False, "gb-1"),
+    "count_only_3dims_gb+1": ((5, 7, 3), 0, None, False, "gb+1"),
+    **{f"p1_{n}": ((7,), 1, 0, False, n)
+       for n in ("1", "gb-1", "gb", "gb+1", "full")},
+    # the SSB cell's tallies: cost (19 planes) and revenue (26)
+    "p19_gb+1": ((7, 5), 19, 0, True, "gb+1"),
+    "p19_full": ((7, 5), 19, None, True, "full"),
+    "p26_1": ((5, 7, 3), 26, 0, True, "1"),
+    "p26_gb+1": ((5, 7, 3), 26, 1, False, "gb+1"),
+    # deeper than one slab of partials
+    "p34_gb": ((5,), 34, 0, True, "gb"),
+    "p34_full": ((5,), 34, None, False, "full"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_ORACLE_CASES))
+def test_group_counts_kernel_equals_its_xla_oracle(interpreted, case):
     """The VMEM group tally (interpreted here) against `_counts_groups` on
-    random words: parts in place, a table longer than its live groups, a
-    mask row, more planes than one slab of partials."""
+    random words: parts of unequal spans read in place, a table longer
+    than its live groups, ragged and whole blocks of groups, a mask row,
+    more planes than one slab of partials, several rounds of the word
+    loop. Groups past `live` read 0."""
     import jax.numpy as jnp
 
     from pilosa_tpu.ops import pallas_kernels as pk
 
+    rows, n_planes, mask_row, filtered, live = _ORACLE_CASES[case]
+    gb = pk.group_block(len(rows), n_planes)[0]
+    g = max(16, 1 << gb.bit_length())  # the table: a power of two > gb
+    live = {"1": 1, "gb-1": max(1, gb - 1), "gb": gb, "gb+1": gb + 1,
+            "full": g}.get(live, live)
     rng = np.random.default_rng(5)
-    spans, w = (8, 8, 5), 256
+    spans, w = (8, 8, 5), 2048
 
-    def stack(rows):
-        return rng.integers(0, 2**32, (rows, sum(spans), w), dtype=np.uint32)
+    def stack(n):
+        return rng.integers(0, 2**32, (n, sum(spans), w), dtype=np.uint32)
 
     def parts(x):
         cuts = np.cumsum(spans)[:-1]
         return tuple(jnp.asarray(p) for p in np.split(x, cuts, axis=1))
 
-    dims = [stack(5), stack(7), stack(3)]
-    filt = stack(1)[0]
-    idx = np.stack([rng.integers(0, len(d), 16) for d in dims]).astype(np.int32)
-    for n_planes, mask_row, live in ((11, 0, 13), (40, 1, 16), (0, None, 9)):
-        planes = stack(n_planes) if n_planes else None
-        got = np.asarray(pk.group_counts(
-            [parts(d) for d in dims], idx, np.array([live], np.int32),
-            None if planes is None else parts(planes), jnp.asarray(filt),
-            mask_row,
-        ))
-        want = np.asarray(qgb._counts_groups(
-            tuple(jnp.asarray(d) for d in dims), idx,
-            None if planes is None else jnp.asarray(planes),
-            jnp.asarray(filt), mask_row,
-        ))
-        assert got.shape == want.shape == (16, 1 + n_planes, sum(spans))
-        assert (got[:live] == want[:live]).all()
-        assert not got[live:].any()  # the padding is not tallied
+    dims = [stack(r) for r in rows]
+    filt = stack(1)[0] if filtered else None
+    planes = stack(n_planes) if n_planes else None
+    idx = np.stack([rng.integers(0, len(d), g) for d in dims]).astype(np.int32)
+    got = np.asarray(pk.group_counts(
+        [parts(d) for d in dims], idx, np.array([live], np.int32),
+        None if planes is None else parts(planes),
+        None if filt is None else jnp.asarray(filt), mask_row,
+    ))
+    want = np.asarray(qgb._counts_groups(
+        tuple(jnp.asarray(d) for d in dims), idx,
+        None if planes is None else jnp.asarray(planes),
+        None if filt is None else jnp.asarray(filt), mask_row,
+    ))
+    assert got.shape == want.shape == (g, 1 + n_planes, sum(spans))
+    assert (got[:live] == want[:live]).all()
+    assert not got[live:].any()  # the padding is not tallied
+
+
+def _groupby_dispatch_tags(uri: str, pql: str) -> list:
+    """The tags of every GroupBy `exec.dispatch` span of one served query."""
+    req = urllib.request.Request(
+        f"{uri}/index/star/query?profile=1", data=pql.encode(), method="POST")
+    with urllib.request.urlopen(req, timeout=120) as resp:
+        profile = json.loads(resp.read())["profile"]
+    found, todo = [], list(profile["roots"])
+    while todo:
+        span = todo.pop()
+        todo.extend(span.get("children", []))
+        if span["name"] == "exec.dispatch" and "groupby.levels" in span["tags"]:
+            found.append(span["tags"])
+    return found
+
+
+@pytest.mark.parametrize("aggregate", [True, False])
+def test_an_aggregate_groupbys_dispatch_tags_its_group_block(node, aggregate):
+    """`groupby.group_block` (groups tallied per loaded plane tile; 1 for
+    the XLA program a CPU node runs) rides on an aggregate GroupBy's
+    `exec.dispatch` and on no count-only one."""
+    pql = "GroupBy(Rows(a), Rows(b), filter=Row(d=1)"
+    pql += ", aggregate=Sum(field=v))" if aggregate else ")"
+    (tags,) = _groupby_dispatch_tags(node.node.uri, pql)
+    if aggregate:
+        assert tags["groupby.group_block"] >= 1
+        assert tags["groupby.planes"] > 0
+    else:
+        assert tags.get("groupby.group_block", 0) == 0
+
+
+def test_group_block_is_the_kernels_block_where_it_covers(monkeypatch):
+    import jax.numpy as jnp
+
+    from pilosa_tpu.ops import pallas_kernels as pk
+
+    dims = [jnp.zeros((7, 8, 256), jnp.uint32)]
+    planes = jnp.zeros((2, 8, 256), jnp.uint32)
+    assert qgb.group_block(dims, planes) == 1  # the XLA program on a CPU
+    monkeypatch.setattr(qgb, "_kernel_covers", lambda *stacks: True)
+    for p in (None, planes):
+        want = pk.group_block(1, 0 if p is None else 2)[0]
+        assert qgb.group_block(dims, p) == want > 1

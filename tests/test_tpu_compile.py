@@ -113,6 +113,9 @@ _GROUP_CASES = {
     # a signed field's second pass, and the deepest field there is
     "signed": ((25, 25, 7), 256, 26, 1),
     "deep_34": ((25,), 32, 34, 0),
+    # blocks of several groups: 4 (three dimensions' row indices) and 16
+    "count_only_3dims": ((25, 25, 7), 256, 0, None),
+    "one_plane": ((25,), 32, 1, 0),
 }
 
 
