@@ -24,7 +24,10 @@ Rules (codes):
   slow-query log key on these names; an undeclared span is a stage
   nothing can attribute.
 * API007 — a declared SPAN_NAMES entry no module starts: stale
-  registry (same contract as API002 for STAT_NAMES).
+  registry (same contract as API002 for STAT_NAMES). A
+  `TraceAnnotation("name", ...)` with a literal name starts one too,
+  in utils/tracing.py itself as well: the profiler-clock-only names
+  (`runtime.gc`, `node.tick`) are annotations and never spans.
 * API009 — a config knob no module ever reads at runtime: the field
   name never appears as an attribute read outside `cli/config.py`
   itself (flag tables and argparse strings don't count — only a real
@@ -55,6 +58,8 @@ _EMIT_METHODS = {"count", "gauge", "histogram", "timing", "set_value", "timer"}
 # span-starting callables (methods on a tracer, or the module-level
 # helpers in utils/tracing.py that route to the active trace's tracer)
 _SPAN_METHODS = {"start_span", "start_span_from_headers", "record_span"}
+# a profiler annotation under a literal name (API006/API007 as a span)
+_ANNOTATION = "TraceAnnotation"
 
 # server flags that intentionally do NOT map to config knobs
 _NON_KNOB_FLAGS = {
@@ -355,8 +360,13 @@ class ApiInvariantsPass(Pass):
         names, names_line = self._declared_spans(tracing_mod)
         started: Set[str] = set()
         for m in modules:
-            if m.rel == tracing_mod.rel:
-                continue  # the tracer plumbing itself, not start sites
+            # the tracer plumbing itself holds no start sites, but its
+            # annotations under a literal name count
+            methods = (
+                {_ANNOTATION}
+                if m.rel == tracing_mod.rel
+                else _SPAN_METHODS | {_ANNOTATION}
+            )
             for node in ast.walk(m.tree):
                 if not isinstance(node, ast.Call) or not node.args:
                     continue
@@ -364,10 +374,10 @@ class ApiInvariantsPass(Pass):
                 # tracer.start_span("x") / tracing.record_span("x", ...)
                 # method style, or a from-imported bare call
                 if isinstance(fn, ast.Attribute):
-                    if fn.attr not in _SPAN_METHODS:
+                    if fn.attr not in methods:
                         continue
                 elif isinstance(fn, ast.Name):
-                    if fn.id not in _SPAN_METHODS:
+                    if fn.id not in methods:
                         continue
                 else:
                     continue

@@ -29,10 +29,12 @@ already accounts for.
 from __future__ import annotations
 
 import math
+import time
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Sequence, Set
 
 from pilosa_tpu.pql import Call, Query
+from pilosa_tpu.utils import tracing
 
 # Row-stack equivalents charged for rank/tally calls (TopN, GroupBy):
 # they tile over the field's rows in bounded chunks rather than stacking
@@ -455,7 +457,15 @@ def estimate(
                 # staged-delta surcharge: this query's read barrier will
                 # merge the fields' pending ingest delta (device keys at
                 # 8 bytes/position) before it can dispatch
+                t_walk = time.perf_counter()
                 peak += staged_merge_bytes(idx, touched)
+                # the walk's share of the api.admit span's estimate
+                span = tracing.active_span()
+                if span is not None:
+                    span.set_tag(
+                        "admit.merge_walk_ms",
+                        round((time.perf_counter() - t_walk) * 1000.0, 3),
+                    )
         t_ms = _transport_estimate(calls, transport) if transport else 0.0
         return QueryCost(
             device_bytes=peak, sweeps=sweeps, write=write, transport_ms=t_ms

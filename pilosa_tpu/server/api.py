@@ -62,6 +62,22 @@ def _pql_family(query) -> str:
     return ",".join(dict.fromkeys(c.name for c in query.calls))
 
 
+def _tag_admit(estimate_s: float, grant_s: float) -> None:
+    """api.admit part by part, as tags and not child spans, so its self
+    time stays what it was: the whole cost estimate (of which
+    sched/cost.py tagged the fragment walk, admit.merge_walk_ms) and the
+    grant less its queue wait (that is the sched.admit child). What is
+    left of api.admit's self time is _admit's own glue."""
+    from pilosa_tpu.utils import tracing
+
+    span = tracing.active_span()
+    if span is None:
+        return
+    span.set_tag("admit.estimate_ms", round(estimate_s * 1000.0, 3))
+    span.tags.setdefault("admit.merge_walk_ms", 0.0)
+    span.set_tag("admit.grant_ms", round(max(0.0, grant_s) * 1000.0, 3))
+
+
 def _validate_view_name(view: str) -> None:
     """View names become path components (view.go naming: standard,
     standard_YYYYMMDDHH, bsig_<field>); anything else is rejected so
@@ -384,6 +400,8 @@ class API:
         scheduler = getattr(self.server, "scheduler", None)
         if scheduler is None:
             return None
+        import time as _time
+
         from pilosa_tpu.sched import admission as admod
         from pilosa_tpu.sched import cost as costmod
 
@@ -435,9 +453,11 @@ class API:
                 shard_count = max(
                     shard_count or 1, transport["device_shards"]
                 )
+        t_estimate = _time.perf_counter()
         qcost = costmod.estimate(
             idx, query, shards, shard_count=shard_count, transport=transport
         )
+        t_estimated = _time.perf_counter()
         from pilosa_tpu.exec import batcher as batchmod
 
         # only batcher-eligible traffic feeds the adaptive-batching hint
@@ -462,7 +482,8 @@ class API:
                 lambda: self.server.executor.warm(index, warm_q, shards),
                 index=index,
             )
-        return scheduler.admit(
+        t_grant = _time.perf_counter()
+        ticket = scheduler.admit(
             cls=cls,
             cost=qcost,
             deadline=deadline,
@@ -474,6 +495,15 @@ class API:
             # hold-and-wait until every deadline expired
             leg=remote,
         )
+        try:
+            _tag_admit(
+                t_estimated - t_estimate,
+                _time.perf_counter() - t_grant - ticket.waited,
+            )
+        except BaseException:  # a granted ticket is released on every path
+            ticket.release()
+            raise
+        return ticket
 
     def _query_batched(self, index, query, shards, opt):
         """Route pure-Count requests through the group-commit batcher

@@ -26,6 +26,7 @@ import time
 import traceback
 from typing import Dict, List, Optional, Sequence
 
+from pilosa_tpu.utils import tracing
 from pilosa_tpu.utils.locks import TrackedLock
 
 from pilosa_tpu.cluster.topology import (
@@ -376,15 +377,13 @@ class NodeServer:
         self.topology_restored = False
         self.long_query_time = long_query_time
         self.metric_poll_interval = metric_poll_interval
-        from pilosa_tpu.utils import tracing as tracingmod
-
         # per-NODE tracer ring (not the process global): in-process
         # multi-node harnesses must exercise REAL cross-node propagation
         # and piggyback assembly, which a shared ring would fake. With
         # tracing disabled, root spans never sample — but an incoming
         # trace header (the sender sampled) and profile=true still record,
         # so the flight recorder works on demand even at sample-rate 0.
-        self.tracer = tracingmod.Tracer(
+        self.tracer = tracing.Tracer(
             keep=trace_ring,
             sample_rate=trace_sample_rate if tracing_enabled else 0.0,
             node=node_id,
@@ -720,10 +719,11 @@ class NodeServer:
         publishes (one wire payload per grant per tick), expire dead
         mirrors, and wake subscription refreshes."""
         while not self._closing.wait(self.coherence_tick_interval):
-            try:
-                self.coherence.tick()
-            except Exception as e:  # noqa: BLE001 - keep the ticker alive
-                self._ticker_error("coherence", e)
+            with tracing.tick("coherence"):
+                try:
+                    self.coherence.tick()
+                except Exception as e:  # noqa: BLE001 - keep the ticker alive
+                    self._ticker_error("coherence", e)
 
     def _coherence_exec(self, index: str, query: str):
         """Subscription (re)compute: through normal admission in the
@@ -746,20 +746,22 @@ class NodeServer:
         the object store, warm fragments shed device residency, and
         budget pressure demotes LRU until local bytes fit."""
         while not self._closing.wait(self.tier_demote_interval):
-            try:
-                self.tier.demote_tick()
-            except Exception as e:  # noqa: BLE001 - keep the ticker alive
-                self._ticker_error("tier-demote", e)
+            with tracing.tick("tier-demote"):
+                try:
+                    self.tier.demote_tick()
+                except Exception as e:  # noqa: BLE001 - keep the ticker alive
+                    self._ticker_error("tier-demote", e)
 
     def _telemetry_loop(self) -> None:
         """Always-on utilization timeline ticker: refresh residency
         gauges (statsd backends see them without an HTTP scrape) and
         append one sample to the /debug/timeline ring per interval."""
         while not self._closing.wait(self.telemetry_sample_interval):
-            try:
-                self.telemetry.sampler.sample_once()
-            except Exception as e:  # noqa: BLE001 - keep the ticker alive
-                self._ticker_error("telemetry", e)
+            with tracing.tick("telemetry"):
+                try:
+                    self.telemetry.sampler.sample_once()
+                except Exception as e:  # noqa: BLE001 - keep the ticker alive
+                    self._ticker_error("telemetry", e)
 
     def publish_cache_gauges(self) -> None:
         """Refresh device-cache residency gauges at scrape time (the
@@ -856,6 +858,20 @@ class NodeServer:
             "exec.compile_cache_hits",
             PROCESS.total_counter("exec.compile_cache_hits"),
         )
+        # process-wide stops (utils/tracing.py): collector passes and
+        # their pauses, all generations and full (generation 2) alone,
+        # and each background loop's passes and wall time, every ticker
+        # listed from process start
+        gct = tracing.gc_totals()
+        self.stats.gauge("runtime.gc_passes", gct["passes"])
+        self.stats.gauge("runtime.gc_pause_ms", gct["pause_ms"])
+        self.stats.gauge("runtime.gc_full_passes", gct["full_passes"])
+        self.stats.gauge("runtime.gc_full_pause_ms", gct["full_pause_ms"])
+        for ticker, (n, ms) in tracing.tick_totals().items():
+            self.stats.with_tags(f"ticker:{ticker}").gauge("runtime.ticks", n)
+            self.stats.with_tags(f"ticker:{ticker}").gauge(
+                "runtime.tick_ms", ms
+            )
         # which program tallied the GroupBy / filtered-TopN crosses
         # (exec/groupby.py cross_tally): the VMEM kernel or the XLA loop;
         # and whether a view of several extents was read where it lies
@@ -1144,25 +1160,27 @@ class NodeServer:
         import resource
 
         while not self._closing.wait(self.metric_poll_interval):
-            try:
-                usage = resource.getrusage(resource.RUSAGE_SELF)
-                self.stats.gauge("runtime.max_rss_kb", usage.ru_maxrss)
-                self.stats.gauge("runtime.threads", threading.active_count())
+            with tracing.tick("runtime-poll"):
                 try:
-                    self.stats.gauge("runtime.open_files", len(os.listdir("/proc/self/fd")))
-                except OSError:
-                    pass
-            except Exception as e:  # noqa: BLE001 - keep the ticker alive
-                self._ticker_error("runtime-poll", e)
+                    usage = resource.getrusage(resource.RUSAGE_SELF)
+                    self.stats.gauge("runtime.max_rss_kb", usage.ru_maxrss)
+                    self.stats.gauge("runtime.threads", threading.active_count())
+                    try:
+                        self.stats.gauge("runtime.open_files", len(os.listdir("/proc/self/fd")))
+                    except OSError:
+                        pass
+                except Exception as e:  # noqa: BLE001 - keep the ticker alive
+                    self._ticker_error("runtime-poll", e)
 
     def _cache_flush_loop(self) -> None:
         """Persist rank caches periodically (reference: holder.go:506
         monitorCacheFlush, 1-minute ticker)."""
         while not self._closing.wait(self.cache_flush_interval):
-            try:
-                self.holder.flush_caches()
-            except Exception as e:  # noqa: BLE001 - keep the ticker alive
-                self._ticker_error("cache-flush", e)
+            with tracing.tick("cache-flush"):
+                try:
+                    self.holder.flush_caches()
+                except Exception as e:  # noqa: BLE001 - keep the ticker alive
+                    self._ticker_error("cache-flush", e)
 
     @property
     def import_pool(self):
@@ -1414,10 +1432,11 @@ class NodeServer:
         from memberlist's SWIM loop, gossip/gossip.go:364-443; here it is
         an explicit probe ticker on the coordinator)."""
         while not self._closing.wait(self.probe_interval):
-            try:
-                self.run_probe_pass()
-            except Exception as e:  # noqa: BLE001 - keep the ticker alive
-                self._ticker_error("liveness-probe", e)
+            with tracing.tick("liveness-probe"):
+                try:
+                    self.run_probe_pass()
+                except Exception as e:  # noqa: BLE001 - keep the ticker alive
+                    self._ticker_error("liveness-probe", e)
 
     def run_probe_pass(self, timeout: float = 2.0) -> bool:
         """One coordinator liveness tick. Returns True when a state change
@@ -1490,21 +1509,23 @@ class NodeServer:
 
     def _anti_entropy_loop(self) -> None:
         while not self._closing.wait(self.anti_entropy_interval):
-            try:
-                # non-waiting variant: the tick must not stall behind
-                # remote passes triggered by the debt nudge
-                self.try_sync_holder()
-            except Exception as e:  # noqa: BLE001 - keep the ticker alive
-                self._ticker_error("anti-entropy", e)
-            if self.tier is not None:
+            with tracing.tick("anti-entropy"):
                 try:
-                    # anti-entropy extended to snapshot objects: the
-                    # shallow pass uploads missing/stale manifests so the
-                    # store keeps mirroring local state (deep verify is
-                    # on demand via POST /internal/tier/sync?deep=true)
-                    self.tier.sync_snapshots(deep=False)
-                except Exception as e:  # noqa: BLE001
-                    self._ticker_error("tier-sync", e)
+                    # non-waiting variant: the tick must not stall behind
+                    # remote passes triggered by the debt nudge
+                    self.try_sync_holder()
+                except Exception as e:  # noqa: BLE001 - keep the ticker alive
+                    self._ticker_error("anti-entropy", e)
+                if self.tier is not None:
+                    try:
+                        # anti-entropy extended to snapshot objects: the
+                        # shallow pass uploads missing/stale manifests so
+                        # the store keeps mirroring local state (deep
+                        # verify is on demand via POST
+                        # /internal/tier/sync?deep=true)
+                        self.tier.sync_snapshots(deep=False)
+                    except Exception as e:  # noqa: BLE001
+                        self._ticker_error("tier-sync", e)
 
     def sync_holder(self) -> int:
         """One full anti-entropy pass: for every local fragment whose shard

@@ -57,6 +57,17 @@ STAT_NAMES = frozenset(
         "runtime.max_rss_kb",
         "runtime.threads",
         "runtime.open_files",
+        # process-wide stops (utils/tracing.py, published at scrape time
+        # by publish_cache_gauges, 0 from process start): CPython
+        # collector passes and their pause ms over every generation, and
+        # of generation 2 (a full pass over every tracked object) alone;
+        # background-loop passes and their wall ms ("ticker:<loop>")
+        "runtime.gc_passes",
+        "runtime.gc_pause_ms",
+        "runtime.gc_full_passes",
+        "runtime.gc_full_pause_ms",
+        "runtime.ticks",
+        "runtime.tick_ms",
         # query admission control & QoS (sched/admission.py); admit/shed/
         # wait series carry "class:<interactive|batch|internal>" and
         # "index:<name>" tags (index "-" when the request is not bound to
@@ -313,6 +324,9 @@ STAT_LABELS: Dict[str, Tuple[str, ...]] = {
     "tier.local_bytes": ("index",),
     "coherence.subscriptions": ("index",),
     "mesh.fallback": ("reason",),
+    # one series per background loop of server/node.py (tracing.TICKERS)
+    "runtime.ticks": ("ticker",),
+    "runtime.tick_ms": ("ticker",),
     # federation meta-gauges (server/telemetry.py writes these into the
     # merged registry directly; the "cluster." prefix covers the names)
     "cluster.peer_stale": ("node",),

@@ -37,11 +37,23 @@ only. Exported spans carry `startMonoNs`, the span's start on
 `time.monotonic()` in nanoseconds — on Linux the clock of
 `time.perf_counter()`, so a client on the same host can place a span
 between its own send and receive without a handshake.
+
+Process-wide stops: two things stop a request thread that no span of
+its own covers, and both are on the profiler's clock too, as
+annotations that are not spans (no ring, no tree): a pass of CPython's
+collector (`runtime.gc`, a `gc.callbacks` entry installed once at
+import) and one pass of a node's background loop (`node.tick`, the
+`tick` helper server/node.py wraps each pass in). Both add to plain
+module integers, taking no lock — a collection can start while its
+thread holds any lock, the registries' own included — and every node's
+publish_cache_gauges copies the totals to `runtime.gc_*` and
+`runtime.ticks` / `runtime.tick_ms` at scrape time.
 """
 
 from __future__ import annotations
 
 import contextvars
+import gc
 import random
 import threading
 import time
@@ -154,6 +166,12 @@ SPAN_NAMES = frozenset(
         # or batch-class recompute, then long-poll wakeup; tags:
         # index / sub / pushed / shed / error
         "sub.push",
+        # profiler-clock-only names: annotations with no span in the ring
+        # or the tree (module docstring, "Process-wide stops"). One pass
+        # of CPython's collector (_on_gc); arg: generation
+        "runtime.gc",
+        # one pass of a node's background loop (tick); arg: ticker
+        "node.tick",
     }
 )
 
@@ -563,6 +581,99 @@ def take_stage_account() -> StageAccount:
     acc = getattr(_stage_tls, "account", None)
     _stage_tls.account = None
     return acc if acc is not None else StageAccount()
+
+
+# ---------------------------------------------------------------------------
+# process-wide stops (module docstring): collector passes and background
+# ticks, counted and timed in plain integers. No lock: CPython runs one
+# collection at a time, and under the GIL an add can only be lost to a
+# tick of the same name in another in-process node
+# ---------------------------------------------------------------------------
+
+# passes and pause nanoseconds by collector generation
+_gc_passes = [0, 0, 0]
+_gc_pause_ns = [0, 0, 0]
+_gc_t0 = 0  # the open pass's start, 0 between passes
+_gc_annotation = None
+_annotating = TraceAnnotation.is_enabled  # a profiler session is open
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    # about 1 us a pass with no profiler session open: the annotation is
+    # built only while one is (is_enabled), which halves the callback
+    global _gc_t0, _gc_annotation
+    try:
+        if phase == "start":
+            _gc_t0 = time.perf_counter_ns()
+            if _annotating():
+                _gc_annotation = TraceAnnotation(
+                    "runtime.gc", generation=info["generation"]
+                )
+                _gc_annotation.__enter__()
+        elif _gc_t0:
+            pause = time.perf_counter_ns() - _gc_t0
+            _gc_t0 = 0
+            if _gc_annotation is not None:
+                _gc_annotation.__exit__(None, None, None)
+                _gc_annotation = None
+            gen = info["generation"]
+            _gc_passes[gen] += 1
+            _gc_pause_ns[gen] += pause
+    except BaseException:  # noqa: BLE001 - a collector callback never raises
+        pass
+
+
+gc.callbacks.append(_on_gc)
+
+
+def gc_totals() -> dict:
+    """Collector passes and pause milliseconds since the process began:
+    over every generation, and of generation 2 (full passes) alone."""
+    return {
+        "passes": sum(_gc_passes),
+        "pause_ms": sum(_gc_pause_ns) / 1e6,
+        "full_passes": _gc_passes[2],
+        "full_pause_ms": _gc_pause_ns[2] / 1e6,
+    }
+
+
+# the background loops of server/node.py, by the name their errors and
+# their runtime.ticks label carry
+TICKERS = (
+    "coherence", "tier-demote", "telemetry", "runtime-poll",
+    "cache-flush", "liveness-probe", "anti-entropy",
+)
+_ticks = {name: [0, 0] for name in TICKERS}  # [passes, wall ns]
+
+
+class tick:
+    """`with tick(<ticker>):` around one pass of a background loop: the
+    pass on the profiler's clock as `node.tick` and in the ticker's
+    totals (tick_totals)."""
+
+    __slots__ = ("ticker", "_t0", "_annotation")
+
+    def __init__(self, ticker: str) -> None:
+        self.ticker = ticker
+
+    def __enter__(self) -> "tick":
+        self._annotation = TraceAnnotation("node.tick", ticker=self.ticker)
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        wall = time.perf_counter_ns() - self._t0
+        self._annotation.__exit__(None, None, None)
+        row = _ticks[self.ticker]
+        row[0] += 1
+        row[1] += wall
+
+
+def tick_totals() -> dict:
+    """{ticker: (passes, wall ms)} since the process began, every ticker
+    of TICKERS listed from the start."""
+    return {name: (n, ns / 1e6) for name, (n, ns) in _ticks.items()}
 
 
 # ---------------------------------------------------------------------------

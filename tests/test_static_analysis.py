@@ -967,6 +967,29 @@ class TestApiInvariantsSeeded:
             f.code == "API007" and "exec.dispatch" in f.message for f in fs
         )
 
+    def test_a_literal_annotation_starts_a_name(self):
+        """A TraceAnnotation under a literal name is a start: declared
+        or API006, and tracing.py's own (runtime.gc, node.tick) keep
+        their profiler-clock-only entries from reading stale."""
+        fs = self._with_span_registry(
+            """
+            from jax.profiler import TraceAnnotation
+
+            def f():
+                with TraceAnnotation("rogue.annotation"):
+                    pass
+            """
+        )
+        assert any(
+            f.code == "API006" and "rogue.annotation" in f.message
+            for f in fs
+        )
+        assert not [
+            f for f in fs
+            if f.code == "API007"
+            and ("runtime.gc" in f.message or "node.tick" in f.message)
+        ]
+
     def test_config_flag_doc_invariants(self, tmp_path):
         config_src = textwrap.dedent(
             """
