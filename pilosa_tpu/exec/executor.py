@@ -636,8 +636,8 @@ def _tag_group_by(launches: int, info: dict) -> None:
     """What one GroupBy's tallies did, on its exec.dispatch span (the
     caller is inside it and holds the dispatch mutex, so a delta of
     groupby.STATS is this query's): groupby.levels / live_groups /
-    planes / group_block / fold_ms from `info`, groupby.tallies the
-    launches."""
+    planes / group_block / cross_tile / fold_ms from `info`,
+    groupby.tallies the launches."""
     sp = tracing.active_span()
     if sp is None:
         return
@@ -3462,6 +3462,7 @@ class Executor:
         # a profile shows the answer came from the device
         def tally():
             launched = _group_by_launches()
+            qgb.take_cross_tiles()
             if value is None:
                 out = qgb.group_by_device(planes_list, child_rows, filt)
                 info = {"levels": len(planes_list), "live_groups": len(out),
@@ -3472,6 +3473,9 @@ class Executor:
                     planes_list, child_rows, filt, value, o.bit_depth,
                     signed=o.min < o.base, info=info,
                 )
+            tiles = qgb.take_cross_tiles()
+            if tiles:
+                info["cross_tile"] = tiles
             _tag_group_by(_group_by_launches() - launched, info)
             return out
 

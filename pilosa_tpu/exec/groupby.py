@@ -86,6 +86,10 @@ STATS = {
 KERNEL_PROGRAM = "jit__cross_counts_vmem"
 XLA_PROGRAM = "jit__counts_cross"
 
+# The word-loop tiles of the kernel tallies since `take_cross_tiles` last
+# ran, each once (`pallas_kernels.cross_tiles`).
+_TILES: List[str] = []
+
 
 def reset_stats() -> None:
     for k in STATS:
@@ -205,6 +209,9 @@ def cross_tally(acc, planes, mid=None, filt=None):  # dispatch-ok: caller holds 
         STATS["kernel_tallies"] += 1
         if len(_parts(planes)) > 1:
             STATS["inplace_tallies"] += 1
+        for tile in pallas_kernels.cross_tiles(acc, planes, mid).split(","):
+            if tile not in _TILES:
+                _TILES.append(tile)
         return pallas_kernels.cross_counts(acc, planes, mid, filt)
     STATS["xla_tallies"] += 1
     if filt is not None:
@@ -213,6 +220,15 @@ def cross_tally(acc, planes, mid=None, filt=None):  # dispatch-ok: caller holds 
         return _counts_cross(acc, planes)
     out = _counts_cross(_cross_expand(acc, mid), planes)
     return out.reshape(acc.shape[0], mid.shape[0], *out.shape[1:])
+
+
+def take_cross_tiles() -> str:
+    """The tiles of the kernel cross tallies since the last call, ","
+    joined ("" where none ran), and forget them: a GroupBy's
+    `groupby.cross_tile` tag. Callers hold the dispatch mutex."""
+    tiles = ",".join(_TILES)
+    _TILES.clear()
+    return tiles
 
 
 def _host_sum(counts) -> np.ndarray:

@@ -51,17 +51,35 @@ from jax.experimental.pallas import tpu as pltpu
 # kernel body per layout, chosen per extent; an operand in the other layout
 # is still answered exactly, after a relayout copy XLA inserts.
 _CROSS_ROWS = (8, 8, 16)  # acc / mid / planes rows per grid step
-_CROSS_PARTS = 32  # partial-count vregs carried through the word loop
 # Words of a row per grid step. The row-major body ends every grid step
 # with a lane reduction per pair, so it wants long steps: 16384 words is
 # what its largest blocks (8 + 8 + 16 rows x 8 shards, double-buffered =
 # 32 MB) leave room for under the VMEM limit (one [8|16, 256, W] extent
 # of Q4, unrolled body: 12.6 ms at 4096, 11.8 at 8192, 11.3 at 16384; my
-# chip runs, PR 32).
+# chip runs, PR 32). Since PR 40 the reductions of a pass of 16 pairs
+# take 174 bundles against 1 728 in its word loop (v5e bundle dump).
 _CROSS_WORDS = {True: 32768, False: 16384}
 _CROSS_VMEM_BYTES = 40 << 20
 _SUBLANES = 8
 _LANES = 128
+# The word loop's live set, in the 64 vregs of a v5e core: one partial
+# vreg per pair a pass carries, the tiles one side of its pairs holds
+# through a round (row-major: the prefixes; shard-major: mid & candidate
+# slab), one tile of the other side, and the temporaries of the schedule.
+# Until PR 40 a pass carried 32 partials through rounds of one 128-word
+# column: at taxi Q4's shapes 27-28 spill stores and 1.72 (row-major) /
+# 1.53 (shard-major) bundles a pair-vreg, where AND, popcount and add
+# need 0.75 (v5e bundle dump). 16 partials, and partials + tiles within
+# 32 vregs, leave both loops without a spill at every served shape
+# (0.84 / 0.98 at Q4's; 40 vregs spilled 5-14 stores an iteration).
+_CROSS_PARTS = 16
+_CROSS_LIVE = 32
+_CROSS_COLS = 8  # most 128-word columns of one tile load
+# Columns of one loop iteration, in rounds of a tile each, so that the
+# next round's loads are scheduled under this one's VALU work (Pallas
+# lowers no partial unroll of a loop): 0.93 -> 0.84 bundles a pair-vreg
+# row-major; 32 columns read 0.80 for twice the code to trace.
+_CROSS_SPAN = 16
 
 
 def _cross_refs(mt: int, has_filt: bool, refs):
@@ -78,31 +96,56 @@ def _cross_refs(mt: int, has_filt: bool, refs):
     return acc_ref, filt_ref, mid_ref, planes_ref, out_ref
 
 
-def _lane_slice(j):
-    return pl.ds(pl.multiple_of(j * _LANES, _LANES), _LANES)
+def cross_tile(gt: int, m_n: int, rt: int, steps: int,
+               shard_major: bool) -> tuple:
+    """(sub, cols): what a pass of the cross body's word loop carries, and
+    the 128-word columns of the tile every operand row is loaded as (its
+    address worked out once a tile, not once a vreg). Row-major: a pass
+    is `sub` prefixes x all rt candidate rows, the prefix tiles held
+    through a round, the candidate rows streamed. Shard-major: a pass is
+    all gt acc rows x `sub` mid rows x the 8-row candidate slabs, the
+    tiles mid & slab held, the acc rows streamed. The most pairs a pass
+    (`_CROSS_PARTS`), then the widest tile, that keep partials + held
+    tiles + one streamed tile within `_CROSS_LIVE` vregs (a pass grows
+    while tiles of half the widest width still fit)."""
+    if shard_major:
+        held = pl.cdiv(rt, _SUBLANES)
+        pairs, most = gt * held, m_n
+    else:
+        held, pairs, most = 1, rt, gt * m_n
+
+    def live(sub, cols):
+        return sub * (pairs + held * cols) + cols
+
+    cols = min(steps, _CROSS_COLS)
+    sub = 1
+    while (sub < most and (sub + 1) * pairs <= _CROSS_PARTS
+           and live(sub + 1, min(cols, _CROSS_COLS // 2)) <= _CROSS_LIVE):
+        sub += 1
+    while cols > 1 and live(sub, cols) > _CROSS_LIVE:
+        cols //= 2
+    return sub, cols
 
 
-def _word_loop(steps: int, body, init):
-    """The loop over a block's 128-word columns, unrolled while the
-    partials of the unrolled bodies still fit the vreg budget (a body of
-    one or two pairs is otherwise mostly loop overhead)."""
-    unroll = max(1, min(8, _CROSS_PARTS // len(init)))
-    while steps % unroll:
-        unroll -= 1
+def _word_loop(steps: int, cols: int, body, init):
+    """The loop over a block's tiles of `cols` columns, `_CROSS_SPAN`
+    columns an iteration where the block has them."""
+    rounds = steps // cols
+    u = min(rounds, max(1, _CROSS_SPAN // cols))
 
-    def unrolled(i, parts):
-        for k in range(unroll):
-            parts = body(i * unroll + k, parts)
+    def rounds_of(i, parts):
+        for k in range(u):
+            parts = body(i * u + k, parts)
         return parts
 
-    return jax.lax.fori_loop(0, steps // unroll, unrolled, init)
+    return jax.lax.fori_loop(0, rounds // u, rounds_of, init)
 
 
-def _tally_step(parts, pre, row, acc_ref, filt, mid_ref, ps):
-    """parts += popcount(acc[g] (& filt) (& mid[m]) & p) for every (g, m)
-    of `pre` and candidate tile p of `ps`, prefix-major; `row(ref, i)`
-    loads row i's tile, each operand row once (a row index is a Python
-    int or a traced scalar; the same index object is the same row)."""
+def _prefixes(pre, row, acc_ref, filt, mid_ref):
+    """The prefix tiles acc[g] (& filt) (& mid[m]) of every (g, m) of
+    `pre`; `row(ref, i)` loads row i's tile, each operand row once (a row
+    index is a Python int or a traced scalar; the same index object is the
+    same row)."""
 
     def key(i):
         return i if isinstance(i, int) else id(i)
@@ -114,79 +157,113 @@ def _tally_step(parts, pre, row, acc_ref, filt, mid_ref, ps):
     if filt is not None:
         accs = {g: a & filt for g, a in accs.items()}
     if mid_ref is None:
-        ts = [accs[key(g)] for g, _ in pre]
-    else:
-        mids = rows(mid_ref, [m for _, m in pre])
-        ts = [accs[key(g)] & mids[key(m)] for g, m in pre]
-    pcs = [
-        jax.lax.population_count(jnp.bitwise_and(t, p).astype(jnp.int32))
-        for t in ts
-        for p in ps
-    ]
-    return tuple(a + b for a, b in zip(parts, pcs))
+        return [accs[key(g)] for g, _ in pre]
+    mids = rows(mid_ref, [m for _, m in pre])
+    return [accs[key(g)] & mids[key(m)] for g, m in pre]
+
+
+def _count(out, i, x, cols):
+    """out[i] += popcount(x), the tile's `cols` 128-word columns added into
+    the one partial vreg."""
+    c = jax.lax.population_count(x.astype(jnp.int32))
+    for u in range(cols):
+        out[i] = out[i] + c[:, u * _LANES : (u + 1) * _LANES]
+
+
+def _lane_sum(part):
+    """A partial's counts summed across its lanes, float32 [n, 1]: one
+    reduction where an int32 sum is two (of 16-bit halves), and exact: a
+    lane holds at most 32 x 256 and the sum at most 2**20."""
+    return jnp.sum(part.astype(jnp.float32), axis=1, keepdims=True)
 
 
 def _cross_kernel_shard_major(gt, mt, rt, has_filt, *refs):
     """One grid step on [rows, wt words] blocks of ONE shard: a vreg holds
-    128 words of 8 candidate rows, each prefix row is broadcast over the
-    sublanes. Partials [8 rows, 128 lanes] per (prefix, 8-row slab) are
-    carried through the word loop and reduced across lanes once, into lane
-    `prefix` of the resident out block [rt, 128]. mt == 0: no mid level."""
+    128 words of 8 candidate rows, and an acc or mid row is broadcast over
+    the sublanes. A pass (`cross_tile`; a loop over the mid rows, not
+    unrolled) holds the tiles mid[m] (& filt) & slab for its mid rows and
+    every 8-row candidate slab, each mid row broadcast once, and streams
+    every acc row through them, so a broadcast serves all the pairs of its
+    row. Partials [8 rows, 128 lanes] per (g, m, slab) are carried through
+    the word loop and reduced across lanes once, into lane g * M + m of
+    the resident out block [rt, 128]. mt == 0: no mid level. A ragged last
+    pass tallies its last mid row again and does not write it. At taxi
+    Q4's tail (8 x 8 x 16 rows) a pass is 8 acc rows x 1 mid row x 2 slabs
+    in tiles of 4 columns: 250 bundles an iteration of four rounds, 256
+    pair-vregs (0.98), no spill (v5e bundle dump); a row's broadcast is a
+    sublane permute per column, 0.56 of a pair-vreg's 3.8 VALU ops."""
     acc_ref, filt_ref, mid_ref, planes_ref, out_ref = _cross_refs(
         mt, has_filt, refs
     )
     steps = planes_ref.shape[-1] // _LANES
     m_n = max(mt, 1)
-    prefixes = [(g, m) for g in range(gt) for m in range(m_n)]
-    slabs = [
-        (r0, min(r0 + _SUBLANES, rt)) for r0 in range(0, rt, _SUBLANES)
-    ]
-    sub_p = max(1, _CROSS_PARTS // len(slabs))
-    for p0 in range(0, len(prefixes), sub_p):
-        pre = prefixes[p0 : p0 + sub_p]
+    sub, cols = cross_tile(gt, m_n, rt, steps, True)
+    width = cols * _LANES
+    slabs = [(lo, min(lo + _SUBLANES, rt)) for lo in range(0, rt, _SUBLANES)]
+    zeros = tuple(jnp.zeros((hi - lo, _LANES), jnp.int32) for lo, hi in slabs)
+    held_n = sub * len(slabs)
 
-        def body(j, parts, pre=pre):
-            sl = _lane_slice(j)
-            return _tally_step(
-                parts, pre, lambda ref, i: ref[i : i + 1, sl], acc_ref,
-                filt_ref[:, sl] if has_filt else None, mid_ref,
-                [planes_ref[lo:hi, sl] for lo, hi in slabs],
-            )
+    def tally(i, carry):
+        m0 = i * sub
+        ms = [jnp.minimum(m0 + k, m_n - 1) for k in range(sub)]
 
-        zeros = tuple(
-            jnp.zeros((hi - lo, _LANES), jnp.int32) for lo, hi in slabs
-        )
-        parts = _word_loop(steps, body, zeros * len(pre))
+        def body(j, parts):
+            sl = pl.ds(pl.multiple_of(j * width, width), width)
+            f = filt_ref[:, sl] if has_filt else None
+            ps = [planes_ref[lo:hi, sl] for lo, hi in slabs]
+            held = []
+            for m in ms:
+                h = f
+                if mt:
+                    row = mid_ref[pl.ds(m, 1), sl]
+                    h = row if h is None else h & row
+                held += [p if h is None else h & p for p in ps]
+            out = list(parts)
+            for g in range(gt):
+                row = acc_ref[g : g + 1, sl]
+                for k, h in enumerate(held):
+                    _count(out, g * held_n + k, row & h, cols)
+            return tuple(out)
+
+        parts = _word_loop(steps, cols, body, zeros * (gt * held_n))
         for v, (lo, hi) in enumerate(slabs):
             lane = jax.lax.broadcasted_iota(jnp.int32, (hi - lo, _LANES), 1)
-            tile = zeros[v]
-            for k in range(len(pre)):
-                col = jnp.sum(parts[k * len(slabs) + v], axis=1, keepdims=True)
-                tile = jnp.where(lane == p0 + k, col, tile)
-            out_ref[lo:hi, :] += tile
+            tile = jnp.zeros((hi - lo, _LANES), jnp.float32)
+            for g in range(gt):
+                for k, m in enumerate(ms):
+                    col = _lane_sum(parts[g * held_n + k * len(slabs) + v])
+                    to = jnp.where(m0 + k < m_n, g * m_n + m, -1)
+                    tile = jnp.where(lane == to, col, tile)
+            out_ref[lo:hi, :] += tile.astype(jnp.int32)
+        return carry
+
+    jax.lax.fori_loop(0, pl.cdiv(m_n, sub), tally, 0)
 
 
 def _cross_kernel_row_major(gt, mt, rt, has_filt, *refs):
     """One grid step on [rows, 8 shards, wt words] blocks: a vreg holds the
     same 128 words of 8 shards of one row, so a pair's partial
     [8 shards, 128 lanes] reduces across lanes to 8 per-shard counts, put
-    in lane `pair` of the resident out block [8, pairs]. The prefix
-    groups that fit the carried partials are a LOOP over one group's code,
-    not unrolled (64 prefixes x 16 rows are 16 groups x 2 slabs of rows):
-    unrolled, one Q4 program of four extents took 6 s to trace and lower
-    and 22 s inside a server whose heap holds an index; so 1 s and 5 s, for
-    a 4 % slower tally (40.5 -> 42.1 ms; my chip runs, PR 32). Candidate
-    rows keep static indices: with those dynamic too it is 48 ms. A ragged
-    last group tallies its last prefix again and does not write it."""
+    in lane `pair` of the resident out block [8, pairs]. A pass
+    (`cross_tile`) forms the tiles of its prefixes once a round and
+    streams every candidate row through them. The passes are a LOOP over
+    one pass's code, not unrolled: unrolled, one Q4 program of four
+    extents took 6 s to trace and lower and 22 s inside a server whose
+    heap holds an index; so 1 s and 5 s, for a 4 % slower tally (40.5 ->
+    42.1 ms; my chip runs, PR 32). Candidate rows keep static indices: with
+    those dynamic too it is 48 ms. A ragged last pass tallies its last
+    prefix again and does not write it. At taxi Q4's shapes a pass is 1
+    prefix x 16 rows in tiles of 8 columns: 216 bundles an iteration of
+    two rounds, 256 pair-vregs (0.84), no spill (v5e bundle dump)."""
     acc_ref, filt_ref, mid_ref, planes_ref, out_ref = _cross_refs(
         mt, has_filt, refs
     )
     steps = planes_ref.shape[-1] // _LANES
     m_n = max(mt, 1)
     n_pre = gt * m_n
-    sub_r = min(rt, _SUBLANES)
-    sub_p = max(1, min(n_pre, _CROSS_PARTS // sub_r))
-    window = sub_p * rt  # lanes of the out block one group writes: <= 64
+    sub_p, cols = cross_tile(gt, m_n, rt, steps, False)
+    width = cols * _LANES
+    window = sub_p * rt  # lanes of the out block one pass writes: <= 16
 
     def group(i, carry):
         p0 = i * sub_p
@@ -196,46 +273,60 @@ def _cross_kernel_row_major(gt, mt, rt, has_filt, *refs):
             pre = [(g, p % m_n) for p in ps]
         else:
             pre = [(p // m_n, p % m_n) for p in ps]
-        for r0 in range(0, rt, sub_r):
-            rows = range(r0, min(r0 + sub_r, rt))
 
-            def body(j, parts, rows=rows):
-                sl = _lane_slice(j)
-                return _tally_step(
-                    parts, pre, lambda ref, i: ref[i, :, sl], acc_ref,
-                    filt_ref[:, sl] if has_filt else None, mid_ref,
-                    [planes_ref[r, :, sl] for r in rows],
+        def body(j, parts):
+            sl = pl.ds(pl.multiple_of(j * width, width), width)
+            ts = _prefixes(
+                pre, lambda ref, i: ref[i, :, sl], acc_ref,
+                filt_ref[:, sl] if has_filt else None, mid_ref,
+            )
+            out = list(parts)
+            for r in range(rt):
+                p = planes_ref[r, :, sl]
+                for k, t in enumerate(ts):
+                    _count(out, k * rt + r, t & p, cols)
+            return tuple(out)
+
+        zero = jnp.zeros((_SUBLANES, _LANES), jnp.int32)
+        parts = _word_loop(steps, cols, body, (zero,) * (sub_p * rt))
+        # the group's pairs are lanes [p0 * rt, (p0 + sub_p) * rt) of the
+        # out block: gathered in registers (a store per pair to a lane
+        # chunk found at run time is a chain of dependent
+        # read-modify-writes), then added to the one or two 128-lane chunks
+        # the window touches
+        lane = jax.lax.broadcasted_iota(jnp.int32, zero.shape, 1)
+        first = p0 * rt // _LANES
+        tiles = [jnp.zeros(zero.shape, jnp.float32)] * (
+            1 if _LANES % window == 0 else 2
+        )
+        for k in range(sub_p):
+            for r in range(rt):
+                col = _lane_sum(parts[k * rt + r])
+                to = jnp.where(
+                    p0 + k < n_pre, p0 * rt % _LANES + k * rt + r, -1
                 )
-
-            zero = jnp.zeros((_SUBLANES, _LANES), jnp.int32)
-            parts = _word_loop(steps, body, (zero,) * (sub_p * len(rows)))
-            # the group's pairs are lanes [p0 * rt, (p0 + sub_p) * rt) of
-            # the out block: gathered in registers (a store per pair to a
-            # lane chunk found at run time is a chain of dependent
-            # read-modify-writes), then added to the one or two 128-lane
-            # chunks the window touches
-            lane = jax.lax.broadcasted_iota(jnp.int32, zero.shape, 1)
-            first = p0 * rt // _LANES
-            tiles = [zero] * (1 if _LANES % window == 0 else 2)
-            for k in range(sub_p):
-                for n, r in enumerate(rows):
-                    col = jnp.sum(
-                        parts[k * len(rows) + n], axis=1, keepdims=True
-                    )
-                    to = jnp.where(
-                        p0 + k < n_pre, p0 * rt % _LANES + k * rt + r, -1
-                    )
-                    tiles = [
-                        jnp.where(lane == to - c * _LANES, col, t)
-                        for c, t in enumerate(tiles)
-                    ]
-            last = out_ref.shape[-1] // _LANES - 1
-            for c, t in enumerate(tiles):
-                at = jnp.minimum(first + c, last) * _LANES
-                out_ref[:, pl.ds(pl.multiple_of(at, _LANES), _LANES)] += t
+                tiles = [
+                    jnp.where(lane == to - c * _LANES, col, t)
+                    for c, t in enumerate(tiles)
+                ]
+        last = out_ref.shape[-1] // _LANES - 1
+        for c, t in enumerate(tiles):
+            at = jnp.minimum(first + c, last) * _LANES
+            out_ref[:, pl.ds(pl.multiple_of(at, _LANES), _LANES)] += t.astype(
+                jnp.int32
+            )
         return carry
 
     jax.lax.fori_loop(0, pl.cdiv(n_pre, sub_p), group, 0)
+
+
+def _cross_block(acc, planes, mid, shard_major: bool) -> tuple:
+    """(gt, mt, rt, wt): the acc, mid (0: none) and candidate rows one
+    grid step of an extent holds, and the words of a row it reads."""
+    gt = min(acc.shape[0], _CROSS_ROWS[0])
+    mt = 0 if mid is None else min(mid.shape[0], _CROSS_ROWS[1])
+    rt = min(planes.shape[0], _CROSS_ROWS[2])
+    return gt, mt, rt, math.gcd(acc.shape[-1], _CROSS_WORDS[shard_major])
 
 
 @functools.partial(jax.jit, static_argnames=("shard_major",))
@@ -248,9 +339,7 @@ def _cross_counts_extent(acc, planes, mid, filt, shard_major: bool):
     r_n = planes.shape[0]
     m_n = 1 if mid is None else mid.shape[0]
     assert w % _LANES == 0, f"row width {w} not a lane multiple"
-    wt = math.gcd(w, _CROSS_WORDS[shard_major])
-    gt, rt = min(g_n, _CROSS_ROWS[0]), min(r_n, _CROSS_ROWS[2])
-    mt = 0 if mid is None else min(m_n, _CROSS_ROWS[1])
+    gt, mt, rt, wt = _cross_block(acc, planes, mid, shard_major)
     pre = gt * max(mt, 1)  # prefixes per grid step
     n_g, n_m, n_r = pl.cdiv(g_n, gt), pl.cdiv(m_n, max(mt, 1)), pl.cdiv(r_n, rt)
     pairs = pre * rt
@@ -373,13 +462,37 @@ def cross_counts(  # dispatch-ok: wrapper; callers serialize (run_serialized)
     per extent; left None each extent follows the layout of its own
     candidate rows."""
     acc, planes, mid = _parts(acc), _parts(planes), _parts(mid)
-    if shard_major is None:
-        shard_major = tuple(_shard_major(p) for p in planes)
-    elif isinstance(shard_major, bool):
-        shard_major = (shard_major,) * len(planes)
     return _cross_counts_vmem(
-        acc, planes, mid, filt, shard_major=tuple(shard_major)
+        acc, planes, mid, filt, shard_major=_bodies(planes, shard_major)
     )
+
+
+def _bodies(planes: tuple, shard_major) -> tuple:
+    """The kernel body of each extent, as `cross_counts` takes it."""
+    if shard_major is None:
+        return tuple(_shard_major(p) for p in planes)
+    if isinstance(shard_major, bool):
+        return (shard_major,) * len(planes)
+    return tuple(shard_major)
+
+
+def cross_tiles(acc, planes, mid=None, shard_major=None) -> str:
+    """The word loop's tile of each kernel body `cross_counts` runs over
+    these operands (given as it takes them), as "<prefixes>x<rows>x<cols>"
+    — the prefixes and candidate rows a pass tallies (`cross_tile`) and
+    the tile's 128-word columns — in extent order, each once, joined by
+    ",". Taxi Q4's four extents: "1x16x8,8x16x4"."""
+    acc, planes, mid = _parts(acc), _parts(planes), _parts(mid)
+    tags = []
+    for e, body in enumerate(_bodies(planes, shard_major)):
+        gt, mt, rt, wt = _cross_block(
+            acc[e], planes[e], None if mid is None else mid[e], body
+        )
+        sub, cols = cross_tile(gt, max(mt, 1), rt, wt // _LANES, body)
+        tag = f"{gt * sub if body else sub}x{rt}x{cols}"
+        if tag not in tags:
+            tags.append(tag)
+    return ",".join(tags)
 
 
 # ---------------------------------------------------------------------------
@@ -405,6 +518,7 @@ _GROUP_ROW_SCALARS = 16
 # the loop (2.0 bundles a pair where its VALU work needs 1.2; v5e bundle
 # dump, PR 38); a tile of 8 leaves it VALU-bound (0.88 a pair).
 _GROUP_COLS = 8
+_GROUP_PARTS = 32  # partial vregs a block carries through its word loop
 
 
 def group_words(rows_total: int, w: int) -> int:
@@ -422,7 +536,7 @@ def group_block(n_dims: int, p_n: int) -> tuple:
     """(gb, pb): the group tally takes its groups gb at a time, each plane
     tile loaded once for the gb of them, and carries pb counts of each
     group (its own and up to pb - 1 planes) through a word loop: gb x pb
-    partials within the cross kernel's budget, the block's row indices
+    partials within `_GROUP_PARTS`, the block's row indices
     within `_GROUP_ROW_SCALARS`, its counts within one 128-lane chunk of
     the out block. The (1 + p_n) counts are cut into slabs of equal size.
     A deep field's tally keeps one group a block: with 8-column tiles its
@@ -432,10 +546,10 @@ def group_block(n_dims: int, p_n: int) -> tuple:
     q = 1 + p_n
     q_pad = 1 << p_n.bit_length()
     gb = 1
-    while (2 * gb * q <= _CROSS_PARTS and 2 * gb * n_dims <= _GROUP_ROW_SCALARS
+    while (2 * gb * q <= _GROUP_PARTS and 2 * gb * n_dims <= _GROUP_ROW_SCALARS
            and 2 * gb * q_pad <= _LANES):
         gb *= 2
-    slabs = pl.cdiv(q, max(1, _CROSS_PARTS // gb))
+    slabs = pl.cdiv(q, max(1, _GROUP_PARTS // gb))
     return gb, pl.cdiv(q, slabs)
 
 

@@ -44,6 +44,7 @@ def rand_words(rng, *shape):
 # -- GroupBy cross tally (pk.cross_counts vs exec/groupby._counts_cross) -----
 
 _CROSS_W = 256  # two lane tiles: the word loop runs more than once
+_WIDE_W = 2048  # 16 lane tiles: two tiles of 8 columns a grid step
 
 
 def _np_cross(acc, planes, mid=None, filt=None):
@@ -128,6 +129,36 @@ _CROSS_CASES = {
                                                  rand_words(r, 6, 3, 128),
                                                  rand_words(r, 3, 3, 128),
                                                  rand_words(r, 3, 128)),
+    # rows of _WIDE_W words: a whole tile of pk._CROSS_COLS columns and
+    # more than one round of the word loop (pk.cross_tile picks the tile)
+    # taxi Q4's form: 8 x 8 prefixes x 16 rows, no filter
+    "wide_q4_shape": lambda r: (rand_words(r, 8, 3, _WIDE_W),
+                                rand_words(r, 16, 3, _WIDE_W),
+                                rand_words(r, 8, 3, _WIDE_W), None),
+    # 25 prefixes x 6 rows: row-major passes of 2 prefixes write 12 lanes,
+    # the eleventh lanes 120-131 across two lane chunks, and the last pass
+    # is ragged; shard-major passes of 3 mid rows, the last ragged
+    "wide_ragged_across_lane_chunks": lambda r: (
+        rand_words(r, 5, 3, _WIDE_W), rand_words(r, 6, 3, _WIDE_W),
+        rand_words(r, 5, 3, _WIDE_W), rand_words(r, 3, _WIDE_W)),
+    # fewer candidate rows than a vreg's 8 sublanes
+    "wide_rows_3": lambda r: (rand_words(r, 8, 3, _WIDE_W),
+                              rand_words(r, 3, 3, _WIDE_W),
+                              rand_words(r, 8, 3, _WIDE_W), None),
+    # mid and filter; 9 rows are a full and a one-row slab, over 9 shards
+    "wide_fused_filtered": lambda r: (rand_words(r, 3, 9, _WIDE_W),
+                                      rand_words(r, 9, 9, _WIDE_W),
+                                      rand_words(r, 4, 9, _WIDE_W),
+                                      rand_words(r, 9, _WIDE_W)),
+    # the filtered TopN's dense chunk: one acc row
+    "wide_topn_one_acc_row": lambda r: (rand_words(r, 1, 3, _WIDE_W),
+                                        rand_words(r, 16, 3, _WIDE_W),
+                                        None, None),
+    # twice the columns: the word loop iterates more than once
+    "wide_two_iterations": lambda r: (rand_words(r, 2, 3, 2 * _WIDE_W),
+                                      rand_words(r, 3, 3, 2 * _WIDE_W),
+                                      rand_words(r, 2, 3, 2 * _WIDE_W),
+                                      None),
 }
 
 
@@ -143,6 +174,8 @@ _PARTS_CASES = {
     "parts_fused_filtered": ("fused_filtered", (3, 3, 3), True),
     "parts_row_tiles_ragged": ("fused_row_tiles_ragged", (1, 1), True),
     "parts_one": ("fused_filtered", (9,), False),
+    "parts_wide_q4_shape": ("wide_q4_shape", (2, 1), True),
+    "parts_wide_fused_filtered": ("wide_fused_filtered", (8, 1), False),
 }
 _CROSS_CASES.update({
     "shards_19": lambda r: (rand_words(r, 3, 19, _CROSS_W),

@@ -553,6 +553,35 @@ class TestStackedGroupBy:
             "assembled_bytes": 0, "aggregate_queries": 0, "plane_tallies": 0,
         }
 
+    def test_kernel_tally_tags_the_tile_of_its_word_loop(
+        self, three_extents, monkeypatch
+    ):
+        """`groupby.cross_tile` on a GroupBy's `exec.dispatch` names the
+        word-loop tile each kernel body ran ("<prefixes>x<rows>x<cols>",
+        `pallas_kernels.cross_tiles`); the XLA program's tally has none."""
+        from jax.experimental.pallas import tpu as pltpu
+
+        import pilosa_tpu.exec.executor as exmod
+        from pilosa_tpu.core.resultcache import RESULT_CACHE
+        from pilosa_tpu.exec import groupby as qgb
+
+        seen = []
+        monkeypatch.setattr(
+            exmod, "_tag_group_by", lambda n, info: seen.append(dict(info))
+        )
+        ex = three_extents
+        query = self._EXTENT_QUERIES[1]
+        ex.execute("gb", query)
+        assert "cross_tile" not in seen[-1]
+        self._engage_kernel(monkeypatch, qgb)
+        RESULT_CACHE.reset()  # the run below must execute
+        with pltpu.force_tpu_interpret_mode():
+            ex.execute("gb", query)
+        # 6 x 5 prefixes x 3 rows, row-major on this backend: passes of 4
+        # prefixes (12 partials), tiles of 4 columns
+        assert seen[-1]["cross_tile"] == "4x3x4"
+        assert qgb.take_cross_tiles() == ""  # taken by the dispatch
+
     @pytest.mark.parametrize(
         "path,query",
         [

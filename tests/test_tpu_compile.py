@@ -71,6 +71,10 @@ _CASES = {
                                    SHARDS * WORDS * 4),
     # the filtered TopN's dense chunk and a descent chunk at 954 shards
     "topn_chunk": (1, 2, 0, False, (SHARDS,), (True,), 0),
+    # the TopN's one acc row against a chunk of 16 rows, read where the
+    # four extents lie
+    "topn_chunk_extents": (1, 16, 0, False, EXTENTS, (False,) * 3 + (True,),
+                           0),
     "descent_chunk": (2, 16, 0, False, (SHARDS,), (True,), 0),
     # stacks the device keeps row-major: odd row counts, or 8 | S
     "odd_rows": (3, 5, 6, True, (SHARDS,), (False,), 0),
