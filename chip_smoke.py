@@ -20,7 +20,9 @@ one process holds the chip and this parent never initialises a JAX backend
    chip (`groupby.kernel_tallies` on `/debug/vars`) over the view's four
    resident extents as they lie (`groupby.assembled_stacks` flat), as the
    XLA program on several, and so must the group tally of the filtered
-   `GroupBy(..., aggregate=Sum(field=v))`; every cold dispatch must have run as one program over all
+   `GroupBy(..., aggregate=Sum(field=v))` on one chip, which on several
+   runs the kernel under shard_map (`groupby.mesh_tallies`) and no XLA
+   program; every cold dispatch must have run as one program over all
    the devices the server holds (`mesh.devices` on its span: 4 on a
    four-chip host, 1 on one chip);
 4. writes (a PQL `Set`, then an `/import` burst large enough to cross the
@@ -256,10 +258,11 @@ def read_queries(ref: Reference) -> list:
          ref.count_gt(V_THRESHOLD), 1),
         ("group_by", "GroupBy(Rows(g), Rows(h))", ref.group_by("g", "h"), 1),
         # the filter's plan, then the group tally: each dimension against
-        # the filter, and the groups the surviving rows make with v's planes.
+        # the filter, and the groups the surviving rows make with v's planes
+        # (on a four-chip host the kernel under shard_map on every chip).
         # Over the first 300 shards (two extents): v's 19 planes over all
         # 954 are more than a quarter of the shipped 4 GB budget, which
-        # the executor answers shard by shard, without a tally
+        # the executor tallies in four shard chunks
         ("group_by_sum",
          f"Options(GroupBy(Rows(g), Rows(h), filter=Row(f=1), "
          f"aggregate=Sum(field=v)), shards={list(range(agg_shards))})",
@@ -447,32 +450,41 @@ def ask(http_: Http, pql: str) -> tuple:
 
 
 TALLY_GAUGES = ("kernel_tallies", "xla_tallies", "inplace_tallies",
-                "assembled_stacks")
+                "assembled_stacks", "mesh_tallies")
 
 
 def tally_counts(http_: Http) -> tuple:
-    """(kernel, xla, in-place, assembled): the cross tallies so far by
-    program, those that read a view's extents where they lie, and the
-    operands concatenated for a tally (exec/groupby.py)."""
+    """(kernel, xla, in-place, assembled, mesh): the tallies so far by
+    program, those that read a view's extents where they lie, the
+    operands concatenated for a tally, and the kernel's tallies that ran
+    under shard_map over the mesh (exec/groupby.py)."""
     vars_ = http_.call("GET", "/debug/vars")
     return tuple(int(vars_.get(f"groupby.{g}", 0)) for g in TALLY_GAUGES)
 
 
 def check_tally_program(family: str, kernel: int, xla: int, device: dict,
-                        assembled: int = 0) -> None:
+                        assembled: int = 0, mesh: int = 0) -> None:
     """A cold GroupBy or filtered TopN tallies its cross on the device.
     On one TPU chip that is the VMEM kernel and never the XLA loop, and it
     reads the view's resident extents in place: no operand is written
-    again as one stack (`assembled`). Stacks sharded over several devices,
-    or another backend, are the XLA program's. A JAX upgrade that breaks
-    the kernel fails here."""
-    one_chip = device["platform"] == "tpu" and device["count"] == 1
-    ran, other = (kernel, xla) if one_chip else (xla, kernel)
+    again as one stack (`assembled`). On several TPU chips the aggregate
+    GroupBy's group tally is the same kernel under shard_map on every
+    chip (`mesh`, each also a kernel tally), and never the XLA program;
+    the count-only cross over sharded stacks, and anything on another
+    backend, is the XLA program's. A JAX upgrade that breaks the kernel
+    fails here."""
+    tpu = device["platform"] == "tpu"
+    one_chip = tpu and device["count"] == 1
+    on_mesh = tpu and device["count"] > 1 and family == "group_by_sum"
+    ran, other = (kernel, xla) if one_chip or on_mesh else (xla, kernel)
     # a filtered TopN whose candidates are all sparse rows tallies no stack
-    if other or not (ran or not family.startswith("group_by")):
+    if other or not (ran or not family.startswith("group_by")) or (
+        on_mesh and mesh != kernel
+    ) or (not on_mesh and mesh):
         raise AssertionError(
             f"{family}: {kernel} kernel and {xla} XLA cross tallies on "
-            f"{device['count']} x {device['platform']}"
+            f"{device['count']} x {device['platform']}, {mesh} kernel "
+            f"tallies on the mesh"
         )
     if one_chip and assembled:
         raise AssertionError(
@@ -506,11 +518,11 @@ def run_queries(http_: Http, queries: list, cold: bool,
         if cold and device is not None:
             check_placement(family, placed, device)
         if tallied:
-            kernel, xla, inplace, assembled = (
+            kernel, xla, inplace, assembled, mesh = (
                 a - b for a, b in zip(tally_counts(http_), before))
-            check_tally_program(family, kernel, xla, device, assembled)
-            print(f"  {family:22s} cross tallies: kernel={kernel} xla={xla} "
-                  f"in place={inplace} assembled stacks={assembled}")
+            check_tally_program(family, kernel, xla, device, assembled, mesh)
+            print(f"  {family:22s} tallies: kernel={kernel} (mesh={mesh}) "
+                  f"xla={xla} in place={inplace} assembled stacks={assembled}")
         if got != want:
             raise AssertionError(f"{family}: {pql} -> {got!r}, want {want!r}")
         if cold and n_dispatch < min_dispatches:

@@ -632,12 +632,35 @@ def _group_by_launches() -> int:
     return qgb.STATS["kernel_tallies"] + qgb.STATS["xla_tallies"]
 
 
+def _add_groups(merged: dict, out: dict) -> None:
+    """One shard chunk's groups into `merged`, key by key: a count, or
+    (count, stored sum, values), added."""
+    for k, v in out.items():
+        was = merged.get(k)
+        if was is None:
+            merged[k] = v
+        elif isinstance(v, tuple):
+            merged[k] = tuple(a + b for a, b in zip(was, v))
+        else:
+            merged[k] = was + v
+
+
+def _add_info(info: dict, part: dict) -> None:
+    """One shard chunk's tally facts into the GroupBy's: host times add,
+    every other fact (levels, planes, rows, block) is the chunks' largest."""
+    for k, v in part.items():
+        if k.endswith("_ms"):
+            info[k] = round(info.get(k, 0.0) + v, 3)
+        else:
+            info[k] = max(info.get(k, v), v)
+
+
 def _tag_group_by(launches: int, info: dict) -> None:
     """What one GroupBy's tallies did, on its exec.dispatch span (the
     caller is inside it and holds the dispatch mutex, so a delta of
     groupby.STATS is this query's): groupby.levels / live_groups /
-    planes / group_block / cross_tile / fold_ms from `info`,
-    groupby.tallies the launches."""
+    planes / group_block / cross_tile / fold_ms / prune_ms / prune_rows /
+    shard_chunks from `info`, groupby.tallies the launches."""
     sp = tracing.active_span()
     if sp is None:
         return
@@ -3367,9 +3390,12 @@ class Executor:
         recursive walk. With `value_field` (aggregate=Sum) its BSI planes
         are staged beside the dimensions, one stack through the same
         residency layer, and a group's value is (count, stored sum,
-        values) instead of its count. Returns None to fall back to the
-        per-shard path (stacked lowering unsupported for this
-        shape/budget)."""
+        values) instead of its count. Operands over the dispatch cap
+        (`_stack_guard`) are staged and tallied over chunks of the shard
+        axis (`_chunk_by_budget`): counts and sums add over shards, so the
+        chunks' groups are added key by key. Returns None to fall back to
+        the per-shard path (stacked lowering unsupported for this shape,
+        or a chunk below 16 shards still over the cap)."""
         if not _STACKED_ENABLED or not shard_list:
             return None
         if filter_call is not None and self._count_shifts(filter_call):
@@ -3395,9 +3421,36 @@ class Executor:
                 ]
         if not gb_shards:
             return {}
+        chunks = self._chunk_by_budget(
+            list(gb_shards),
+            lambda chunk: self._group_by_stage(
+                idx, child_views, child_rows, filter_call, chunk, value_field
+            ),
+        )
+        if chunks is None:
+            return None
+        if not chunks:
+            return {}
+        return self._group_by_dispatch(chunks, child_rows, value_field)
+
+    def _group_by_stage(
+        self, idx, child_views, child_rows, filter_call, chunk, value_field
+    ) -> Optional[list]:
+        """The operands of one shard chunk's tally, staged: [(planes_list,
+        filt, value)], [] where nothing in the chunk can hold a group, None
+        for a shape with no stacked form. A stack over the dispatch cap
+        raises BudgetExceeded before anything is lowered or staged."""
         from pilosa_tpu.core.devcache import DEVICE_CACHE
 
-        low = _StackedLowering(self, idx, gb_shards, no_sparse_guard=True)
+        low = _StackedLowering(self, idx, chunk, no_sparse_guard=True)
+        bsiv = depth = None
+        if value_field is not None:
+            depth = value_field.options.bit_depth
+            bsiv = value_field.view(value_field.bsi_view_name())
+        for v, rows in zip(child_views, child_rows):
+            low._stack_guard(v, mult=max(len(rows), 1))
+        if bsiv is not None:
+            low._stack_guard(bsiv, mult=depth + 2)
         planes_list = []
         try:
             with lower_span("groupby"), DEVICE_CACHE.deferred_eviction():
@@ -3405,51 +3458,41 @@ class Executor:
                 if filter_call is not None:
                     root = low.lower(filter_call)
                     if isinstance(root, PZero) or not low.operands:
-                        return {}  # filter matches nothing anywhere
+                        return []  # the filter matches nothing here
                     filt = StackedPlan(
-                        root, low.operands, low.scalars, len(gb_shards)
+                        root, low.operands, low.scalars, len(chunk)
                     ).rows_full()
                 for v, rows in zip(child_views, child_rows):
-                    low._stack_guard(v, mult=max(len(rows), 1))
                     # the resident extents as they are: every child is
                     # staged over low.shards at one extent size, so the
                     # parts line up span for span and the tally reads
                     # them in place (exec/groupby.py)
                     p = v.plane_stack(rows, low.shards, parts=True)
                     if p is None:
-                        return {}
+                        return []
                     planes_list.append(p)
                 value = None
-                if value_field is not None:
+                if bsiv is not None:
                     # exists, sign and the magnitude planes as ONE stack
                     # over the same shards: a shard without a BSI fragment
                     # is a zero slab (no column there holds a value)
-                    depth = value_field.options.bit_depth
-                    bsiv = value_field.view(value_field.bsi_view_name())
-                    if bsiv is not None:
-                        low._stack_guard(bsiv, mult=depth + 2)
-                        value = bsiv.plane_stack(
-                            range(BSI_OFFSET_BIT + depth), low.shards,
-                            parts=True,
-                        )
+                    value = bsiv.plane_stack(
+                        range(BSI_OFFSET_BIT + depth), low.shards, parts=True,
+                    )
+        except BudgetExceeded:
+            raise  # _chunk_by_budget halves the chunk
         except Unsupported:
             return None
         finally:
             low.extents.release()  # staging-window pins (see _stacked_bsi)
-        if value_field is not None and value is None:
-            # no column holds a value anywhere: the groups, with nothing summed
-            counted = self._group_by_dispatch(planes_list, child_rows, filt)
-            return {k: (cnt, 0, 0) for k, cnt in counted.items()}
-        return self._group_by_dispatch(
-            planes_list, child_rows, filt, value, value_field
-        )
+        return [(planes_list, filt, value)]
 
     @staticmethod
-    def _group_by_dispatch(
-        planes_list, child_rows, filt, value=None, value_field=None
-    ):
-        """The whole tally pipeline — the cross tally, or with `value` (the
-        aggregate's plane stack) the group tally — as ONE exec.dispatch."""
+    def _group_by_dispatch(chunks, child_rows, value_field=None):
+        """The whole tally pipeline of every shard chunk — the cross tally,
+        or with a value field (its plane stack staged beside the
+        dimensions) the group tally — as ONE exec.dispatch; the chunks'
+        groups added key by key."""
         from pilosa_tpu.exec import groupby as qgb
         from pilosa_tpu.exec import plan as planmod
 
@@ -3463,29 +3506,39 @@ class Executor:
         def tally():
             launched = _group_by_launches()
             qgb.take_cross_tiles()
-            if value is None:
-                out = qgb.group_by_device(planes_list, child_rows, filt)
-                info = {"levels": len(planes_list), "live_groups": len(out),
-                        "planes": 0, "fold_ms": 0.0}
-            else:
-                o, info = value_field.options, {}
-                out = qgb.group_by_aggregate(
-                    planes_list, child_rows, filt, value, o.bit_depth,
-                    signed=o.min < o.base, info=info,
-                )
+            merged, info = {}, {}
+            for planes_list, filt, value in chunks:
+                if value is None:
+                    out = qgb.group_by_device(planes_list, child_rows, filt)
+                    part = {"levels": len(planes_list), "planes": 0,
+                            "fold_ms": 0.0}
+                    if value_field is not None:
+                        # no column of the chunk holds a value: the
+                        # groups, with nothing summed
+                        out = {k: (cnt, 0, 0) for k, cnt in out.items()}
+                else:
+                    o, part = value_field.options, {}
+                    out = qgb.group_by_aggregate(
+                        planes_list, child_rows, filt, value, o.bit_depth,
+                        signed=o.min < o.base, info=part,
+                    )
+                _add_groups(merged, out)
+                _add_info(info, part)
+            info.update(live_groups=len(merged), shard_chunks=len(chunks))
             tiles = qgb.take_cross_tiles()
             if tiles:
                 info["cross_tile"] = tiles
             _tag_group_by(_group_by_launches() - launched, info)
-            return out
+            return merged
 
+        planes_list, filt, value = chunks[0]
         return planmod.run_counted(
             tally, read=False, family="groupby",
             program=(
                 qgb.tally_program(planes_list, filt) if value is None
                 else qgb.group_program(planes_list, value, filt)
             ),
-            arrays=planes_list,
+            arrays=[c[0] for c in chunks],
         )
 
     def _group_by_shard(  # dispatch-ok: per-shard path, single-device

@@ -46,9 +46,11 @@ the only ones a group can hold), and the groups those rows make — or,
 where they are too many to list, the live groups of the count-only tally
 above — are handed to `group_tally` as a table of row indices, which
 counts each listed group and its planes in one pass over the rows where
-they lie (`ops/pallas_kernels.group_counts`; `_counts_groups` is the XLA
-program for every other backend and the kernel's oracle). Per-shard
-counts are uint32 and are folded on the host in exact integers.
+they lie (`ops/pallas_kernels.group_counts`; over stacks sharded on a
+mesh of TPUs the same kernel on every chip's block under shard_map,
+`group_counts_mesh`; `_counts_groups` is the XLA program for every other
+backend and the kernel's oracle). Per-shard counts are uint32 and are
+folded on the host in exact integers.
 
 Cross-products too deep or too large for one read are tallied level-wise:
 at depth d one tally counts every live prefix against every candidate
@@ -76,11 +78,13 @@ import numpy as np
 # tallies by program, tallies that read more than one extent in place, and
 # operands concatenated into one stack for a tally.
 # `assembled_bytes` is what those concatenations wrote; `aggregate_queries`
-# and `plane_tallies` (group x plane pairs counted) are the aggregate's.
+# and `plane_tallies` (group x plane pairs counted) are the aggregate's;
+# `mesh_tallies` the kernel tallies that ran under shard_map over the
+# active mesh (counted in `kernel_tallies` too).
 STATS = {
     "evals": 0, "kernel_tallies": 0, "xla_tallies": 0,
     "inplace_tallies": 0, "assembled_stacks": 0, "assembled_bytes": 0,
-    "aggregate_queries": 0, "plane_tallies": 0,
+    "aggregate_queries": 0, "plane_tallies": 0, "mesh_tallies": 0,
 }
 
 KERNEL_PROGRAM = "jit__cross_counts_vmem"
@@ -414,6 +418,7 @@ def _descend(  # dispatch-ok: caller holds dispatch_mutex
 # ---------------------------------------------------------------------------
 
 GROUP_KERNEL_PROGRAM = "jit__group_counts_vmem"
+GROUP_MESH_PROGRAM = "jit__group_counts_mesh"
 GROUP_XLA_PROGRAM = "jit__counts_groups"
 # rows of the value field's plane stack, as its fragments number them
 from pilosa_tpu.core.fragment import (  # noqa: E402
@@ -456,8 +461,44 @@ def _group_kernel_covers(dims, planes, filt) -> bool:
     return bool(pallas_kernels.group_words(rows, _shape(dims[0])[2]))
 
 
+def _on_tpu(devices) -> bool:
+    return all(d.platform == "tpu" for d in devices)
+
+
+def _group_mesh(dims, planes, filt):
+    """The active mesh, where the group tally runs as the kernel under
+    shard_map over it: every operand one array placed as the stacked path
+    places it (dimensions and planes P(None, "shards", "cols"), the filter
+    P("shards", "cols")), every device a TPU, and one tile of every row of
+    a device's block within the kernel's buffer. Else None."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from pilosa_tpu.ops import pallas_kernels
+    from pilosa_tpu.parallel import mesh as pmesh
+
+    mesh = pmesh.active_mesh()
+    if mesh is None or not _on_tpu(mesh.devices.flat):
+        return None
+    stacks = list(dims) + ([planes] if planes is not None else [])
+    placed = [(x, P(None, "shards", "cols")) for x in stacks]
+    if filt is not None:
+        placed.append((filt, P("shards", "cols")))
+    for x, spec in placed:
+        parts = _parts(x)
+        sharding = getattr(parts[0], "sharding", None)
+        if len(parts) != 1 or sharding is None or not sharding.is_equivalent_to(
+            NamedSharding(mesh, spec), parts[0].ndim
+        ):
+            return None
+    w = _shape(dims[0])[2] // mesh.shape["cols"]
+    rows = sum(_shape(x)[0] for x in stacks) + (filt is not None)
+    return mesh if pallas_kernels.group_words(rows, w) else None
+
+
 def group_program(dims, planes=None, filt=None) -> str:
     """The jitted program that tallies an aggregate GroupBy's groups."""
+    if _group_mesh(dims, planes, filt) is not None:
+        return GROUP_MESH_PROGRAM
     if _group_kernel_covers(dims, planes, filt):
         return GROUP_KERNEL_PROGRAM
     return GROUP_XLA_PROGRAM
@@ -468,7 +509,9 @@ def group_block(dims, planes=None, filt=None) -> int:
     it loads: the kernel's block, 1 for the XLA program."""
     from pilosa_tpu.ops import pallas_kernels
 
-    if not _group_kernel_covers(dims, planes, filt):
+    if _group_mesh(dims, planes, filt) is None and not _group_kernel_covers(
+        dims, planes, filt
+    ):
         return 1
     p_n = 0 if planes is None else _shape(planes)[0]
     return pallas_kernels.group_block(len(dims), p_n)[0]
@@ -478,15 +521,19 @@ def group_tally(dims, idx: np.ndarray, planes=None, filt=None, mask_row=None):  
     """Per-shard counts of the groups `idx` int[n_dims, G] lists (row
     idx[d, g] of dimension d makes group g), and of each with every plane
     of `planes`: a list of device arrays uint32[<= G padded, 1 + P, S], one
-    a launch, for `_read_groups`. The kernel where it covers the operands
-    (parts read in place, the table padded to a power of two and only the
-    listed groups tallied), else the XLA program over whole stacks in
-    chunks of `_gmax` groups. Launches are asynchronous."""
+    a launch, for `_read_groups` (on the mesh the last axis is the mesh's
+    rows of shards, each summed over its shards on the device). The kernel
+    where it covers the operands (parts read in place, the table padded to
+    a power of two and only the listed groups tallied) — on one TPU, or on
+    each chip of the active mesh under shard_map where the operands are
+    sharded over it — else the XLA program over whole stacks in chunks of
+    `_gmax` groups. Launches are asynchronous."""
     from pilosa_tpu.ops import pallas_kernels
 
     g_n = idx.shape[1]
     STATS["plane_tallies"] += g_n * (0 if planes is None else _shape(planes)[0])
-    kernel = _group_kernel_covers(dims, planes, filt)
+    mesh = _group_mesh(dims, planes, filt)
+    kernel = mesh is not None or _group_kernel_covers(dims, planes, filt)
     if kernel:
         step = pallas_kernels.GROUP_MAX_GROUPS
         in_place = len(_parts(dims[0])) > 1
@@ -500,12 +547,18 @@ def group_tally(dims, idx: np.ndarray, planes=None, filt=None, mask_row=None):  
         chunk = idx[:, lo : lo + step]
         table = np.stack([_pad_pow2(r) for r in chunk]).astype(np.int32)
         STATS["evals"] += 1
-        if kernel:
+        live = np.array([chunk.shape[1]], np.int32)
+        if mesh is not None:
+            STATS["kernel_tallies"] += 1
+            STATS["mesh_tallies"] += 1
+            outs.append(pallas_kernels.group_counts_mesh(
+                mesh, dims, table, live, planes, filt, mask_row,
+            ))
+        elif kernel:
             STATS["kernel_tallies"] += 1
             STATS["inplace_tallies"] += in_place
             outs.append(pallas_kernels.group_counts(
-                dims, table, np.array([chunk.shape[1]], np.int32), planes,
-                filt, mask_row,
+                dims, table, live, planes, filt, mask_row,
             ))
         else:
             STATS["xla_tallies"] += 1
@@ -514,8 +567,8 @@ def group_tally(dims, idx: np.ndarray, planes=None, filt=None, mask_row=None):  
 
 
 def _read_groups(outs, g_n: int) -> np.ndarray:
-    """The launches of one `group_tally` read and summed over the shard
-    axis in exact uint64: [G, 1 + P]."""
+    """The launches of one `group_tally` read and summed over their last
+    (shard) axis in exact uint64: [G, 1 + P]."""
     return np.concatenate([_host_sum(o) for o in outs], axis=0)[:g_n]
 
 
@@ -539,7 +592,10 @@ def group_by_aggregate(  # dispatch-ok: caller holds dispatch_mutex
     filter), `values` those of them that hold a value and `stored sum` the
     exact integer sum of the stored (base-relative) values over them.
     `info` gains levels / live_groups / planes / group_block (groups the
-    tally counts against each plane tile it loads) / fold_ms.
+    tally counts against each plane tile it loads) / fold_ms, and under a
+    filter prune_ms (host wall time from the first launch that tallies a
+    dimension against it to the end of their reads) / prune_rows (the
+    dimensions' rows so tallied).
 
     Launches and blocking reads are O(levels): under a filter one tally
     of each dimension against the filter; then the groups the surviving
@@ -551,13 +607,15 @@ def group_by_aggregate(  # dispatch-ok: caller holds dispatch_mutex
 
     STATS["aggregate_queries"] += 1
     info.update(levels=len(planes_list), planes=_shape(value)[0],
-                live_groups=0, group_block=0, fold_ms=0.0)
+                live_groups=0, group_block=0, fold_ms=0.0, prune_ms=0.0,
+                prune_rows=0)
     rows = [_shape(p)[0] for p in planes_list]
     if not rows or not all(rows):
         return {}
     # the rows of each dimension a group can hold: those the filter leaves
     live = [np.arange(r) for r in rows]
     if filt is not None:
+        t0 = time.perf_counter()
         outs = [
             group_tally([p], np.arange(r)[None], filt=filt)
             for p, r in zip(planes_list, rows)
@@ -565,6 +623,8 @@ def group_by_aggregate(  # dispatch-ok: caller holds dispatch_mutex
         live = [
             np.nonzero(_read_groups(o, r)[:, 0])[0] for o, r in zip(outs, rows)
         ]
+        info.update(prune_ms=round((time.perf_counter() - t0) * 1000.0, 3),
+                    prune_rows=sum(rows))
         if not all(len(x) for x in live):
             return {}
     n_cand = int(np.prod([len(x) for x in live], dtype=np.int64))

@@ -901,6 +901,10 @@ class NodeServer:
         self.stats.gauge(
             "groupby.plane_tallies", groupby_mod.STATS["plane_tallies"]
         )
+        # of the kernel's tallies, those run under shard_map on the mesh
+        self.stats.gauge(
+            "groupby.mesh_tallies", groupby_mod.STATS["mesh_tallies"]
+        )
         # the views' row summaries (core/view.py row_summary): hits over
         # hits + bypassed is the share of Rows / GroupBy-prefetch /
         # unfiltered-TopN reads the table served

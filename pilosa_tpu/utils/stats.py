@@ -122,6 +122,9 @@ STAT_NAMES = frozenset(
         "groupby.assembled_bytes",
         "groupby.aggregate_queries",
         "groupby.plane_tallies",
+        # of the kernel's tallies, those that ran under shard_map on every
+        # chip of the active mesh (the group tally over sharded stacks)
+        "groupby.mesh_tallies",
         # per-view row summary (core/view.py row_summary, counted in the
         # process registry and published at scrape time): readers that
         # found the table under the view's current mutation clock, tables

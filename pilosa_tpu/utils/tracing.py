@@ -131,7 +131,11 @@ SPAN_NAMES = frozenset(
         # (aggregate only: groups tallied per loaded plane tile) /
         # cross_tile (the cross kernel's word-loop tile of each body it
         # ran, "<prefixes>x<rows>x<cols>") / tallies (launches) / fold_ms
-        # (host time from the last read to the finished groups)
+        # (host time from the last read to the finished groups) /
+        # shard_chunks (chunks of the shard axis tallied; 1 unchunked) /
+        # prune_ms and prune_rows (aggregate under a filter: host time of
+        # the tallies of each dimension against the filter, with their
+        # reads, and the rows so tallied)
         "exec.dispatch",
         # a whole distributed fan-out incl. re-map rounds
         # (exec/distributed.py)

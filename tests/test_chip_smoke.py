@@ -32,10 +32,22 @@ def test_smoke_parts_agree_with_a_served_index_small():
         device = {"platform": "cpu", "kind": "cpu", "count": 8}
         before = cs.tally_counts(http_)
         cs.run_queries(http_, queries, cold=True, device=device)
-        kernel, xla, inplace, assembled = (
+        kernel, xla, inplace, assembled, mesh = (
             a - b for a, b in zip(cs.tally_counts(http_), before))
         # 3 shards are one extent (and the mesh stages one array anyway)
         assert kernel == 0 and xla >= 1 and (inplace, assembled) == (0, 0)
+        assert mesh == 0  # a CPU mesh runs no kernel
+        # four TPU chips: the aggregate's group tally is the kernel on the
+        # mesh and nothing else; the count-only cross stays XLA's there
+        four = {"platform": "tpu", "kind": "TPU v5 lite", "count": 4}
+        cs.check_tally_program("group_by_sum", 3, 0, four, mesh=3)
+        for kernel_, xla_, mesh_ in ((0, 3, 0), (3, 1, 3), (3, 0, 2)):
+            with pytest.raises(AssertionError, match="on the mesh"):
+                cs.check_tally_program("group_by_sum", kernel_, xla_, four,
+                                       mesh=mesh_)
+        cs.check_tally_program("group_by", 0, 1, four)
+        with pytest.raises(AssertionError, match="on the mesh"):
+            cs.check_tally_program("group_by", 1, 1, four, mesh=1)
         one_chip = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
         with pytest.raises(AssertionError, match="cross tallies"):
             cs.check_tally_program("group_by", kernel, xla, one_chip)

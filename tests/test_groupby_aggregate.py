@@ -274,6 +274,7 @@ def test_kernel_tallies_live_groups_by_planes_in_place(
         # one launch a dimension against the filter, one for the groups
         "kernel_tallies": 3 + 1, "inplace_tallies": 3 + 1, "evals": 3 + 1,
         "xla_tallies": 0, "assembled_stacks": 0, "assembled_bytes": 0,
+        "mesh_tallies": 0,
     }
     # a signed field costs one more launch, over the same groups
     qgb.reset_stats()

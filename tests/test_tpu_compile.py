@@ -22,7 +22,7 @@ SHARDS, WORDS = 954, 32768  # taxi-1b at the shipped shard width
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topology():
     from jax.experimental import topologies
 
     described = {
@@ -41,7 +41,21 @@ def one_chip():
             )
         except Exception as e:
             pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-        yield SingleDeviceSharding(topo.devices[0])
+        yield topo
+
+
+@pytest.fixture(scope="module")
+def one_chip(topology):
+    return SingleDeviceSharding(topology.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topology):
+    """The 2 x 2 ("shards", "cols") mesh a four-chip host forms."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(topology.devices).reshape(2, 2), ("shards", "cols"))
 
 
 def _stack(sharding, rows, shards=SHARDS):
@@ -145,3 +159,51 @@ def test_group_counts_compiles_for_v5e_without_copying_its_stacks(
     ).compile()
     assert compiled.as_text().count("tpu_custom_call") >= len(SSB_EXTENTS)
     assert compiled.memory_analysis().temp_size_in_bytes <= 1 << 20
+
+
+# ssb-sf100-x4.q32-q34: 573 shards in chunks of 287 and 286, padded to the
+# mesh's two shard rows: a chip's block of a chunk is 144 (or 143) shards x
+# 16 384 words. name: (rows of each dimension, shards, table, planes, mask)
+_MESH_CASES = {
+    "x4_prune_city": ((250,), 288, 256, 0, None),
+    "x4_prune_year": ((7,), 288, 8, 0, None),
+    "x4_q32": ((250, 250, 7), 288, 1024, 26, 0),
+    "x4_q32_tail_chunk": ((250, 250, 7), 286, 1024, 26, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_MESH_CASES))
+def test_group_counts_mesh_compiles_for_v5e_at_the_cells_shapes(
+    four_chips, case
+):
+    """The group tally under shard_map on the 2 x 2 mesh: every chip runs
+    the kernel on its [rows, shards / 2, 16 384] block of the city
+    dimensions, the year and the 26 planes of lo_revenue, sums its counts
+    over its shards, and the "cols" halves are added by one all-reduce; no
+    operand is copied to feed it, and what is read back is one count a
+    group, plane and row of shards."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    dims, shards, g, planes, mask_row = _MESH_CASES[case]
+    stack = NamedSharding(four_chips, P(None, "shards", "cols"))
+    every = NamedSharding(four_chips, P())
+
+    def rows(n):
+        return _stack(stack, n, shards)
+
+    compiled = pk._group_counts_mesh.lower(
+        tuple(rows(r) for r in dims),
+        jax.ShapeDtypeStruct((len(dims), g), jnp.int32, sharding=every),
+        jax.ShapeDtypeStruct((1,), jnp.int32, sharding=every),
+        rows(planes) if planes else None,
+        _stack(NamedSharding(four_chips, P("shards", "cols")), 0, shards),
+        mask_row=mask_row, mesh=four_chips,
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 1
+    assert "all-reduce" in text
+    # the counts and their all-reduce, nothing the size of a stack
+    counts = g * (1 + planes) * shards * 4
+    assert compiled.memory_analysis().temp_size_in_bytes <= 4 * counts
+    out = compiled.out_info
+    assert out.shape == (g, 1 + planes, 2)
