@@ -22,7 +22,11 @@ one process holds the chip and this parent never initialises a JAX backend
    XLA program on several, and so must the group tally of the filtered
    `GroupBy(..., aggregate=Sum(field=v))` on one chip, which on several
    runs the kernel under shard_map (`groupby.mesh_tallies`) and no XLA
-   program; every cold dispatch must have run as one program over all
+   program; on a TPU the aggregate over a 250-row field of which the
+   filter leaves 10 rows must have cut its tally to the rows it names
+   (`groupby.compacted_tallies`, a 4 096-word tile in the span's
+   `groupby.tally_words`), and the one over 30 resident rows must not;
+   every cold dispatch must have run as one program over all
    the devices the server holds (`mesh.devices` on its span: 4 on a
    four-chip host, 1 on one chip);
 4. writes (a PQL `Set`, then an `/import` burst large enough to cross the
@@ -59,10 +63,16 @@ import numpy as np
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 INDEX = "smoke"
+SET_FIELDS = ("f", "g", "h", "k")
 SHARDS = 954  # 1 B columns at the shipped shard width (BASELINE.md)
 PER_SHARD = 4096  # populated columns per shard
 F_DENSITY = (0.60, 0.52, 0.45, 0.38, 0.31, 0.24, 0.17, 0.10)  # per f row
 G_ROWS, H_ROWS = 3, 4
+# a wide dimension, as a city is in a star schema: the group tally over 10
+# of its rows, f's 8 and g's 3 is cut to the rows it names (the tile of
+# every resident row would be 1 024 words)
+K_ROWS, K_NAMED, K_SHARDS = 250, 10, 16
+CUT_WORDS = 4096  # the group tally's tile once cut (GROUP_FULL_WORDS)
 V_MAX = 1_000_000  # needs 20 planes: more than one 16-plane slab
 V_SHARE = 0.75  # of populated columns carrying a value
 V_THRESHOLD = 500_000
@@ -100,6 +110,7 @@ class Data:
         self.h = rng.integers(0, H_ROWS, size=(shards, PER_SHARD))
         self.has_v = rng.random((shards, PER_SHARD)) < V_SHARE
         self.v = rng.integers(0, V_MAX + 1, size=(shards, PER_SHARD))
+        self.k = rng.integers(0, K_ROWS, size=(shards, PER_SHARD))
         if not (self.f.any(axis=2).all() and self.has_v.any(axis=1).all()):
             raise AssertionError("a (row, shard) came out empty")
 
@@ -107,7 +118,8 @@ class Data:
         """Boolean [shards, PER_SHARD] membership of each row of `field`."""
         if field == "f":
             return list(self.f)
-        vals, n = (self.g, G_ROWS) if field == "g" else (self.h, H_ROWS)
+        vals, n = {"g": (self.g, G_ROWS), "h": (self.h, H_ROWS),
+                   "k": (self.k, K_ROWS)}[field]
         return [vals == r for r in range(n)]
 
     def unused_columns(self, strata) -> np.ndarray:
@@ -129,7 +141,7 @@ class Reference:
         flat = data.cols.ravel()
         self.rows = {
             name: [flat[m.ravel()] for m in data.set_rows(name)]
-            for name in ("f", "g", "h")
+            for name in SET_FIELDS
         }
         self.shards, self.width = data.shards, data.shard_width
         hv = data.has_v.ravel()
@@ -217,15 +229,21 @@ class Reference:
         return out
 
 
-    def group_by_sum(self, a: str, b: str, filt: np.ndarray) -> dict:
-        """{(row of a, row of b): (columns under the filter, sum of v over
+    def group_by_sum(self, fields, filt: np.ndarray) -> dict:
+        """{(row of each field): (columns under the filter, sum of v over
         those of them that hold a value)}."""
         out = {}
-        for i, ra in enumerate(self.rows[a]):
-            for j, rb in enumerate(self.rows[b]):
-                cols = np.intersect1d(np.intersect1d(ra, rb, True), filt, True)
-                if len(cols):
-                    out[(i, j)] = (len(cols), int(self._values(cols).sum()))
+
+        def walk(key, cols):
+            if len(key) == len(fields):
+                out[key] = (len(cols), int(self._values(cols).sum()))
+                return
+            for r, row in enumerate(self.rows[fields[len(key)]]):
+                sub = np.intersect1d(cols, row, True)
+                if len(sub):
+                    walk(key + (r,), sub)
+
+        walk((), filt)
         return out
 
 
@@ -234,6 +252,11 @@ def read_queries(ref: Reference) -> list:
     run may show)."""
     f = [ref.row("f", r) for r in range(len(F_DENSITY))]
     agg_shards = min(ref.shards, 300)
+    cut_shards = min(ref.shards, K_SHARDS)
+    named_k = np.unique(np.concatenate(
+        [ref.row("k", r) for r in range(K_NAMED)]))
+    cut_filt = np.intersect1d(f[1], named_k, True)
+    k_rows = ", ".join(f"Row(k={r})" for r in range(K_NAMED))
     return [
         ("count_intersect", "Count(Intersect(Row(f=0), Row(f=1)))",
          len(np.intersect1d(f[0], f[1], True)), 1),
@@ -266,7 +289,20 @@ def read_queries(ref: Reference) -> list:
         ("group_by_sum",
          f"Options(GroupBy(Rows(g), Rows(h), filter=Row(f=1), "
          f"aggregate=Sum(field=v)), shards={list(range(agg_shards))})",
-         ref.group_by_sum("g", "h", f[1][f[1] < agg_shards * ref.width]), 2),
+         ref.group_by_sum(("g", "h"), f[1][f[1] < agg_shards * ref.width]),
+         2),
+        # over a 250-row field of which the filter leaves 10 rows, f's 8
+        # and g's 3, on the first 16 shards (8 a chip on each of the mesh's
+        # rows of shards): on a TPU the tally is cut to the rows its table
+        # names, 16 + 8 + 3 with v's 22 planes and the filter (SSB Q3.4's
+        # 50 rows at city grain)
+        ("group_by_sum_cut",
+         f"Options(GroupBy(Rows(k), Rows(f), Rows(g), filter=Intersect("
+         f"Row(f=1), Union({k_rows})), aggregate=Sum(field=v)), "
+         f"shards={list(range(cut_shards))})",
+         ref.group_by_sum(
+             ("k", "f", "g"), cut_filt[cut_filt < cut_shards * ref.width]),
+         2),
         # a filtered MinRow/MaxRow counts the [S, W] filter stack
         # (`ops/bitmap.popcount`), a Tanimoto TopN counts it per shard
         # (`popcount_rows`): the served path's only uses of the two
@@ -356,7 +392,7 @@ def _fan_out(uri: str, n_items: int, one) -> None:
 
 def create_schema(http_: Http) -> None:
     http_.call("POST", f"/index/{INDEX}", {"options": {}})
-    for name in ("f", "g", "h"):
+    for name in SET_FIELDS:
         http_.call("POST", f"/index/{INDEX}/field/{name}",
                    {"options": {"type": "set"}})
     http_.call("POST", f"/index/{INDEX}/field/v",
@@ -367,7 +403,7 @@ def load(uri: str, data: Data) -> dict:
     """Every shard of every field through the public import routes; returns
     the wall seconds of each field's load (smoke timings)."""
     took = {}
-    for name in ("f", "g", "h"):
+    for name in SET_FIELDS:
         rows = data.set_rows(name)
 
         def one(http_, s, rows=rows, name=name):
@@ -437,27 +473,28 @@ def _normalise(result):
 
 
 def ask(http_: Http, pql: str) -> tuple:
-    """(answer, the `mesh.devices` tag of each exec.dispatch span, wall
-    seconds) of one profiled query."""
+    """(answer, the tags of each exec.dispatch span, wall seconds) of one
+    profiled query."""
     t0 = time.perf_counter()
     out = http_.call("POST", f"/index/{INDEX}/query?profile=1", pql)
     took = time.perf_counter() - t0
-    placed = [
-        s["tags"].get("mesh.devices")
+    dispatched = [
+        s["tags"]
         for r in out["profile"]["roots"] for s in _spans(r, "exec.dispatch")
     ]
-    return _normalise(out["results"][0]), placed, took
+    return _normalise(out["results"][0]), dispatched, took
 
 
 TALLY_GAUGES = ("kernel_tallies", "xla_tallies", "inplace_tallies",
-                "assembled_stacks", "mesh_tallies")
+                "assembled_stacks", "mesh_tallies", "compacted_tallies")
 
 
 def tally_counts(http_: Http) -> tuple:
-    """(kernel, xla, in-place, assembled, mesh): the tallies so far by
-    program, those that read a view's extents where they lie, the
-    operands concatenated for a tally, and the kernel's tallies that ran
-    under shard_map over the mesh (exec/groupby.py)."""
+    """(kernel, xla, in-place, assembled, mesh, compacted): the tallies so
+    far by program, those that read a view's extents where they lie, the
+    operands concatenated for a tally, the kernel's tallies that ran
+    under shard_map over the mesh, and its group tallies cut to the rows
+    their table names (exec/groupby.py)."""
     vars_ = http_.call("GET", "/debug/vars")
     return tuple(int(vars_.get(f"groupby.{g}", 0)) for g in TALLY_GAUGES)
 
@@ -475,7 +512,7 @@ def check_tally_program(family: str, kernel: int, xla: int, device: dict,
     fails here."""
     tpu = device["platform"] == "tpu"
     one_chip = tpu and device["count"] == 1
-    on_mesh = tpu and device["count"] > 1 and family == "group_by_sum"
+    on_mesh = tpu and device["count"] > 1 and family.startswith("group_by_sum")
     ran, other = (kernel, xla) if one_chip or on_mesh else (xla, kernel)
     # a filtered TopN whose candidates are all sparse rows tallies no stack
     if other or not (ran or not family.startswith("group_by")) or (
@@ -493,6 +530,22 @@ def check_tally_program(family: str, kernel: int, xla: int, device: dict,
         )
 
 
+def check_cut(family: str, compacted: int, words: int, device: dict) -> None:
+    """On a TPU, one chip or the mesh, the aggregate over `k` (250 resident
+    rows, 10 named) is tallied on the rows its table names, at the
+    4 096-word tile (`groupby.tally_words` of its dispatch), and the one
+    over `g` and `h` (30 resident rows: a tile of 8 192 words or more
+    already) on the rows as they are resident. Elsewhere the XLA program
+    cuts nothing."""
+    cut = device["platform"] == "tpu" and family == "group_by_sum_cut"
+    if (compacted > 0) != cut or (cut and words != CUT_WORDS):
+        raise AssertionError(
+            f"{family}: {compacted} group tallies cut to their named rows, "
+            f"a tile of {words} words, on {device['count']} x "
+            f"{device['platform']}"
+        )
+
+
 def check_placement(family: str, placed: list, device: dict) -> None:
     """Every compiled dispatch of a cold query ran as one program over
     all the devices the server holds: 4 on a four-chip host, whose stacks
@@ -505,7 +558,8 @@ def check_placement(family: str, placed: list, device: dict) -> None:
         )
 
 
-TALLY_FAMILIES = ("group_by", "group_by_sum", "topn_filtered")
+TALLY_FAMILIES = ("group_by", "group_by_sum", "group_by_sum_cut",
+                  "topn_filtered")
 
 
 def run_queries(http_: Http, queries: list, cold: bool,
@@ -513,16 +567,20 @@ def run_queries(http_: Http, queries: list, cold: bool,
     for family, pql, want, min_dispatches in queries:
         tallied = cold and device is not None and family in TALLY_FAMILIES
         before = tally_counts(http_) if tallied else None
-        got, placed, took = ask(http_, pql)
-        n_dispatch = len(placed)
+        got, dispatched, took = ask(http_, pql)
+        n_dispatch = len(dispatched)
         if cold and device is not None:
-            check_placement(family, placed, device)
+            check_placement(
+                family, [t.get("mesh.devices") for t in dispatched], device)
         if tallied:
-            kernel, xla, inplace, assembled, mesh = (
+            kernel, xla, inplace, assembled, mesh, compacted = (
                 a - b for a, b in zip(tally_counts(http_), before))
             check_tally_program(family, kernel, xla, device, assembled, mesh)
+            words = max(t.get("groupby.tally_words", 0) for t in dispatched)
+            check_cut(family, compacted, words, device)
             print(f"  {family:22s} tallies: kernel={kernel} (mesh={mesh}) "
-                  f"xla={xla} in place={inplace} assembled stacks={assembled}")
+                  f"xla={xla} in place={inplace} assembled stacks={assembled} "
+                  f"cut={compacted} tile={words}")
         if got != want:
             raise AssertionError(f"{family}: {pql} -> {got!r}, want {want!r}")
         if cold and n_dispatch < min_dispatches:

@@ -80,11 +80,13 @@ import numpy as np
 # `assembled_bytes` is what those concatenations wrote; `aggregate_queries`
 # and `plane_tallies` (group x plane pairs counted) are the aggregate's;
 # `mesh_tallies` the kernel tallies that ran under shard_map over the
-# active mesh (counted in `kernel_tallies` too).
+# active mesh (counted in `kernel_tallies` too); `compacted_tallies` the
+# kernel group tallies whose dimensions were cut to the rows they name.
 STATS = {
     "evals": 0, "kernel_tallies": 0, "xla_tallies": 0,
     "inplace_tallies": 0, "assembled_stacks": 0, "assembled_bytes": 0,
     "aggregate_queries": 0, "plane_tallies": 0, "mesh_tallies": 0,
+    "compacted_tallies": 0,
 }
 
 KERNEL_PROGRAM = "jit__cross_counts_vmem"
@@ -517,17 +519,50 @@ def group_block(dims, planes=None, filt=None) -> int:
     return pallas_kernels.group_block(len(dims), p_n)[0]
 
 
-def group_tally(dims, idx: np.ndarray, planes=None, filt=None, mask_row=None):  # dispatch-ok: caller holds dispatch_mutex
+def _compacted(chunk: np.ndarray, resident, other: int, w: int):
+    """(table, rows, words) of one kernel launch listing the groups
+    `chunk`: where `pallas_kernels.compacted_words` says cutting pays, the
+    table over positions in the rows each dimension is cut to — rows[d]
+    int32[L_d], the rows the table names, sorted, then the last of them
+    again up to `compact_rows`, or None for a dimension read whole — and
+    the cut tile; else the table as it is, None and the resident tile."""
+    from pilosa_tpu.ops import pallas_kernels
+
+    named = [np.unique(r) for r in chunk]
+    words = pallas_kernels.compacted_words(
+        resident, [len(u) for u in named], other, w)
+    if not words:
+        return chunk, None, pallas_kernels.group_words(sum(resident) + other, w)
+    table, rows = [], []
+    for u, r, n in zip(named, chunk, resident):
+        cut = pallas_kernels.compact_rows(len(u))
+        if cut < n:
+            table.append(np.searchsorted(u, r))
+            rows.append(np.pad(u, (0, cut - len(u)), mode="edge")
+                        .astype(np.int32))
+        else:
+            table.append(r)
+            rows.append(None)
+    return np.stack(table), tuple(rows), words
+
+
+def group_tally(  # dispatch-ok: caller holds dispatch_mutex
+    dims, idx: np.ndarray, planes=None, filt=None, mask_row=None,
+    info: Optional[dict] = None,
+):
     """Per-shard counts of the groups `idx` int[n_dims, G] lists (row
     idx[d, g] of dimension d makes group g), and of each with every plane
     of `planes`: a list of device arrays uint32[<= G padded, 1 + P, S], one
     a launch, for `_read_groups` (on the mesh the last axis is the mesh's
     rows of shards, each summed over its shards on the device). The kernel
     where it covers the operands (parts read in place, the table padded to
-    a power of two and only the listed groups tallied) — on one TPU, or on
-    each chip of the active mesh under shard_map where the operands are
-    sharded over it — else the XLA program over whole stacks in chunks of
-    `_gmax` groups. Launches are asynchronous."""
+    a power of two and only the listed groups tallied; each dimension cut
+    to the rows its table names where the resident rows would narrow the
+    tile, `_compacted`) — on one TPU, or on each chip of the active mesh
+    under shard_map where the operands are sharded over it — else the XLA
+    program over whole stacks in chunks of `_gmax` groups. `info` gains
+    tally_words: the kernel's tile in words (0 for the XLA program).
+    Launches are asynchronous."""
     from pilosa_tpu.ops import pallas_kernels
 
     g_n = idx.shape[1]
@@ -537,32 +572,41 @@ def group_tally(dims, idx: np.ndarray, planes=None, filt=None, mask_row=None):  
     if kernel:
         step = pallas_kernels.GROUP_MAX_GROUPS
         in_place = len(_parts(dims[0])) > 1
+        resident = [_shape(d)[0] for d in dims]
+        other = (0 if planes is None else _shape(planes)[0]) + (filt is not None)
+        w = _shape(dims[0])[2] // (1 if mesh is None else mesh.shape["cols"])
     else:
         dims = tuple(_assembled(d) for d in dims)
         planes = _assembled(planes)
         _, s, w = _shape(dims[0])
         step = 1 << (_gmax(s, w).bit_length() - 1)
-    outs = []
+    outs, words = [], 0
     for lo in range(0, g_n, step):
         chunk = idx[:, lo : lo + step]
+        live = np.array([chunk.shape[1]], np.int32)
+        rows = None
+        if kernel:
+            chunk, rows, wt = _compacted(chunk, resident, other, w)
+            words = max(words, wt)
+            STATS["kernel_tallies"] += 1
+            STATS["compacted_tallies"] += rows is not None
         table = np.stack([_pad_pow2(r) for r in chunk]).astype(np.int32)
         STATS["evals"] += 1
-        live = np.array([chunk.shape[1]], np.int32)
         if mesh is not None:
-            STATS["kernel_tallies"] += 1
             STATS["mesh_tallies"] += 1
             outs.append(pallas_kernels.group_counts_mesh(
-                mesh, dims, table, live, planes, filt, mask_row,
+                mesh, dims, table, live, planes, filt, mask_row, rows,
             ))
         elif kernel:
-            STATS["kernel_tallies"] += 1
             STATS["inplace_tallies"] += in_place
             outs.append(pallas_kernels.group_counts(
-                dims, table, live, planes, filt, mask_row,
+                dims, table, live, planes, filt, mask_row, rows,
             ))
         else:
             STATS["xla_tallies"] += 1
             outs.append(_counts_groups(dims, table, planes, filt, mask_row))
+    if info is not None:
+        info["tally_words"] = words
     return outs
 
 
@@ -592,7 +636,8 @@ def group_by_aggregate(  # dispatch-ok: caller holds dispatch_mutex
     filter), `values` those of them that hold a value and `stored sum` the
     exact integer sum of the stored (base-relative) values over them.
     `info` gains levels / live_groups / planes / group_block (groups the
-    tally counts against each plane tile it loads) / fold_ms, and under a
+    tally counts against each plane tile it loads) / tally_words (that
+    tally's tile in words, 0 for the XLA program) / fold_ms, and under a
     filter prune_ms (host wall time from the first launch that tallies a
     dimension against it to the end of their reads) / prune_rows (the
     dimensions' rows so tallied).
@@ -607,8 +652,8 @@ def group_by_aggregate(  # dispatch-ok: caller holds dispatch_mutex
 
     STATS["aggregate_queries"] += 1
     info.update(levels=len(planes_list), planes=_shape(value)[0],
-                live_groups=0, group_block=0, fold_ms=0.0, prune_ms=0.0,
-                prune_rows=0)
+                live_groups=0, group_block=0, tally_words=0, fold_ms=0.0,
+                prune_ms=0.0, prune_rows=0)
     rows = [_shape(p)[0] for p in planes_list]
     if not rows or not all(rows):
         return {}
@@ -641,7 +686,7 @@ def group_by_aggregate(  # dispatch-ok: caller holds dispatch_mutex
         )
     g_n = idx.shape[1]
     info["group_block"] = group_block(planes_list, value, filt)
-    pos = group_tally(planes_list, idx, value, filt, _EXISTS_ROW)
+    pos = group_tally(planes_list, idx, value, filt, _EXISTS_ROW, info)
     neg = None
     if signed:
         neg = group_tally(planes_list, idx, value, filt, _SIGN_ROW)

@@ -505,9 +505,10 @@ def cross_tiles(acc, planes, mid=None, shard_major=None) -> str:
 # that the filter leaves) and owes each of them one count per BSI plane of
 # the value field: work that follows the listed groups, not the cross. Here
 # every dimension's rows, the filter and the planes come into VMEM once per
-# tile, all rows of a dimension as they are resident (no row is selected or
-# written in HBM), and a table of row indices in SMEM says which rows make
-# each group.
+# tile, and a table of row indices in SMEM says which rows make each group.
+# A dimension comes as it is resident, or, where its resident rows would
+# narrow the tile below `GROUP_FULL_WORDS`, cut first to the rows the table
+# names (`compacted_words`: a copy of those rows, written once a tally).
 _GROUP_ROWS_BYTES = 12 << 20  # one buffer of row tiles; the pipeline keeps two
 GROUP_MAX_GROUPS = 2048  # groups per launch: the index table lives in SMEM
 # A block's row indices are held in scalar registers through its word loop
@@ -521,6 +522,13 @@ _GROUP_ROW_SCALARS = 16
 # dump, PR 38); a tile of 8 leaves it VALU-bound (0.88 a pair).
 _GROUP_COLS = 8
 _GROUP_PARTS = 32  # partial vregs a block carries through its word loop
+# The narrowest tile at which the tally is bound by its word loop's VALU
+# work with the row loads hidden behind it (0.88 bundles a pair-vreg at
+# 4 096 words, v5e bundle dump and chip). Every grid step ends with one lane
+# reduction per count, ~9 bundles against ~3.5 of loop work a pair at 512
+# words: a table whose resident rows leave a narrower tile has its
+# dimensions cut to the rows it names (`compacted_words`).
+GROUP_FULL_WORDS = 4096
 
 
 def group_words(rows_total: int, w: int) -> int:
@@ -532,6 +540,29 @@ def group_words(rows_total: int, w: int) -> int:
     while wt >= _LANES and rows_total * _SUBLANES * wt * 4 > _GROUP_ROWS_BYTES:
         wt //= 2
     return wt if wt >= _LANES and w % wt == 0 else 0
+
+
+def compact_rows(named: int) -> int:
+    """Rows of a dimension cut to the `named` rows its table names: a power
+    of two of at least 8 (the last named row repeated), so that tables of
+    1 to 8 rows compile one shape."""
+    return max(_SUBLANES, 1 << (named - 1).bit_length())
+
+
+def compacted_words(resident, named, other: int, w: int) -> int:
+    """The tile of a group tally whose dimensions are cut to the rows its
+    table names — dimension d, of which the table names named[d] of its
+    resident[d] rows, to `compact_rows(named[d])` rows where those are
+    fewer — beside `other` rows read whole (planes, filter), at w words a
+    row; 0 where the tally reads the resident rows: no dimension would be
+    cut, the resident rows allow a `GROUP_FULL_WORDS` tile already, or the
+    cut would not widen it."""
+    whole = group_words(sum(resident) + other, w)
+    kept = [min(r, compact_rows(n)) for r, n in zip(resident, named)]
+    if whole >= GROUP_FULL_WORDS or kept == list(resident):
+        return 0
+    cut = group_words(sum(kept) + other, w)
+    return cut if cut > whole else 0
 
 
 def group_block(n_dims: int, p_n: int) -> tuple:
@@ -698,18 +729,34 @@ def _group_counts_extent(dims, idx, live, planes, filt, mask_row):
     return out.transpose(1, 2, 0).astype(jnp.uint32)
 
 
+def _cut(dims, rows):
+    """Each dimension [R_d, S, W] cut to its rows rows[d] int32[L_d] ->
+    [L_d, S, W], a copy written row by row (the table indexes those rows);
+    a dimension whose rows[d] is None, or all of them where rows is None,
+    as it is."""
+    if rows is None:
+        return dims
+    return tuple(
+        d if r is None else jnp.stack(
+            [jax.lax.dynamic_index_in_dim(d, r[i], keepdims=False)
+             for i in range(r.shape[0])])
+        for d, r in zip(dims, rows)
+    )
+
+
 @functools.partial(jax.jit, static_argnames=("mask_row",))
-def _group_counts_vmem(dims, idx, live, planes, filt, mask_row):
+def _group_counts_vmem(dims, idx, live, planes, filt, mask_row, rows=None):
     """The jitted entry: every dimension (and planes) a TUPLE of per-extent
     parts that line up span for span, filt [S, W] whole and sliced per
-    extent here; one `pallas_call` per extent inside the one program, the
-    per-shard counts concatenated."""
+    extent here, rows None or a tuple of int32[L_d] or None a dimension
+    (each extent's dimensions cut to them, `_cut`); one `pallas_call` per
+    extent inside the one program, the per-shard counts concatenated."""
     outs, lo = [], 0
     for e in range(len(dims[0])):
         hi = lo + dims[0][e].shape[1]
         outs.append(
             _group_counts_extent(
-                tuple(d[e] for d in dims), idx, live,
+                _cut(tuple(d[e] for d in dims), rows), idx, live,
                 None if planes is None else planes[e],
                 None if filt is None else filt[lo:hi], mask_row,
             )
@@ -719,7 +766,7 @@ def _group_counts_vmem(dims, idx, live, planes, filt, mask_row):
 
 
 def group_counts(  # dispatch-ok: wrapper; callers serialize (run_serialized)
-    dims, idx, live, planes=None, filt=None, mask_row=None
+    dims, idx, live, planes=None, filt=None, mask_row=None, rows=None
 ) -> jnp.ndarray:
     """Counts of listed groups in one pass over the rows they name.
 
@@ -731,25 +778,30 @@ def group_counts(  # dispatch-ok: wrapper; callers serialize (run_serialized)
     of AND_d dims[d][idx[d, g]] (& filt), [g, 1 + p] that of the same &
     planes[mask_row] & planes[p] (mask_row None: no mask). Every row is
     read from HBM once per tally where it lies; nothing but the counts is
-    written."""
+    written. With rows (a tuple of int32[L_d], or None for a dimension
+    read as it is) a dimension is first cut to its rows rows[d], written
+    once as a copy, and idx indexes those."""
     dims = tuple(_parts(d) for d in dims)
     return _group_counts_vmem(
-        dims, idx, live, _parts(planes), filt, mask_row=mask_row
+        dims, idx, live, _parts(planes), filt, mask_row=mask_row, rows=rows
     )
 
 
 @functools.partial(jax.jit, static_argnames=("mask_row", "mesh"))
-def _group_counts_mesh(dims, idx, live, planes, filt, mask_row, mesh):
+def _group_counts_mesh(dims, idx, live, planes, filt, mask_row, mesh,
+                       rows=None):
     """The group tally of stacks sharded over `mesh`: dims [R_d, S, W] and
     planes [P, S, W] as P(None, "shards", "cols"), filt [S, W] as
-    P("shards", "cols"), the table and live count on every device. Each
-    chip runs the one-extent kernel on its [R, S / shards, W / cols] block,
-    sums its per-shard counts on the device, and the "cols" halves are
-    added by a psum -> uint32[G, 1 + P, k] sharded over "shards", k the
-    mesh's "shards" rows, each the counts of its shards. Exact in uint32
-    while a chip row's shards hold fewer than 2^32 bits (4 095 shards of
-    2^20); a wider block keeps its per-shard counts (k = S), which the
-    host sums in uint64. Either way `_read_groups` sums the last axis; the
+    P("shards", "cols"), the table, live count and rows (None, or each
+    dimension's rows to cut it to) on every device. Each chip cuts its
+    dimensions' blocks to those rows (the row axis is not sharded: no
+    collective), runs the one-extent kernel on its [R, S / shards,
+    W / cols] block, sums its per-shard counts on the device, and the
+    "cols" halves are added by a psum -> uint32[G, 1 + P, k] sharded over
+    "shards", k the mesh's "shards" rows, each the counts of its shards.
+    Exact in uint32 while a chip row's shards hold fewer than 2^32 bits
+    (4 095 shards of 2^20); a wider block keeps its per-shard counts
+    (k = S), which the host sums in uint64. Either way `_read_groups` sums the last axis; the
     per-shard form of 1 024 listed groups x 27 counts over 288 shards is
     32 MB, whose uint64 sum alone took 79 ms on a v5e host."""
     stack, row = P(None, "shards", "cols"), P("shards", "cols")
@@ -757,16 +809,19 @@ def _group_counts_mesh(dims, idx, live, planes, filt, mask_row, mesh):
     per_chip = s // mesh.shape["shards"] * w * 32 < 1 << 32
     args = [dims, idx, live]
     specs = [(stack,) * len(dims), P(), P()]
-    for x, spec in ((planes, stack), (filt, row)):
+    cut = None if rows is None else tuple(
+        None if r is None else P() for r in rows)
+    for x, spec in ((rows, cut), (planes, stack), (filt, row)):
         if x is not None:
             args.append(x)
             specs.append(spec)
 
     def body(dims, idx, live, *rest):
         rest = list(rest)
+        r = rest.pop(0) if rows is not None else None
         p = rest.pop(0) if planes is not None else None
         f = rest.pop(0) if filt is not None else None
-        out = _group_counts_extent(dims, idx, live, p, f, mask_row)
+        out = _group_counts_extent(_cut(dims, r), idx, live, p, f, mask_row)
         if per_chip:
             out = jnp.sum(out, axis=-1, keepdims=True, dtype=jnp.uint32)
         return jax.lax.psum(out, "cols")
@@ -778,16 +833,17 @@ def _group_counts_mesh(dims, idx, live, planes, filt, mask_row, mesh):
 
 
 def group_counts_mesh(  # dispatch-ok: wrapper; callers serialize (run_serialized)
-    mesh, dims, idx, live, planes=None, filt=None, mask_row=None
+    mesh, dims, idx, live, planes=None, filt=None, mask_row=None, rows=None
 ) -> jnp.ndarray:
     """`group_counts` for operands sharded over `mesh` (each one array, as
     the stacked path places them there): one program, the kernel on every
-    chip's block, its counts summed over the chip's shards and the "cols"
-    halves added on the device -> uint32[G, 1 + P, k], k the mesh's
-    "shards" rows (`_group_counts_mesh`). The profiler names it
+    chip's block (its dimensions first cut to `rows`, as there), its
+    counts summed over the chip's shards and the "cols" halves added on
+    the device -> uint32[G, 1 + P, k], k the mesh's "shards" rows
+    (`_group_counts_mesh`). The profiler names it
     `jit__group_counts_mesh`."""
     return _group_counts_mesh(
         tuple(_parts(d)[0] for d in dims), idx, live,
         None if planes is None else _parts(planes)[0], filt,
-        mask_row=mask_row, mesh=mesh,
+        mask_row=mask_row, mesh=mesh, rows=rows,
     )

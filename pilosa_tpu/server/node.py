@@ -905,6 +905,11 @@ class NodeServer:
         self.stats.gauge(
             "groupby.mesh_tallies", groupby_mod.STATS["mesh_tallies"]
         )
+        # of the kernel's group tallies, those run on the rows they name
+        self.stats.gauge(
+            "groupby.compacted_tallies",
+            groupby_mod.STATS["compacted_tallies"],
+        )
         # the views' row summaries (core/view.py row_summary): hits over
         # hits + bypassed is the share of Rows / GroupBy-prefetch /
         # unfiltered-TopN reads the table served

@@ -125,6 +125,9 @@ STAT_NAMES = frozenset(
         # of the kernel's tallies, those that ran under shard_map on every
         # chip of the active mesh (the group tally over sharded stacks)
         "groupby.mesh_tallies",
+        # of the kernel's group tallies, those whose dimensions were cut to
+        # the rows their table names before the tiles were sized
+        "groupby.compacted_tallies",
         # per-view row summary (core/view.py row_summary, counted in the
         # process registry and published at scrape time): readers that
         # found the table under the view's current mutation clock, tables

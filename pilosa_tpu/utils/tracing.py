@@ -129,6 +129,8 @@ SPAN_NAMES = frozenset(
         # a GroupBy's (exec/executor.py _tag_group_by) groupby.levels /
         # live_groups / planes (0 without aggregate=) / group_block
         # (aggregate only: groups tallied per loaded plane tile) /
+        # tally_words (aggregate only: the group tally's tile in words,
+        # 0 for the XLA program) /
         # cross_tile (the cross kernel's word-loop tile of each body it
         # ran, "<prefixes>x<rows>x<cols>") / tallies (launches) / fold_ms
         # (host time from the last read to the finished groups) /

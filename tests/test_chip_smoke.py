@@ -24,7 +24,7 @@ def test_smoke_parts_agree_with_a_served_index_small():
         ref = cs.Reference(data)
         queries = cs.read_queries(ref)
         cs.create_schema(http_)
-        assert set(cs.load(uri, data)) == {"f", "g", "h", "v"}
+        assert set(cs.load(uri, data)) == {"f", "g", "h", "k", "v"}
         # a CPU server tallies its GroupBy and filtered TopN with the
         # XLA program, and the smoke's counter check says so; every cold
         # dispatch spans the suite's 8-device mesh, and its check that
@@ -32,11 +32,24 @@ def test_smoke_parts_agree_with_a_served_index_small():
         device = {"platform": "cpu", "kind": "cpu", "count": 8}
         before = cs.tally_counts(http_)
         cs.run_queries(http_, queries, cold=True, device=device)
-        kernel, xla, inplace, assembled, mesh = (
+        kernel, xla, inplace, assembled, mesh, compacted = (
             a - b for a, b in zip(cs.tally_counts(http_), before))
         # 3 shards are one extent (and the mesh stages one array anyway)
         assert kernel == 0 and xla >= 1 and (inplace, assembled) == (0, 0)
         assert mesh == 0  # a CPU mesh runs no kernel
+        assert compacted == 0  # nor cuts a tally to its named rows
+        # on a TPU the aggregate over k's 10 named rows is cut, at a
+        # 4 096-word tile, and the one over 30 resident rows is not
+        for device_ in (dict(device, platform="tpu", count=1),
+                        dict(device, platform="tpu", count=4)):
+            cs.check_cut("group_by_sum_cut", 1, 4096, device_)
+            cs.check_cut("group_by_sum", 0, 16384, device_)
+            for family, cut, words in (("group_by_sum_cut", 0, 0),
+                                       ("group_by_sum_cut", 1, 2048),
+                                       ("group_by_sum", 1, 4096)):
+                with pytest.raises(AssertionError, match="named rows"):
+                    cs.check_cut(family, cut, words, device_)
+        cs.check_cut("group_by_sum_cut", 0, 0, device)
         # four TPU chips: the aggregate's group tally is the kernel on the
         # mesh and nothing else; the count-only cross stays XLA's there
         four = {"platform": "tpu", "kind": "TPU v5 lite", "count": 4}

@@ -274,7 +274,7 @@ def test_kernel_tallies_live_groups_by_planes_in_place(
         # one launch a dimension against the filter, one for the groups
         "kernel_tallies": 3 + 1, "inplace_tallies": 3 + 1, "evals": 3 + 1,
         "xla_tallies": 0, "assembled_stacks": 0, "assembled_bytes": 0,
-        "mesh_tallies": 0,
+        "mesh_tallies": 0, "compacted_tallies": 0,
     }
     # a signed field costs one more launch, over the same groups
     qgb.reset_stats()
@@ -415,21 +415,31 @@ _ORACLE_CASES = {
     # deeper than one slab of partials
     "p34_gb": ((5,), 34, 0, True, "gb"),
     "p34_full": ((5,), 34, None, False, "full"),
+    # through `group_tally`, with a tile buffer that holds every resident
+    # row at less than the full width: each extent's wide dimensions cut to
+    # the rows the table names (the last named row repeated up to 8 or 16
+    # rows), repeated rows, dimensions no cut would shrink read whole
+    **{"cut_" + "_".join(map(str, n)): ((40, 5, 3), 6, 0, True, 11, n)
+       for n in ((3, 5, 2), (1, 1, 1))},
+    "cut_16_sign": ((40, 12), 26, 1, False, 11, (9, 4)),
 }
 
 
 @pytest.mark.parametrize("case", sorted(_ORACLE_CASES))
-def test_group_counts_kernel_equals_its_xla_oracle(interpreted, case):
+def test_group_counts_kernel_equals_its_xla_oracle(
+    interpreted, monkeypatch, case
+):
     """The VMEM group tally (interpreted here) against `_counts_groups` on
     random words: parts of unequal spans read in place, a table longer
     than its live groups, ragged and whole blocks of groups, a mask row,
     more planes than one slab of partials, several rounds of the word
-    loop. Groups past `live` read 0."""
+    loop, dimensions cut to the rows the table names. Groups past `live`
+    read 0."""
     import jax.numpy as jnp
 
     from pilosa_tpu.ops import pallas_kernels as pk
 
-    rows, n_planes, mask_row, filtered, live = _ORACLE_CASES[case]
+    rows, n_planes, mask_row, filtered, live, *named = _ORACLE_CASES[case]
     gb = pk.group_block(len(rows), n_planes)[0]
     g = max(16, 1 << gb.bit_length())  # the table: a power of two > gb
     live = {"1": 1, "gb-1": max(1, gb - 1), "gb": gb, "gb+1": gb + 1,
@@ -448,11 +458,33 @@ def test_group_counts_kernel_equals_its_xla_oracle(interpreted, case):
     filt = stack(1)[0] if filtered else None
     planes = stack(n_planes) if n_planes else None
     idx = np.stack([rng.integers(0, len(d), g) for d in dims]).astype(np.int32)
-    got = np.asarray(pk.group_counts(
-        [parts(d) for d in dims], idx, np.array([live], np.int32),
-        None if planes is None else parts(planes),
-        None if filt is None else jnp.asarray(filt), mask_row,
-    ))
+    if not named:
+        got = np.asarray(pk.group_counts(
+            [parts(d) for d in dims], idx, np.array([live], np.int32),
+            None if planes is None else parts(planes),
+            None if filt is None else jnp.asarray(filt), mask_row,
+        ))
+    else:
+        from test_groupby_mesh import named_table
+
+        idx = named_table(rng, rows, named[0], g, live)
+        other = n_planes + filtered
+        cut = sum(min(r, pk.compact_rows(n))
+                  for r, n in zip(rows, named[0])) + other
+        monkeypatch.setattr(pk, "_GROUP_ROWS_BYTES", cut * 8 * 4 * w)
+        assert pk.group_words(sum(rows) + other, w) < w
+        monkeypatch.setattr(qgb, "_kernel_covers", lambda *stacks: True)
+        qgb.reset_stats()
+        info = {}
+        (got,) = qgb.group_tally(
+            [parts(d) for d in dims], idx[:, :live],
+            None if planes is None else parts(planes),
+            None if filt is None else jnp.asarray(filt), mask_row, info,
+        )
+        got = np.asarray(got)
+        assert qgb.STATS["compacted_tallies"] == 1
+        assert qgb.STATS["inplace_tallies"] == 1
+        assert info["tally_words"] == w
     want = np.asarray(qgb._counts_groups(
         tuple(jnp.asarray(d) for d in dims), idx,
         None if planes is None else jnp.asarray(planes),
@@ -481,7 +513,8 @@ def _groupby_dispatch_tags(uri: str, pql: str) -> list:
 @pytest.mark.parametrize("aggregate", [True, False])
 def test_an_aggregate_groupbys_dispatch_tags_its_group_block(node, aggregate):
     """`groupby.group_block` (groups tallied per loaded plane tile; 1 for
-    the XLA program a CPU node runs) rides on an aggregate GroupBy's
+    the XLA program a CPU node runs) and `groupby.tally_words` (the
+    kernel's tile; 0 for the XLA program) ride on an aggregate GroupBy's
     `exec.dispatch` and on no count-only one."""
     pql = "GroupBy(Rows(a), Rows(b), filter=Row(d=1)"
     pql += ", aggregate=Sum(field=v))" if aggregate else ")"
@@ -489,8 +522,10 @@ def test_an_aggregate_groupbys_dispatch_tags_its_group_block(node, aggregate):
     if aggregate:
         assert tags["groupby.group_block"] >= 1
         assert tags["groupby.planes"] > 0
+        assert tags["groupby.tally_words"] == 0
     else:
         assert tags.get("groupby.group_block", 0) == 0
+        assert "groupby.tally_words" not in tags
 
 
 def test_group_block_is_the_kernels_block_where_it_covers(monkeypatch):

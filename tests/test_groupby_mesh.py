@@ -60,7 +60,19 @@ def _numpy_counts(dims, idx, planes, filt, mask_row):
     return np.array(out, np.uint64)
 
 
-# (rows of each dimension, planes, mask row, filtered, shards)
+def named_table(rng, rows, named, g: int, live: int) -> np.ndarray:
+    """int32[n_dims, g]: the first `live` groups name exactly named[d] rows
+    of dimension d (drawn from its rows[d], each at least once, repeated
+    and shuffled); the rest is the table's padding."""
+    idx = np.zeros((len(rows), g), np.int32)
+    for d, (r, n) in enumerate(zip(rows, named)):
+        pick = rng.choice(r, n, replace=False)
+        idx[d, :live] = rng.permutation(pick[np.arange(live) % n])
+    return idx
+
+
+# (rows of each dimension, planes, mask row, filtered, shards[, rows the
+# table names of each dimension: the tally is cut to them])
 _MESH_CASES = {
     # a prune: one dimension's every row against the filter
     "prune_5": ((7,), 0, None, True, 5),
@@ -70,20 +82,32 @@ _MESH_CASES = {
     "planes_exists_5": ((5, 7, 3), 6, 0, True, 5),
     "planes_sign_7": ((5, 7, 3), 6, 1, True, 7),
     "planes_unfiltered_7": ((6, 4), 5, 0, False, 7),
+    # a wide dimension of which a few rows are named (cut to 8 rows, the
+    # last named row repeated as padding), beside two no cut would shrink
+    "cut_wide_few_5": ((40, 5, 3), 6, 0, True, 5, (3, 5, 2)),
+    # 11 groups over 2 x 1 rows: every row repeated in the table
+    "cut_repeats_sign_7": ((40, 6), 5, 1, True, 7, (2, 1)),
+    # more than 8 rows named: cut to 16 and to 8, no filter
+    "cut_sixteen_unfiltered_5": ((40, 12), 3, None, False, 5, (9, 4)),
 }
 
 
 @pytest.mark.parametrize("case", sorted(_MESH_CASES))
-def test_mesh_group_tally_equals_the_oracle_and_numpy(mesh_2x2, case):
+def test_mesh_group_tally_equals_the_oracle_and_numpy(
+    mesh_2x2, monkeypatch, case
+):
     """Each chip tallies its [R, S / 2, W / 2] block and sums it over its
     shards, the "cols" halves are added on the device: one count a mesh
     row of shards, each the oracle's per-shard counts of that row added,
-    the padded shards (5 -> 6, 7 -> 8) among them and counting 0."""
+    the padded shards (5 -> 6, 7 -> 8) among them and counting 0. Where
+    the table names few rows of a dimension and the tile buffer is too
+    small for every resident row at the full width, `group_tally` cuts
+    each chip's dimensions to the named rows and the tile widens."""
     from jax.experimental.pallas import tpu as pltpu
 
-    rows, n_planes, mask_row, filtered, shards = _MESH_CASES[case]
+    rows, n_planes, mask_row, filtered, shards, *named = _MESH_CASES[case]
     rng = np.random.default_rng(11)
-    s_pad, w = pmesh.padded_shards(shards), 256
+    s_pad, w = pmesh.padded_shards(shards), 1024 if named else 256
 
     def stack(n):
         x = rng.integers(0, 2**32, (n, s_pad, w), dtype=np.uint32)
@@ -99,19 +123,42 @@ def test_mesh_group_tally_equals_the_oracle_and_numpy(mesh_2x2, case):
         live = rows[0]
         idx = np.zeros((1, g), np.int32)
         idx[0, :live] = np.arange(live)
+    elif named:
+        idx = named_table(rng, rows, named[0], g, live)
     else:
         idx = np.stack(
             [rng.integers(0, r, g) for r in rows]).astype(np.int32)
     row_spec = NamedSharding(mesh_2x2, P("shards", "cols"))
     stack_spec = NamedSharding(mesh_2x2, P(None, "shards", "cols"))
+    on_mesh = (
+        tuple(jax.device_put(d, stack_spec) for d in dims),
+        None if planes is None else jax.device_put(planes, stack_spec),
+        None if filt is None else jax.device_put(filt, row_spec),
+    )
     with pltpu.force_tpu_interpret_mode():
-        got = pk.group_counts_mesh(
-            mesh_2x2, tuple(jax.device_put(d, stack_spec) for d in dims),
-            idx, np.array([live], np.int32),
-            None if planes is None else jax.device_put(planes, stack_spec),
-            None if filt is None else jax.device_put(filt, row_spec),
-            mask_row,
-        )
+        if not named:
+            got = pk.group_counts_mesh(
+                mesh_2x2, on_mesh[0], idx, np.array([live], np.int32),
+                *on_mesh[1:], mask_row,
+            )
+        else:
+            # a buffer that holds one tile of the cut rows at each chip's
+            # full width, and of the resident rows at less
+            other = n_planes + filtered
+            cut = sum(min(r, pk.compact_rows(n))
+                      for r, n in zip(rows, named[0])) + other
+            monkeypatch.setattr(pk, "_GROUP_ROWS_BYTES", cut * 8 * 4 * w // 2)
+            assert pk.group_words(sum(rows) + other, w // 2) < w // 2
+            monkeypatch.setattr(qgb, "_on_tpu", lambda devices: True)
+            qgb.reset_stats()
+            info = {}
+            (got,) = qgb.group_tally(
+                on_mesh[0], idx[:, :live], on_mesh[1], on_mesh[2], mask_row,
+                info,
+            )
+            assert qgb.STATS["compacted_tallies"] == 1
+            assert qgb.STATS["mesh_tallies"] == 1
+            assert info["tally_words"] == w // 2
     assert got.sharding.is_equivalent_to(
         NamedSharding(mesh_2x2, P(None, None, "shards")), 3)
     got = np.asarray(got)
@@ -129,6 +176,34 @@ def test_mesh_group_tally_equals_the_oracle_and_numpy(mesh_2x2, case):
     summed = qgb._read_groups([got], live)
     assert (summed == _numpy_counts(dims, idx[:, :live], planes, filt,
                                     mask_row)).all()
+
+
+# (resident rows of each dimension, rows its table names, plane and filter
+# rows, words a chip's row holds, the resident tile, the cut tile or 0)
+_CUT_RULE = {
+    # SSB Q3.2 at city grain on a chip of the 2 x 2 mesh: two 250-row
+    # cities and the years, 26 planes and the filter
+    "ssb_x4_q32": ((250, 250, 7), (10, 10, 6), 27, 16384, 512, 4096),
+    "ssb_x4_q33": ((250, 250, 7), (2, 2, 6), 27, 16384, 512, 4096),
+    "ssb_x4_q34": ((250, 250, 7), (2, 1, 1), 27, 16384, 512, 4096),
+    # SSB Q3.1 on one chip: 84 rows already allow 4 096 words
+    "ssb_q31": ((25, 25, 7), (5, 5, 6), 27, 32768, 4096, 0),
+    # a prune names every row of its dimension
+    "ssb_x4_city_prune": ((250,), (250,), 1, 16384, 1024, 0),
+    "ssb_x4_year_prune": ((7,), (7,), 1, 16384, 16384, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CUT_RULE))
+def test_the_tally_is_cut_to_named_rows_where_the_tile_is_narrow(case):
+    """The group tally cuts its dimensions to the rows its table names
+    only where it names fewer than some dimension holds and the resident
+    rows leave a tile under `GROUP_FULL_WORDS`; then the tile is sized
+    from the cut rows."""
+    resident, named, other, w, whole, cut = _CUT_RULE[case]
+    assert pk.group_words(sum(resident) + other, w) == whole
+    assert pk.compacted_words(resident, named, other, w) == cut
+    assert cut == 0 or whole < pk.GROUP_FULL_WORDS <= cut
 
 
 def test_mesh_selection_reads_the_operands(mesh_2x2, monkeypatch):

@@ -477,7 +477,7 @@ class TestStackedGroupBy:
             "evals": launches, "kernel_tallies": 1, "xla_tallies": 0,
             "inplace_tallies": 0, "assembled_stacks": 0,  # one extent
             "assembled_bytes": 0, "aggregate_queries": 0, "plane_tallies": 0,
-            "mesh_tallies": 0,
+            "mesh_tallies": 0, "compacted_tallies": 0,
         }
 
     def test_kernel_replaces_the_xla_tally_inside_the_descent(
@@ -552,7 +552,7 @@ class TestStackedGroupBy:
             "evals": 1, "kernel_tallies": 1, "xla_tallies": 0,
             "inplace_tallies": 1, "assembled_stacks": 0,
             "assembled_bytes": 0, "aggregate_queries": 0, "plane_tallies": 0,
-            "mesh_tallies": 0,
+            "mesh_tallies": 0, "compacted_tallies": 0,
         }
 
     def test_kernel_tally_tags_the_tile_of_its_word_loop(

@@ -119,7 +119,8 @@ def test_cross_counts_compiles_for_v5e_without_copying_its_stacks(
 
 SSB_EXTENTS = (256, 256, 61)  # 573 shards: ssb-sf100
 
-# name: (rows of each dimension, table length, planes, mask row)
+# name: (rows of each dimension, table length, planes, mask row[, the rows
+# each dimension is cut to, 0 for one read whole])
 _GROUP_CASES = {
     # ssb-sf100.flights-q3-q4: each dimension against the filter, then the
     # groups the surviving rows make with the value field's planes
@@ -134,7 +135,20 @@ _GROUP_CASES = {
     # blocks of several groups: 4 (three dimensions' row indices) and 16
     "count_only_3dims": ((25, 25, 7), 256, 0, None),
     "one_plane": ((25,), 32, 1, 0),
+    # a 100-row dimension of which the table names at most 16 rows: each
+    # extent's rows of it copied once, row by row, beside the rest as is
+    "cut_100": ((100, 25, 7), 1024, 26, 0, (16, 0, 0)),
 }
+
+
+def _cut_rows(cut, sharding):
+    """The `rows` operand of a tally cut to `cut` rows a dimension."""
+    if not any(cut):
+        return None
+    return tuple(
+        jax.ShapeDtypeStruct((n,), jnp.int32, sharding=sharding) if n else None
+        for n in cut
+    )
 
 
 @pytest.mark.parametrize("case", sorted(_GROUP_CASES))
@@ -143,8 +157,10 @@ def test_group_counts_compiles_for_v5e_without_copying_its_stacks(
 ):
     """The aggregate GroupBy's tally at the SSB cell's shapes: every
     dimension, the planes and the filter read where the three extents lie
-    (row-major: none of these row counts is 1, 2, 4 or 8k)."""
-    dims, g, planes, mask_row = _GROUP_CASES[case]
+    (row-major: none of these row counts is 1, 2, 4 or 8k); a dimension
+    cut to the rows the table names is written once, no more."""
+    dims, g, planes, mask_row, *cut = _GROUP_CASES[case]
+    cut = cut[0] if cut else (0,) * len(dims)
 
     def stack(rows):
         return tuple(_stack(one_chip, rows, s) for s in SSB_EXTENTS)
@@ -155,20 +171,26 @@ def test_group_counts_compiles_for_v5e_without_copying_its_stacks(
         jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip),
         stack(planes) if planes else None,
         _stack(one_chip, 0, sum(SSB_EXTENTS)),
-        mask_row=mask_row,
+        mask_row=mask_row, rows=_cut_rows(cut, one_chip),
     ).compile()
     assert compiled.as_text().count("tpu_custom_call") >= len(SSB_EXTENTS)
-    assert compiled.memory_analysis().temp_size_in_bytes <= 1 << 20
+    copies = 2 * sum(cut) * max(SSB_EXTENTS) * WORDS * 4
+    assert compiled.memory_analysis().temp_size_in_bytes <= (1 << 20) + copies
 
 
 # ssb-sf100-x4.q32-q34: 573 shards in chunks of 287 and 286, padded to the
 # mesh's two shard rows: a chip's block of a chunk is 144 (or 143) shards x
-# 16 384 words. name: (rows of each dimension, shards, table, planes, mask)
+# 16 384 words. name: (rows of each dimension, shards, table, planes,
+# mask[, the rows each dimension is cut to, 0 for one read whole])
 _MESH_CASES = {
     "x4_prune_city": ((250,), 288, 256, 0, None),
     "x4_prune_year": ((7,), 288, 8, 0, None),
     "x4_q32": ((250, 250, 7), 288, 1024, 26, 0),
     "x4_q32_tail_chunk": ((250, 250, 7), 286, 1024, 26, 0),
+    # the tallies as they run: the cities cut to the 10 (Q3.2) or 1-2
+    # (Q3.3, Q3.4) rows the table names, the year read whole
+    "x4_q32_cut": ((250, 250, 7), 288, 1024, 26, 0, (16, 16, 0)),
+    "x4_q34_cut": ((250, 250, 7), 286, 4, 26, 0, (8, 8, 0)),
 }
 
 
@@ -180,11 +202,13 @@ def test_group_counts_mesh_compiles_for_v5e_at_the_cells_shapes(
     the kernel on its [rows, shards / 2, 16 384] block of the city
     dimensions, the year and the 26 planes of lo_revenue, sums its counts
     over its shards, and the "cols" halves are added by one all-reduce; no
-    operand is copied to feed it, and what is read back is one count a
-    group, plane and row of shards."""
+    operand is copied to feed it (a city cut to the rows the table names
+    is written once), and what is read back is one count a group, plane
+    and row of shards."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    dims, shards, g, planes, mask_row = _MESH_CASES[case]
+    dims, shards, g, planes, mask_row, *cut = _MESH_CASES[case]
+    cut = cut[0] if cut else (0,) * len(dims)
     stack = NamedSharding(four_chips, P(None, "shards", "cols"))
     every = NamedSharding(four_chips, P())
 
@@ -197,13 +221,15 @@ def test_group_counts_mesh_compiles_for_v5e_at_the_cells_shapes(
         jax.ShapeDtypeStruct((1,), jnp.int32, sharding=every),
         rows(planes) if planes else None,
         _stack(NamedSharding(four_chips, P("shards", "cols")), 0, shards),
-        mask_row=mask_row, mesh=four_chips,
+        mask_row=mask_row, mesh=four_chips, rows=_cut_rows(cut, every),
     ).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 1
     assert "all-reduce" in text
-    # the counts and their all-reduce, nothing the size of a stack
+    # the counts and their all-reduce, and each chip's cut rows: nothing
+    # the size of a stack
     counts = g * (1 + planes) * shards * 4
-    assert compiled.memory_analysis().temp_size_in_bytes <= 4 * counts
+    copies = 2 * sum(cut) * (shards // 2) * (WORDS // 2) * 4
+    assert compiled.memory_analysis().temp_size_in_bytes <= 4 * counts + copies
     out = compiled.out_info
     assert out.shape == (g, 1 + planes, 2)
