@@ -5,8 +5,9 @@ several chips as one sharded program. The metrics whose source says
     {"module": "meshwork", "reduce": "per_chip_roofline_pct"}
     {"module": "meshwork", "reduce": "collective_share_pct"}
     {"module": "meshwork", "reduce": "sharded_dispatch_pct"}
+    {"module": "meshwork", "reduce": "mesh_tally_pct"}
 
-All three take the number of chips from the configuration's file (`chips`;
+All four take the number of chips from the configuration's file (`chips`;
 `run.py` has already held the server's `/info` to the cell's). A
 configuration without it, or a program whose `exec.dispatch` spans carry no
 `mesh.devices` tag (one from before the tag), gives nothing to read: None,
@@ -22,11 +23,17 @@ from . import work
 from .readers import did_device_work, spans
 
 # by instruction name; the asynchronous forms (`all-reduce-start`,
-# `all-reduce-done`) and numbered copies (`all-reduce.1`) hold the same words
+# `all-reduce-done`) and numbered copies (`all-reduce.1`) hold the same words,
+# and a `psum` inside `shard_map` keeps its own name (`%psum.3`)
 COLLECTIVES = (
     "all-reduce", "all-gather", "reduce-scatter", "collective-permute",
-    "all-to-all",
+    "all-to-all", "psum",
 )
+
+# the GroupBy tally counters: on the mesh, by the kernels (the mesh's among
+# them), by the XLA program
+TALLIES = ("groupby.mesh_tallies", "groupby.kernel_tallies",
+           "groupby.xla_tallies")
 
 
 def _peak(ctx):
@@ -93,10 +100,26 @@ def sharded_dispatch_pct(ctx, chips: int):
     return 100.0 * sum(n == chips for n in placed) / len(placed)
 
 
+def mesh_tally_pct(ctx, chips: int):
+    """Share of the window's GroupBy tallies (the group and cross kernels'
+    on one device or the mesh, and the XLA program's) that ran on the mesh:
+    deltas of `groupby.mesh_tallies` over `groupby.kernel_tallies` +
+    `groupby.xla_tallies`. A mesh tally is counted among the kernel's too,
+    so a tally that fell back to one device lowers the share where mesh /
+    (mesh + XLA) would not see it."""
+    mesh, kernel, xla = (
+        ctx.after[s] - ctx.before.get(s, 0) if s in ctx.after else None
+        for s in TALLIES)
+    if None in (mesh, kernel, xla) or not kernel + xla:
+        return None
+    return 100.0 * mesh / (kernel + xla)
+
+
 REDUCERS = {
     "per_chip_roofline_pct": per_chip_roofline_pct,
     "collective_share_pct": collective_share_pct,
     "sharded_dispatch_pct": sharded_dispatch_pct,
+    "mesh_tally_pct": mesh_tally_pct,
 }
 
 

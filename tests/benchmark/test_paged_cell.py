@@ -4,7 +4,9 @@ and say what BENCHMARK.json says of them; and a run of
 `segment-10b-paged.count-zipf` cut to 12 rows x 3 shards behind a budget of
 4 rows pages, answers exactly, and reads all three metrics above 0 from the
 tags and the counter the program emits. (Both new cells also run as cases
-of `test_benchmark_harness.py`, which is parametrised over `workloads`.)"""
+of `test_benchmark_harness.py`, which is parametrised over `workloads`.)
+A test that reads BENCHMARK.json's lists takes `bench_root`
+(`conftest.py`): the repo's tree and copies with entries appended."""
 
 import os
 import sys
@@ -26,21 +28,23 @@ NEW_METRICS = ("stage_build_ms", "stage_put_ms", "evictions_per_query")
 ROW_BYTES = 131072
 
 
-def entry(group, name):
-    return next(e for e in BENCH[group] if e["name"] == name)
+def entry(group, name, bench=BENCH):
+    return next(e for e in bench[group] if e["name"] == name)
 
 
-def test_the_paged_configuration_states_its_deployment():
-    cell = harness.Cell(ROOT, PAGED)
-    config, listed = cell.config, entry("configs", "segment-10b-paged")
-    sibling = harness.Cell(ROOT, "segment-10b-share.count-zipf").config
+def test_the_paged_configuration_states_its_deployment(bench_root):
+    root = bench_root
+    bench = harness.read_json(root, "BENCHMARK.json")
+    cell = harness.Cell(root, PAGED)
+    config, listed = cell.config, entry("configs", "segment-10b-paged", bench)
+    sibling = harness.Cell(root, "segment-10b-share.count-zipf").config
     assert config["source"] == listed["source"] and len(listed["source"]) <= 200
     assert sorted(config["reduced"]) == sorted(listed["reduced"])
     # the sibling's shapes, mix and guarantees; only the scale differs
     for key in ("index", "shards", "shard_width_exponent", "columns_per_shard",
                 "guarantees", "published"):
         assert config[key] == sibling[key], key
-    assert cell.mix == harness.Cell(ROOT, "segment-10b-share.count-zipf").mix
+    assert cell.mix == harness.Cell(root, "segment-10b-share.count-zipf").mix
     field, = config["fields"]
     assert field == dict(sibling["fields"][0], rows=1024)
     # 20.0 GB of dense device rows behind a budget that holds 659 of them
@@ -56,38 +60,47 @@ def test_the_paged_configuration_states_its_deployment():
     assert cell.entry["chips"] == 1 and len(cell.entry["why"]) <= 200
 
 
-def test_the_four_caller_mix_is_the_taxi_mix_with_four_clients():
-    one = harness.read_json(ROOT, "benchmarks", "traffic", "q1-q4.json")
-    four = harness.read_json(ROOT, "benchmarks", "traffic", "q1-q4-c4.json")
+def test_the_four_caller_mix_is_the_taxi_mix_with_four_clients(bench_root):
+    root = bench_root
+    one = harness.read_json(root, "benchmarks", "traffic", "q1-q4.json")
+    four = harness.read_json(root, "benchmarks", "traffic", "q1-q4-c4.json")
     assert four["clients"] == 4 and four["why"] != one["why"]
     assert dict(four, clients=1, why="") == dict(one, why="")
-    cell = harness.Cell(ROOT, TAXI_C4)
+    cell = harness.Cell(root, TAXI_C4)
     assert cell.entry["config"] == "taxi-1b" and cell.entry["chips"] == 1
-    assert cell.config == harness.Cell(ROOT, "taxi-1b.q1-q4").config
+    assert cell.config == harness.Cell(root, "taxi-1b.q1-q4").config
     # no p50 in a cell of four templates, as in its sibling
-    assert {m["name"] for m in cell.metrics("end_to_end")} == {
+    assert small.known(cell, "end_to_end") == {
         "qps", "latency_p95_ms", "setup_s"}
 
 
 @pytest.mark.parametrize("new, sibling", [
     (PAGED, "segment-10b-share.count-zipf"), (TAXI_C4, "taxi-1b.q1-q4")])
-def test_a_new_cell_reports_what_its_sibling_reports(new, sibling):
+def test_a_new_cell_reports_what_its_sibling_reports(bench_root, new, sibling):
+    """Everything its sibling reports, and the paged cell the three staging
+    metrics; one appended since for the sibling alone is the appending
+    change's to judge."""
+    cell, other = (harness.Cell(bench_root, c) for c in (new, sibling))
     ours = {m["name"] for g in ("end_to_end", "per_layer")
-            for m in harness.Cell(ROOT, new).metrics(g)}
-    theirs = {m["name"] for g in ("end_to_end", "per_layer")
-              for m in harness.Cell(ROOT, sibling).metrics(g)}
-    assert ours - theirs == (set(NEW_METRICS) if new == PAGED else set())
-    assert theirs <= ours
+            for m in cell.metrics(g)}
+    theirs = {m for g in ("end_to_end", "per_layer")
+              for m in small.known(other, g)}
+    assert theirs | (set(NEW_METRICS) if new == PAGED else set()) <= ours
 
 
 @pytest.mark.parametrize("name", NEW_METRICS)
-def test_a_staging_metric_file_says_what_its_entry_says(name):
-    spec = harness.read_json(ROOT, "benchmarks", "metrics", name + ".json")
-    listed = entry("per_layer", name)
+def test_a_staging_metric_file_says_what_its_entry_says(bench_root, name):
+    """The paged cell is listed first; a cell appended since that pages
+    too may follow it."""
+    bench = harness.read_json(bench_root, "BENCHMARK.json")
+    spec = harness.read_json(
+        bench_root, "benchmarks", "metrics", name + ".json")
+    listed = entry("per_layer", name, bench)
     assert (spec["name"], spec["layer"], spec["unit"], spec["moves"]) == (
         name, listed["layer"], listed["unit"], listed["moves"])
-    assert listed["workloads"] == [PAGED] and listed["better"] == "lower"
-    assert listed["layer"] == entry("per_layer", "restage_mb_per_query")["layer"]
+    assert listed["workloads"][:1] == [PAGED] and listed["better"] == "lower"
+    assert listed["layer"] == entry(
+        "per_layer", "restage_mb_per_query", bench)["layer"]
     kind = spec["source"]["kind"]
     assert listed["source"] == {"span": "program_span",
                                 "counter": "program_counter"}[kind]

@@ -2,7 +2,9 @@
 read by a reducer `lib/readers.py` already has, listed for all six
 accepted cells. The process-runtime three read counters the parent does
 not publish (nothing, and no error, there); the admission three read tags
-the parent's api.admit span does not carry (0.0 there)."""
+the parent's api.admit span does not carry (0.0 there). A test that
+reads BENCHMARK.json's lists takes `bench_root` (`conftest.py`): the
+repo's tree and copies with entries appended."""
 
 import os
 import sys
@@ -19,7 +21,6 @@ from lib import readers  # noqa: E402
 import test_benchmark_harness as small  # noqa: E402
 
 BENCH = harness.read_json(ROOT, "BENCHMARK.json")
-CELLS = [w["name"] for w in BENCH["workloads"]]
 COUNTERS = {"gc_pause_ms": "runtime.gc_pause_ms",
             "gc_full_pause_ms": "runtime.gc_full_pause_ms",
             "ticker_ms": "runtime.tick_ms"}
@@ -27,23 +28,29 @@ TAGS = {"admit_estimate_ms": "admit.estimate_ms",
         "admit_merge_walk_ms": "admit.merge_walk_ms",
         "admit_grant_ms": "admit.grant_ms"}
 RUNTIME_LAYER = "process runtime (CPython collector, server/node.py tickers)"
+# the six in the order they were appended, and how many per_layer entries
+# stood before them then
+SIX = list(COUNTERS) + list(TAGS)
+FOLLOWED = 25
 
 
-def entry(name):
-    return next(e for e in BENCH["per_layer"] if e["name"] == name)
+def entry(name, bench=BENCH):
+    return next(e for e in bench["per_layer"] if e["name"] == name)
 
 
-def spec(name):
-    return harness.read_json(ROOT, "benchmarks", "metrics", name + ".json")
+def spec(name, root=ROOT):
+    return harness.read_json(root, "benchmarks", "metrics", name + ".json")
 
 
 @pytest.mark.parametrize("name", sorted(COUNTERS) + sorted(TAGS))
-def test_a_new_metric_file_says_what_its_entry_says(name):
-    s, listed = spec(name), entry(name)
+def test_a_new_metric_file_says_what_its_entry_says(bench_root, name):
+    bench = harness.read_json(bench_root, "BENCHMARK.json")
+    s, listed = spec(name, bench_root), entry(name, bench)
     assert (s["name"], s["layer"], s["unit"], s["moves"]) == (
         name, listed["layer"], listed["unit"], listed["moves"])
     assert listed["moves"] == "qps" and listed["better"] == "lower"
-    assert listed["workloads"] == CELLS
+    # every cell, those appended since included: a cell appends its name
+    assert listed["workloads"] == [w["name"] for w in bench["workloads"]]
     assert "module" not in s["source"]  # a reducer `lib/readers.py` has
     if name in COUNTERS:
         assert s["source"] == {"kind": "counter", "stat": COUNTERS[name],
@@ -55,17 +62,19 @@ def test_a_new_metric_file_says_what_its_entry_says(name):
                                "tag": TAGS[name],
                                "reduce": "mean_tag_per_request"}
         assert listed["source"] == "program_span"
-        assert listed["layer"] == entry("admit_ms")["layer"]
+        assert listed["layer"] == entry("admit_ms", bench)["layer"]
 
 
-def test_the_new_entries_come_last_and_every_cell_reports_them():
-    names = [m["name"] for m in BENCH["per_layer"]]
-    assert names[-6:] == ["gc_pause_ms", "gc_full_pause_ms", "ticker_ms",
-                          "admit_estimate_ms", "admit_merge_walk_ms",
-                          "admit_grant_ms"]
-    for cell in CELLS:
-        got = {m["name"] for m in harness.Cell(ROOT, cell).metrics("per_layer")}
-        assert set(COUNTERS) | set(TAGS) <= got
+def test_the_new_entries_come_last_and_every_cell_reports_them(bench_root):
+    """The six are one run, in their order, where they were appended:
+    whatever is appended after them leaves them there."""
+    bench = harness.read_json(bench_root, "BENCHMARK.json")
+    names = [m["name"] for m in bench["per_layer"]]
+    assert names[FOLLOWED:FOLLOWED + 6] == SIX
+    for cell in bench["workloads"]:
+        got = {m["name"] for m in
+               harness.Cell(bench_root, cell["name"]).metrics("per_layer")}
+        assert set(SIX) <= got
 
 
 class _Ctx:

@@ -5,7 +5,9 @@ configuration, its dialect (`lib/dialects/ssb.py`: the field kind
 them; a run of `ssb-sf100.flights-q3-q4` cut to a few shards over an
 in-process node comes out correct, with one `sum` altered underneath not
 correct, and its control fails as it must. (The cell also runs as a case
-of `test_benchmark_harness.py`, which is parametrised over `workloads`.)"""
+of `test_benchmark_harness.py`, which is parametrised over `workloads`.)
+A test that reads BENCHMARK.json's lists takes `bench_root`
+(`conftest.py`): the repo's tree and copies with entries appended."""
 
 import copy
 import os
@@ -35,8 +37,8 @@ SHARDS = 4  # of a served run; the reference alone takes REF_SHARDS
 REF_SHARDS = 64  # enough columns that every group of Q4.2 holds some
 
 
-def entry(group, name):
-    return next(e for e in BENCH[group] if e["name"] == name)
+def entry(group, name, bench=BENCH):
+    return next(e for e in bench[group] if e["name"] == name)
 
 
 def small_ssb(n_devices):
@@ -59,9 +61,11 @@ def ssb():
     return cell, data, Reference(data)
 
 
-def test_the_configuration_states_its_deployment():
-    cell = harness.Cell(ROOT, CELL)
-    config, listed = cell.config, entry("configs", "ssb-sf100")
+def test_the_configuration_states_its_deployment(bench_root):
+    root = bench_root
+    bench = harness.read_json(root, "BENCHMARK.json")
+    cell = harness.Cell(root, CELL)
+    config, listed = cell.config, entry("configs", "ssb-sf100", bench)
     assert config["source"] == listed["source"] and len(listed["source"]) <= 200
     assert sorted(config["reduced"]) == sorted(listed["reduced"])
     assert config["dialect"] == "ssb" and config["architecture"] is None
@@ -87,27 +91,35 @@ def test_the_configuration_states_its_deployment():
     assert ryw not in {f.get("of") for f in config["fields"]}
     assert cell.entry["chips"] == 1 and len(cell.entry["why"]) <= 200
     # no p50 in a cell of five fixed templates, as in taxi's
-    assert {m["name"] for m in cell.metrics("end_to_end")} == {
+    assert small.known(cell, "end_to_end") == {
         "qps", "latency_p95_ms", "setup_s"}
 
 
-def test_the_cell_reports_what_taxis_cell_reports_and_its_two_metrics():
-    ours = {m["name"] for m in harness.Cell(ROOT, CELL).metrics("per_layer")}
-    taxi = {m["name"] for m in
-            harness.Cell(ROOT, "taxi-1b.q1-q4").metrics("per_layer")}
-    assert ours - taxi == set(NEW_METRICS) and taxi <= ours
+def test_the_cell_reports_what_taxis_cell_reports_and_its_two_metrics(
+        bench_root):
+    """Taxi's metrics and its own two; one listed for it since (as
+    `groupby_prune_ms`), or for taxi's cell alone, is the appending
+    change's to judge."""
+    ours = {m["name"] for m in
+            harness.Cell(bench_root, CELL).metrics("per_layer")}
+    taxi = small.known(harness.Cell(bench_root, "taxi-1b.q1-q4"), "per_layer")
+    assert set(NEW_METRICS) <= ours - taxi and taxi <= ours
 
 
 @pytest.mark.parametrize("name", NEW_METRICS)
-def test_a_new_metric_file_says_what_its_entry_says(name):
-    spec = harness.read_json(ROOT, "benchmarks", "metrics", name + ".json")
-    listed = entry("per_layer", name)
+def test_a_new_metric_file_says_what_its_entry_says(bench_root, name):
+    """The SSB cell is listed first; a cell appended since that reports the
+    metric too may follow it."""
+    bench = harness.read_json(bench_root, "BENCHMARK.json")
+    spec = harness.read_json(
+        bench_root, "benchmarks", "metrics", name + ".json")
+    listed = entry("per_layer", name, bench)
     assert (spec["name"], spec["layer"], spec["unit"], spec["moves"]) == (
         name, listed["layer"], listed["unit"], listed["moves"])
-    assert listed["workloads"] == [CELL] and listed["better"] == "lower"
+    assert listed["workloads"][:1] == [CELL] and listed["better"] == "lower"
     sibling = {"groupby_fold_ms": "query_kernels_roofline",
                "assembled_mb_per_query": "restage_mb_per_query"}[name]
-    assert listed["layer"] == entry("per_layer", sibling)["layer"]
+    assert listed["layer"] == entry("per_layer", sibling, bench)["layer"]
     assert listed["source"] == {"span": "program_span", "counter":
                                 "program_counter"}[spec["source"]["kind"]]
     assert "module" not in spec["source"]  # a reducer `lib/readers.py` has
@@ -216,17 +228,26 @@ def test_the_warm_up_sends_each_text_twice_and_the_forms_round_trip(ssb):
         assert got != want and set(got) == set(want)
 
 
-def served_run(tmp_path, trace=False):
+def served_run(tmp_path, trace=False, one_device=False):
+    """`one_device`: the node's stacks on one device, as on the cell's one
+    chip, where a CPU node otherwise forms a mesh of all it holds."""
     import jax
 
+    from pilosa_tpu.parallel import mesh as pmesh
     from pilosa_tpu.testing import ClusterHarness
 
+    old_mesh = pmesh.active_mesh()
     with ClusterHarness(1, in_memory=True) as c:
-        return harness.run_cell(
-            small_ssb(len(jax.devices())), seed=2**31 + 37, seconds=1.5,
-            trace=trace, server=small.ServedNode(c[0].node.uri),
-            work=str(tmp_path), require_tpu=False,
-        )
+        try:
+            if one_device:
+                pmesh.set_active_mesh(None)
+            return harness.run_cell(
+                small_ssb(len(jax.devices())), seed=2**31 + 37, seconds=1.5,
+                trace=trace, server=small.ServedNode(c[0].node.uri),
+                work=str(tmp_path), require_tpu=False,
+            )
+        finally:
+            pmesh.set_active_mesh(old_mesh)
 
 
 def test_the_small_cell_runs_correct_and_reads_its_two_metrics(
@@ -242,11 +263,25 @@ def test_the_small_cell_runs_correct_and_reads_its_two_metrics(
     out = served_run(tmp_path, trace=True)
     assert out["correct"], out["checks"]
     metrics = {k: v["value"] for k, v in out["metrics"].items()}
-    assert metrics["groupby_fold_ms"] > 0
+    assert metrics["groupby_fold_ms"] > 0 and metrics["groupby_prune_ms"] > 0
     assert metrics["assembled_mb_per_query"] >= 0  # a CPU node's mesh: none
     # the filter's plan and the tallies: two spans a request
     assert metrics["dispatches_per_query"] == 2.0
     assert metrics["compiles_per_query"] == 0
+
+
+def test_the_one_device_aggregate_path_tags_its_prunes(tmp_path, monkeypatch):
+    """With no mesh, as on the cell's one chip, every text's GroupBy prunes
+    each dimension against its filter and its exec.dispatch says how long
+    that took: `groupby_prune_ms` has something to read in this cell too."""
+    from lib import trace as tracelib
+
+    monkeypatch.setattr(tracelib, "extract", lambda d, w: small.HAND_TRACE)
+    out = served_run(tmp_path, trace=True, one_device=True)
+    assert out["correct"], out["checks"]
+    metrics = {k: v["value"] for k, v in out["metrics"].items()}
+    assert metrics["groupby_prune_ms"] > 0
+    assert "mesh_tally_pct" not in metrics  # not listed for this cell
 
 
 def test_one_sum_altered_underneath_makes_correct_false(tmp_path, monkeypatch):

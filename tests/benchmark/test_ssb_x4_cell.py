@@ -4,7 +4,9 @@ rollups of the city fields and of the month agree, the three templates'
 reference answers equal a numpy group-by written here, the cell is listed
 where a mesh cell reports, and a run cut to a few shards over an
 in-process node comes out correct. (The cell also runs as a case
-of `test_benchmark_harness.py`, which is parametrised over `workloads`.)"""
+of `test_benchmark_harness.py`, which is parametrised over `workloads`.)
+A test that reads BENCHMARK.json's lists takes `bench_root`
+(`conftest.py`): the repo's tree and copies with entries appended."""
 
 import copy
 import os
@@ -18,7 +20,7 @@ sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import run as harness  # noqa: E402
-from lib import work  # noqa: E402
+from lib import readers, work  # noqa: E402
 from lib.data import Data  # noqa: E402
 from lib.reference import Reference  # noqa: E402
 from lib.traffic import Mix  # noqa: E402
@@ -33,10 +35,20 @@ SHARDS = 4  # of a served run
 # month, two cities each way: 8e-7 of the columns) finds groups
 REF_SHARDS = 573
 SEED = 2**31 + 41
+SSB = "ssb-sf100.flights-q3-q4"
+# the group tally's two metrics of this cell: what each file reads, and the
+# cells listed first for it
+TALLY_METRICS = {
+    "mesh_tally_pct": ({"module": "meshwork", "reduce": "mesh_tally_pct"},
+                       [CELL]),
+    "groupby_prune_ms": ({"kind": "span", "span": "exec.dispatch",
+                          "tag": "groupby.prune_ms",
+                          "reduce": "mean_tag_per_request"}, [SSB, CELL]),
+}
 
 
-def entry(group, name):
-    return next(e for e in BENCH[group] if e["name"] == name)
+def entry(group, name, bench=BENCH):
+    return next(e for e in bench[group] if e["name"] == name)
 
 
 def small_cell(n_devices, shards=SHARDS):
@@ -60,28 +72,30 @@ def ssb():
     return cell, data, Reference(data)
 
 
-def test_the_configuration_states_a_four_chip_deployment():
-    cell = harness.Cell(ROOT, CELL)
-    config, listed = cell.config, entry("configs", "ssb-sf100-x4")
+def test_the_configuration_states_a_four_chip_deployment(bench_root):
+    root = bench_root
+    bench = harness.read_json(root, "BENCHMARK.json")
+    cell = harness.Cell(root, CELL)
+    config, listed = cell.config, entry("configs", "ssb-sf100-x4", bench)
     assert config["source"] == listed["source"] and len(listed["source"]) <= 200
-    assert config["source"] != entry("configs", "ssb-sf100")["source"]
+    assert config["source"] != entry("configs", "ssb-sf100", bench)["source"]
     assert sorted(config["reduced"]) == sorted(listed["reduced"])
     assert config["dialect"] == "ssb" and config["architecture"] is None
     assert cell.dialect.forms == ("GroupBy(aggregate=)",)
     # SF 100, as ssb-sf100: nothing of the scale is cut
-    sibling = harness.Cell(ROOT, "ssb-sf100.flights-q3-q4").config
+    sibling = harness.Cell(root, "ssb-sf100.flights-q3-q4").config
     for key in ("shards", "shard_width_exponent", "columns_per_shard"):
         assert config[key] == sibling[key]
     assert config["published"]["lineorder_rows"] == 600_037_902
     # one host of four chips as the 2 x 2 mesh, the budget stated globally
-    x4 = harness.Cell(ROOT, "segment-10b-x4.count-zipf").config
+    x4 = harness.Cell(root, "segment-10b-x4.count-zipf").config
     assert (config["chips"], config["chip_kind"], config["mesh"]) == (
         x4["chips"], x4["chip_kind"], x4["mesh"])
     assert config["server"]["env"] == x4["server"]["env"]
     assert config["server"]["toml"] == {"cache": {"result-mb": 0}}
     assert set(config["server"]["env"]) <= set(config["assumed"])
     assert cell.entry["chips"] == 4 and len(cell.entry["why"]) <= 200
-    assert {m["name"] for m in cell.metrics("end_to_end")} == {
+    assert small.known(cell, "end_to_end") == {
         "qps", "latency_p95_ms", "setup_s"}
     rows = {f["name"]: work.field_rows(config, f["name"], cell.dialect)
             for f in config["fields"]}
@@ -177,25 +191,101 @@ def test_the_warm_up_sends_each_text_twice(ssb):
         == [1.0, 1.0, 1.0]
 
 
-def test_the_new_cell_is_listed_where_a_mesh_cell_reports():
-    """Every metric whose list names all six older cells, and the mesh's
-    three, list this one; the one-chip roofline (whose reader would read 4
-    x the truth here), the cache, staging and eviction metrics do not, nor
-    do the SSB cell's own two, whose lists its test file pins."""
-    six = [w["name"] for w in BENCH["workloads"]][:6]
-    for m in BENCH["per_layer"]:
+# the cells that stood before this one, and where it was appended
+OLDER = ["segment-10b-share.count-zipf", "taxi-1b.q1-q4",
+         "segment-10b-x4.count-zipf", "taxi-1b.q1-q4-c4",
+         "segment-10b-paged.count-zipf", "ssb-sf100.flights-q3-q4"]
+
+
+def test_the_new_cell_is_listed_where_a_mesh_cell_reports(bench_root):
+    """Every metric this suite knows whose list names all six older cells,
+    and the mesh's three, list this one; the one-chip roofline (whose
+    reader would read 4 x the truth here), the cache metrics (the cell runs
+    with the cache off), and the staging and eviction metrics (nothing
+    pages under a mesh) do not. The cell stands where it was appended, after the
+    six, whatever is appended after it; and the four-chip cells stay within
+    the rule for them: at most half of all cells, rounded down, or one."""
+    bench = harness.read_json(bench_root, "BENCHMARK.json")
+    names = [w["name"] for w in bench["workloads"]]
+    assert names[:6] == OLDER and names[6] == CELL
+    for m in bench["per_layer"][:small.KNOWN["per_layer"]]:
         listed = m.get("workloads")
-        if listed is not None and set(six) <= set(listed):
+        if listed is not None and set(OLDER) <= set(listed):
             assert CELL in listed, m["name"]
     for name in ("mesh_kernels_roofline", "collective_share_pct",
-                 "sharded_dispatch_pct"):
-        assert CELL in entry("per_layer", name)["workloads"]
+                 "sharded_dispatch_pct", *TALLY_METRICS):
+        assert CELL in entry("per_layer", name, bench)["workloads"]
     for name in ("query_kernels_roofline", "cache_hit_pct", "cache_lookup_ms",
-                 "stage_build_ms", "stage_put_ms", "evictions_per_query",
-                 "groupby_fold_ms", "assembled_mb_per_query"):
-        assert CELL not in entry("per_layer", name)["workloads"], name
-    assert [w["name"] for w in BENCH["workloads"]][-1] == CELL
-    assert sum(w["chips"] == 4 for w in BENCH["workloads"]) == 2
+                 "stage_build_ms", "stage_put_ms", "evictions_per_query"):
+        assert CELL not in entry("per_layer", name, bench)["workloads"], name
+    assert entry("workloads", CELL, bench)["chips"] == 4
+    four = sum(w["chips"] == 4 for w in bench["workloads"])
+    assert four <= max(len(names) // 2, 1)
+
+
+@pytest.mark.parametrize("name", TALLY_METRICS)
+def test_a_tally_metric_file_says_what_its_entry_says(bench_root, name):
+    """Layer and `moves` as `groupby_fold_ms`; the cells named here come
+    first in its list, and a cell appended since may follow them."""
+    bench = harness.read_json(bench_root, "BENCHMARK.json")
+    spec = harness.read_json(
+        bench_root, "benchmarks", "metrics", name + ".json")
+    listed, fold = entry("per_layer", name, bench), entry(
+        "per_layer", "groupby_fold_ms", bench)
+    source, first = TALLY_METRICS[name]
+    assert (spec["name"], spec["layer"], spec["unit"], spec["moves"]) == (
+        name, listed["layer"], listed["unit"], listed["moves"])
+    assert (listed["layer"], listed["moves"]) == (fold["layer"], fold["moves"])
+    assert listed["workloads"][:len(first)] == first
+    assert spec["source"] == source  # a reducer `lib/` has
+    assert listed["source"] == (
+        "program_span" if source.get("kind") == "span" else "program_counter")
+    assert (listed["unit"], listed["better"]) in (
+        ("%", "higher"), ("ms", "lower"))
+
+
+def test_the_tally_readers_give_the_mesh_share_and_the_prune_time():
+    """The mesh's share is of all the GroupBy tallies, the kernels' and
+    XLA's: a mesh tally is counted among the kernel's too, so a tally that
+    fell back to the one-device kernel lowers it. A program without the
+    counter reads nothing, one without the tag 0.0."""
+    mesh = harness.read_json(ROOT, "benchmarks", "metrics", "mesh_tally_pct.json")
+    prune = harness.read_json(
+        ROOT, "benchmarks", "metrics", "groupby_prune_ms.json")
+
+    class Ctx:
+        config = {"chips": 4}
+        before = {"groupby.mesh_tallies": 10, "groupby.xla_tallies": 3,
+                  "groupby.kernel_tallies": 10}
+        after = {"groupby.mesh_tallies": 22, "groupby.xla_tallies": 3,
+                 "groupby.kernel_tallies": 22}
+        # the filter's plan, then the tally; the second prunes nothing
+        requests = [{"roots": [{"name": "http.request", "tags": {},
+                                "children": [
+            {"name": "exec.dispatch", "tags": {"plan.family": "stacked"},
+             "children": []},
+            {"name": "exec.dispatch", "tags": {"plan.family": "groupby",
+                                               "groupby.prune_ms": p},
+             "children": []}]}]} for p in (6.0, 0.0)]
+
+    assert readers.read(mesh, Ctx) == 100.0
+    assert readers.read(prune, Ctx) == pytest.approx(3.0)
+    Ctx.after = dict(Ctx.after, **{"groupby.xla_tallies": 7})
+    assert readers.read(mesh, Ctx) == pytest.approx(75.0)
+    # four tallies fell back to the one-device kernel: mesh / (mesh + XLA)
+    # would still read 100
+    Ctx.after = {"groupby.mesh_tallies": 22, "groupby.xla_tallies": 3,
+                 "groupby.kernel_tallies": 26}
+    assert readers.read(mesh, Ctx) == pytest.approx(75.0)
+    Ctx.after = {"groupby.mesh_tallies": 10, "groupby.xla_tallies": 3,
+                 "groupby.kernel_tallies": 10}  # no tally in the window
+    assert readers.read(mesh, Ctx) is None
+    Ctx.after = {"groupby.xla_tallies": 7,  # a program without the counter
+                 "groupby.kernel_tallies": 10}
+    assert readers.read(mesh, Ctx) is None
+    Ctx.requests = [{"roots": [{"name": "exec.dispatch", "tags": {},
+                                "children": []}]}]
+    assert readers.read(prune, Ctx) == 0.0
 
 
 def served_run(tmp_path, trace=False):
@@ -227,3 +317,6 @@ def test_the_small_cell_runs_correct_on_every_device(
     # over every device the node holds
     assert metrics["dispatches_per_query"] == 2.0
     assert metrics["sharded_dispatch_pct"] == 100.0
+    # every text prunes under its filter; a CPU mesh tallies with XLA
+    assert metrics["groupby_prune_ms"] > 0
+    assert metrics["mesh_tally_pct"] == 0.0
